@@ -47,8 +47,11 @@ Emits the repo's CSV row format plus a machine-readable
 
     PYTHONPATH=src python -m benchmarks.bench_bcd_eval \
         [--rt 32] [--chunk-size 8] [--prefetch auto] [--repeats 3] \
-        [--out BENCH_bcd_eval.json] [--history BENCH_history.jsonl] \
-        [--compile-cache DIR]
+        [--out BENCH_bcd_eval.json] [--history BENCH_history.jsonl]
+
+JAX's persistent compilation cache is on (``launch.compile_cache``:
+``JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.jax_cache``); the hit count
+prints at exit.
 """
 from __future__ import annotations
 
@@ -215,15 +218,10 @@ def main():
     ap.add_argument("--history", default=None,
                     help="append-only perf log (default: BENCH_history.jsonl"
                          " next to --out; pass 'none' to skip)")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="enable the jax persistent compilation cache at "
-                         "DIR (re-runs skip re-jit; hit counts are logged)")
     args = ap.parse_args()
 
-    counter = None
-    if args.compile_cache:
-        compile_cache.enable(args.compile_cache)
-        counter = compile_cache.hit_counter()
+    compile_cache.enable()
+    counter = compile_cache.hit_counter()
 
     if args.arch == "resnet":
         model, params, batch, masks0 = build_pipeline(
@@ -437,8 +435,7 @@ def main():
           f"{report['speedup_suffix_vs_batched_mean']:.2f}x, aggregate "
           f"{report['speedup_suffix_vs_batched_aggregate']:.2f}x"
           f"  -> {args.out}")
-    if counter is not None:
-        print(counter.log_line())
+    print(counter.log_line())
     return report
 
 

@@ -12,6 +12,7 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+import traceback
 
 import numpy as np
 import jax
@@ -224,21 +225,28 @@ ALL = [bench_table1_relu_counts, bench_pi_latency, bench_kernel_masked_act,
        bench_fig4_bcd_on_autorep, bench_lm_linearize]
 
 
-def main() -> None:
+def main() -> int:
+    """Run every selected phase; a phase that raises prints an ``ERROR``
+    row and its traceback, the rest still run, and the exit code is 1."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
     print("name,us_per_call,derived")
+    failed = []
     for fn in ALL:
         if args.only and args.only not in fn.__name__:
             continue
         try:
             fn()
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — report, run the rest, fail
+            failed.append(fn.__name__)
             C.row(fn.__name__, 0.0, f"ERROR:{type(e).__name__}:{e}")
-            import traceback
             traceback.print_exc(file=sys.stderr)
+    if failed:
+        print(f"FAILED phases: {','.join(failed)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -81,7 +81,6 @@ def parse_args():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=32)
     ap.add_argument("--eval-batch", type=int, default=4)
-    ap.add_argument("--compile-cache", default=None, metavar="DIR")
     ap.add_argument("--bench-history", default=None,
                     help="append the post-sweep mid-scan suffix-vs-batched "
                          "timing here (default: <out-dir>/BENCH_history"
@@ -235,10 +234,8 @@ def record_midscan_speedup(args, model, masks, params, eval_b):
 
 def main():
     args = parse_args()
-    counter = None
-    if args.compile_cache:
-        compile_cache.enable(args.compile_cache)
-        counter = compile_cache.hit_counter()
+    compile_cache.enable()
+    counter = compile_cache.hit_counter()
 
     cfg = get_config(args.arch).reduced()
     model = LM(cfg)
@@ -322,8 +319,7 @@ def main():
     if coordinator.is_writer:
         record_midscan_speedup(args, model, payload["final_masks"],
                                holder["params"], eval_b)
-    if counter is not None:
-        print(counter.log_line())
+    print(counter.log_line())
     return payload
 
 

@@ -5,7 +5,7 @@ vs SNL(B_target) head-to-head (Fig. 1 / Table 3 protocol, synthetic CIFAR).
         [--image-size 16] [--ref-frac 0.6] [--target-frac 0.4] [--full] \
         [--engine batched] [--chunk-size 8] [--prefetch 2|auto] \
         [--moves remove,add_back,swap,stage_drop,share] \
-        [--proposal uniform|sensitivity] [--compile-cache DIR]
+        [--proposal uniform|sensitivity]
 
 --full uses the real ResNet18 geometry at 32x32 (slow on CPU); the default
 uses a reduced stage plan with the same code path.  --engine selects the BCD
@@ -23,9 +23,10 @@ bit-identical across engines for a fixed seed.  --moves widens the
 coordinate-descent move set beyond the paper's removals (docs/bcd_engine.md
 §Move vocabulary) and --proposal sensitivity weights kinds/sites by their
 running acceptance rates; per-kind accepted/proposed counters land in the
-sweep artifact and print at exit.  --compile-cache DIR turns
-on jax's persistent compilation cache so re-runs and resumed sweeps skip
-re-jit (hit counts print at exit).
+sweep artifact and print at exit.  JAX's persistent compilation cache is
+on (launch.compile_cache: JAX_COMPILATION_CACHE_DIR, else
+<checkout>/.jax_cache), so re-runs and resumed sweeps skip re-jit (hit
+counts print at exit).
 
 Sweep mode (the paper's accuracy-vs-budget curve, Fig. 4 protocol):
 
@@ -90,10 +91,6 @@ def parse_args():
                     help="chunks kept staged ahead (pipelined/suffix "
                          "engines), or 'auto' to pick from measured rates "
                          "(pipelined and suffix)")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="enable the jax persistent compilation cache at "
-                         "DIR — sweep restarts stop paying re-jit (cache "
-                         "hit counts are logged at exit)")
     ap.add_argument("--sweep", default=None,
                     help="comma-separated descending budget fractions "
                          "(e.g. '0.55,0.4'): run the multi-budget sweep "
@@ -329,19 +326,16 @@ def run_head_to_head(args):
 
 def main():
     args = parse_args()
-    counter = None
-    if args.compile_cache:
-        # before any jit: re-runs and resumed sweeps then reuse compiled
-        # executables instead of paying re-jit (the cache key covers
-        # jax/XLA versions + options, so stale dirs are cold, not wrong)
-        compile_cache.enable(args.compile_cache)
-        counter = compile_cache.hit_counter()
+    # before any jit: re-runs and resumed sweeps then reuse compiled
+    # executables instead of paying re-jit (the cache key covers jax/XLA
+    # versions + options, so stale dirs are cold, not wrong)
+    compile_cache.enable()
+    counter = compile_cache.hit_counter()
     if args.sweep is not None:
         run_sweep_mode(args)
     else:
         run_head_to_head(args)
-    if counter is not None:
-        print(counter.log_line())
+    print(counter.log_line())
 
 
 if __name__ == "__main__":

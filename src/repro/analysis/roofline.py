@@ -17,24 +17,37 @@ import dataclasses
 import re
 from typing import Dict, Optional, Tuple
 
-# TPU v5e per-chip constants (per assignment)
-PEAK_FLOPS = 197e12          # bf16
-HBM_BW = 819e9               # bytes/s
-LINK_BW = 50e9               # bytes/s per ICI link
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    """Published per-chip peaks."""
+    flops: float                 # bf16 FLOP/s
+    hbm_bw: float                # HBM bytes/s
+    link_bw: float               # bytes/s per ICI link
+
+
+# Keyed by ``jax.Device.device_kind``.  TPU v5e: Google Cloud documentation,
+# "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s of ICI per chip
+# over 4 links (50 GB/s each).
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """The peaks of ``device_kind``; a device not in :data:`PEAKS` is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r} (known: {sorted(PEAKS)})") from None
+
 
 def xla_cost(compiled) -> Dict[str, float]:
-    """``Compiled.cost_analysis()`` normalized across jax versions.
-
-    Newer jax returns the flat properties dict directly; older versions wrap
-    it in a one-element list (one dict per partition).  Returns {} when the
-    backend offers no cost analysis.
-    """
-    ca = compiled.cost_analysis()
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca)
+    """``Compiled.cost_analysis()`` as a dict ({} when the backend offers no
+    cost analysis)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 _DTYPE_BYTES = {
@@ -139,6 +152,7 @@ class Roofline:
     shape: str
     mesh: str
     chips: int
+    device_kind: str               # key into PEAKS
     flops_per_device: float        # raw XLA cost_analysis (body-once!)
     bytes_per_device: float        # raw XLA cost_analysis (body-once!)
     collective_bytes_global: float
@@ -148,20 +162,27 @@ class Roofline:
     bytes_per_device_peak: Optional[float] = None   # memory_analysis
 
     @property
+    def peaks(self) -> Peaks:
+        return peaks(self.device_kind)
+
+    @property
     def t_compute(self):
         if self.analytic_flops_global:
-            return self.analytic_flops_global / (self.chips * PEAK_FLOPS)
-        return self.flops_per_device / PEAK_FLOPS
+            return self.analytic_flops_global / (self.chips
+                                                 * self.peaks.flops)
+        return self.flops_per_device / self.peaks.flops
 
     @property
     def t_memory(self):
         if self.analytic_bytes_global:
-            return self.analytic_bytes_global / (self.chips * HBM_BW)
-        return self.bytes_per_device / HBM_BW
+            return self.analytic_bytes_global / (self.chips
+                                                 * self.peaks.hbm_bw)
+        return self.bytes_per_device / self.peaks.hbm_bw
 
     @property
     def t_collective(self):
-        return self.collective_bytes_global / (self.chips * LINK_BW)
+        return self.collective_bytes_global / (self.chips
+                                               * self.peaks.link_bw)
 
     @property
     def bottleneck(self):
@@ -177,7 +198,7 @@ class Roofline:
     def roofline_fraction(self):
         """Fraction of the hardware roof actually doing model math:
         (MODEL_FLOPS / chips / peak) / max(term) — 1.0 = perfect."""
-        t_model = self.model_flops_global / (self.chips * PEAK_FLOPS)
+        t_model = self.model_flops_global / (self.chips * self.peaks.flops)
         t_dom = max(self.t_compute, self.t_memory, self.t_collective)
         return t_model / max(t_dom, 1e-30)
 

@@ -55,6 +55,7 @@ but replays selection in sampling order — ``tests/test_split_forward.py``).
 from __future__ import annotations
 
 import collections
+import contextlib
 import functools
 import statistics
 import time
@@ -221,6 +222,14 @@ def evaluate_prefetched(evaluator, chunks: Iterable[M.MaskTree]
         yield evaluator.evaluate_staged(staged.popleft())
 
 
+def _mesh_scope(mesh):
+    """Dispatch context for an evaluator's jits: under a mesh, trace and run
+    inside ``jax.set_mesh`` so the TPU kernels see it and run per device
+    shard (``kernels.ops._per_shard``) — Mosaic kernels cannot be
+    partitioned automatically."""
+    return contextlib.nullcontext() if mesh is None else jax.set_mesh(mesh)
+
+
 def _with_stacked_route(eval_fn, *, fused: bool = False):
     """Trace eval_fn under linearize.stacked_kernel_route so the TPU
     hard-mask dispatch emits the custom-vmap routed op: vmapping the
@@ -278,6 +287,7 @@ class BatchedEvaluator:
         between outer steps update it via :meth:`set_context` and the
         compiled executable picks up the new values without retracing."""
         self._has_ctx = context is not None
+        self._mesh = None
         # commit the context to device once: leaving numpy leaves in the
         # tree makes every dispatch re-transfer them (and re-hash the host
         # arrays), which is pure per-chunk overhead on the hot path
@@ -320,8 +330,9 @@ class BatchedEvaluator:
         if self._pad_to is not None and n < self._pad_to:
             stacked = M.pad_stacked(stacked, self._pad_to)
         batch = self._device_batch(stacked)
-        accs = self._vmapped(batch, self.context) if self._has_ctx \
-            else self._vmapped(batch)
+        with _mesh_scope(self._mesh):
+            accs = self._vmapped(batch, self.context) if self._has_ctx \
+                else self._vmapped(batch)
         return StagedChunk(n, accs)
 
     def evaluate_staged(self, staged: StagedChunk) -> np.ndarray:
@@ -755,7 +766,8 @@ class SuffixEvaluator:
         from it."""
         if self._pre_jit is None:
             return context
-        pre = self._pre_jit(context)
+        with _mesh_scope(self._mesh):
+            pre = self._pre_jit(context)
         if self._mesh is not None:
             pre = jax.device_put(pre, self._cache_sharding)
         return {**context, "pre": pre}
@@ -933,8 +945,9 @@ class SuffixEvaluator:
                                  device=self._cand_sharding))
         batch = {k: put(np.asarray(v, dtype=np.float32))
                  for k, v in sub.items()}
-        cached = self._prefix_for(site)
-        accs = self._suffix_for(site)(batch, cached, self.context)
+        with _mesh_scope(self._mesh):
+            cached = self._prefix_for(site)
+            accs = self._suffix_for(site)(batch, cached, self.context)
         return StagedChunk(n, accs)
 
     # ------------------------------------------------------------- protocol

@@ -124,10 +124,13 @@ def finetune(params, hard_masks: M.MaskTree, loss_fn, batches,
                        schedule=opt_lib.cosine(lr, steps)))
     masks_dev = M.as_device(hard_masks)
 
+    # masks are a jit argument, not a closure constant: every call then
+    # lowers the same program, which the persistent compilation cache serves
+    # instead of recompiling per BCD step
     @jax.jit
-    def step(p, ostate, batch):
+    def step(p, ostate, batch, masks):
         def l(p):
-            loss, _ = loss_fn(p, masks_dev, batch, False)
+            loss, _ = loss_fn(p, masks, batch, False)
             return loss
         grads = jax.grad(l)(p)
         updates, ostate = opt.update(grads, ostate, p)
@@ -135,5 +138,6 @@ def finetune(params, hard_masks: M.MaskTree, loss_fn, batches,
 
     ostate = opt.init(params)
     for i in range(steps):
-        params, ostate = step(params, ostate, batches(start_step + i))
+        params, ostate = step(params, ostate, batches(start_step + i),
+                              masks_dev)
     return params

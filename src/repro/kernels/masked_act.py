@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _SQRT_2_OVER_PI = 0.7978845608028654
 
@@ -207,29 +208,113 @@ def masked_act_2d_batched(
 # guide).  Replacement is identity-only (poly2 sites keep the unfused pair)
 # and weights are candidate-shared.
 #
-# VMEM footprint: the matmul kernel holds (block_rows, K) + (K, N) per
-# program; the conv kernel holds one sample's (H, W, Cin) site plus the
-# (Ho*Wo, 9*Cin) patch matrix and (9*Cin, Cout) weights — sized for
-# CIFAR-scale stages (≤32×32×512 f32 ≈ 2 MB), not ImageNet stems.
+# VMEM footprint: the matmul kernel tiles rows, output columns and the
+# contraction (K) axis, so a program holds a (block_rows, block_k) gate tile,
+# a (block_k, block_n) weight tile and a (block_rows, block_n) float32
+# accumulator whatever K and N are — an LM down-projection's whole (K, N)
+# weight does not fit VMEM.  The conv kernel holds one sample's (H, W, Cin)
+# site plus the (H*W, 9*Cin) patch matrix and (9*Cin, Cout) weights — sized
+# for CIFAR-scale stages (≤32×32×512 f32 ≈ 2 MB), not ImageNet stems.
 
 
-def _masked_act_matmul_kernel(x_ref, m_ref, w_ref, o_ref, *, kind: str):
-    x = x_ref[...]                       # (br, K)
-    m = m_ref[...].astype(x.dtype)       # (1, K) -> broadcast over rows
-    g = m * _act_tile(x, kind) + (1.0 - m) * x
-    o_ref[...] = jnp.dot(g, w_ref[...],
-                         preferred_element_type=jnp.float32
-                         ).astype(o_ref.dtype)
+def _gate(x, m, kind: str):
+    return m * _act_tile(x, kind) + (1.0 - m) * x
 
 
-def _masked_act_matmul_mul_kernel(x_ref, m_ref, u_ref, w_ref, o_ref,
-                                  *, kind: str):
-    x = x_ref[...]
-    m = m_ref[...].astype(x.dtype)
-    g = (m * _act_tile(x, kind) + (1.0 - m) * x) * u_ref[...]
-    o_ref[...] = jnp.dot(g, w_ref[...],
-                         preferred_element_type=jnp.float32
-                         ).astype(o_ref.dtype)
+def _matmul_tile(dim: int, pref: int) -> int:
+    """Block size along a tiled matmul axis: the whole axis when it fits
+    ``pref`` (a full-extent block is always legal), else the largest of
+    pref, pref/2, …, 128 that divides it (lane-aligned), else ``pref`` with
+    the axis zero-padded up to a multiple."""
+    if dim <= pref:
+        return dim
+    b = pref
+    while b >= 128:
+        if dim % b == 0:
+            return b
+        b //= 2
+    return pref
+
+
+def _masked_act_matmul_kernel(*refs, kind: str, has_mul: bool):
+    if has_mul:
+        x_ref, m_ref, u_ref, w_ref, o_ref, acc_ref = refs
+    else:
+        x_ref, m_ref, w_ref, o_ref, acc_ref = refs
+    k = pl.program_id(3)
+
+    @pl.when(k == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    x = x_ref[0]                          # (br, bk) of one candidate
+    g = _gate(x, m_ref[0].astype(x.dtype), kind)   # mask (1, bk) over rows
+    if has_mul:
+        g = g * u_ref[0]
+    acc_ref[...] += jnp.dot(g, w_ref[...], preferred_element_type=jnp.float32)
+
+    @pl.when(k == pl.num_programs(3) - 1)
+    def _():
+        o_ref[0] = acc_ref[...].astype(o_ref.dtype)
+
+
+def masked_act_matmul_2d_batched(
+    x: jax.Array,
+    mask: jax.Array,
+    w: jax.Array,
+    mul: jax.Array | None = None,
+    *,
+    kind: str = "relu",
+    block_rows: int = 256,
+    block_n: int = 512,
+    block_k: int = 512,
+    interpret: bool = False,
+) -> jax.Array:
+    """Fused ``(m·act(x) + (1−m)·x) [· mul] @ w`` over N stacked candidates.
+
+    x: (N, rows, K); mask: (N, K) 0/1 — one mask row per candidate;
+    w: (K, N_out) candidate-shared weights; mul: optional (N, rows, K)
+    second operand (gated-FFN up branch, multiplied after the gate, before
+    the matmul).  Grid (candidate, rows, N_out, K) with K innermost: each
+    program adds one (block_rows, block_k) @ (block_k, block_n) product into
+    a float32 VMEM accumulator.  The gated tensor never leaves VMEM.
+    Zero-padding K is exact: every activation maps 0 to 0.
+    """
+    n, rows, k = x.shape
+    assert mask.shape == (n, k), (mask.shape, x.shape)
+    assert w.shape[0] == k, (w.shape, x.shape)
+    n_out = w.shape[1]
+    br = min(block_rows, rows)
+    bn = _matmul_tile(n_out, block_n)
+    bk = _matmul_tile(k, block_k)
+    pr, pn, pk = (-rows) % br, (-n_out) % bn, (-k) % bk
+    xp = jnp.pad(x, ((0, 0), (0, pr), (0, pk))) if (pr or pk) else x
+    mp = (jnp.pad(mask, ((0, 0), (0, pk))) if pk else mask).reshape(n, 1, -1)
+    wp = jnp.pad(w, ((0, pk), (0, pn))) if (pk or pn) else w
+    grid = (n, xp.shape[1] // br, wp.shape[1] // bn, wp.shape[0] // bk)
+    x_spec = pl.BlockSpec((1, br, bk), lambda b, i, j, kk: (b, i, kk))
+    in_specs = [x_spec,
+                pl.BlockSpec((1, 1, bk), lambda b, i, j, kk: (b, 0, kk))]
+    args = [xp, mp]
+    if mul is not None:
+        in_specs.append(x_spec)
+        args.append(jnp.pad(mul, ((0, 0), (0, pr), (0, pk)))
+                    if (pr or pk) else mul)
+    in_specs.append(pl.BlockSpec((bk, bn), lambda b, i, j, kk: (kk, j)))
+    args.append(wp)
+    out = pl.pallas_call(
+        functools.partial(_masked_act_matmul_kernel, kind=kind,
+                          has_mul=mul is not None),
+        grid=grid, in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, br, bn), lambda b, i, j, kk: (b, i, j)),
+        out_shape=jax.ShapeDtypeStruct((n, xp.shape[1], wp.shape[1]),
+                                       x.dtype),
+        scratch_shapes=[pltpu.VMEM((br, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary")),
+        interpret=interpret)(*args)
+    return out[:, :rows, :n_out] if (pr or pn) else out
 
 
 def masked_act_matmul_2d(
@@ -242,152 +327,42 @@ def masked_act_matmul_2d(
     block_rows: int = 256,
     interpret: bool = False,
 ) -> jax.Array:
-    """Fused ``(m·act(x) + (1−m)·x) [· mul] @ w`` over a 2D (rows, K) array.
-
-    mask: (K,) 0/1; w: (K, N) candidate-shared weights; mul: optional
-    (rows, K) second operand (gated-FFN up branch, multiplied after the
-    gate, before the matmul).  The gated tensor never leaves VMEM.
-    """
-    rows, k = x.shape
-    assert mask.shape == (k,), (mask.shape, x.shape)
-    assert w.shape[0] == k, (w.shape, x.shape)
-    n_out = w.shape[1]
-    br = min(block_rows, rows)
-    pr = (-rows) % br
-    xp = jnp.pad(x, ((0, pr), (0, 0))) if pr else x
-    grid = (xp.shape[0] // br,)
-    x_spec = pl.BlockSpec((br, k), lambda i: (i, 0))
-    m_spec = pl.BlockSpec((1, k), lambda i: (0, 0))
-    w_spec = pl.BlockSpec((k, n_out), lambda i: (0, 0))
-    out_spec = pl.BlockSpec((br, n_out), lambda i: (i, 0))
-    out_shape = jax.ShapeDtypeStruct((xp.shape[0], n_out), x.dtype)
-    if mul is None:
-        fn = pl.pallas_call(
-            functools.partial(_masked_act_matmul_kernel, kind=kind),
-            grid=grid, in_specs=[x_spec, m_spec, w_spec],
-            out_specs=out_spec, out_shape=out_shape, interpret=interpret)
-        out = fn(xp, mask.reshape(1, -1), w)
-    else:
-        up = jnp.pad(mul, ((0, pr), (0, 0))) if pr else mul
-        fn = pl.pallas_call(
-            functools.partial(_masked_act_matmul_mul_kernel, kind=kind),
-            grid=grid, in_specs=[x_spec, m_spec, x_spec, w_spec],
-            out_specs=out_spec, out_shape=out_shape, interpret=interpret)
-        out = fn(xp, mask.reshape(1, -1), up, w)
-    return out[:rows] if pr else out
+    """One-candidate :func:`masked_act_matmul_2d_batched`: x (rows, K),
+    mask (K,), w (K, N_out), mul optional (rows, K)."""
+    out = masked_act_matmul_2d_batched(
+        x[None], mask[None], w, None if mul is None else mul[None],
+        kind=kind, block_rows=block_rows, interpret=interpret)
+    return out[0]
 
 
-def _masked_act_matmul_kernel_b(x_ref, m_ref, w_ref, o_ref, *, kind: str):
-    x = x_ref[0]                         # (br, K) of one candidate
-    m = m_ref[0].astype(x.dtype)         # (1, K) — candidate's mask row
-    g = m * _act_tile(x, kind) + (1.0 - m) * x
-    o_ref[0] = jnp.dot(g, w_ref[...],
-                       preferred_element_type=jnp.float32
-                       ).astype(o_ref.dtype)
+def _conv3x3_tile(g, w_flat, *, out_dtype):
+    """im2col SAME 3x3 conv of one gated sample g: (H, W, Cin) ->
+    (H, W, Cout).
 
-
-def _masked_act_matmul_mul_kernel_b(x_ref, m_ref, u_ref, w_ref, o_ref,
-                                    *, kind: str):
-    x = x_ref[0]
-    m = m_ref[0].astype(x.dtype)
-    g = (m * _act_tile(x, kind) + (1.0 - m) * x) * u_ref[0]
-    o_ref[0] = jnp.dot(g, w_ref[...],
-                       preferred_element_type=jnp.float32
-                       ).astype(o_ref.dtype)
-
-
-def masked_act_matmul_2d_batched(
-    x: jax.Array,
-    mask: jax.Array,
-    w: jax.Array,
-    mul: jax.Array | None = None,
-    *,
-    kind: str = "relu",
-    block_rows: int = 256,
-    interpret: bool = False,
-) -> jax.Array:
-    """Stacked-candidate :func:`masked_act_matmul_2d`.
-
-    x: (N, rows, K); mask: (N, K) — one mask row per candidate; w: (K, N_out)
-    shared; mul: optional (N, rows, K).
-    """
-    n, rows, k = x.shape
-    assert mask.shape == (n, k), (mask.shape, x.shape)
-    n_out = w.shape[1]
-    br = min(block_rows, rows)
-    pr = (-rows) % br
-    xp = jnp.pad(x, ((0, 0), (0, pr), (0, 0))) if pr else x
-    grid = (n, xp.shape[1] // br)
-    x_spec = pl.BlockSpec((1, br, k), lambda b, i: (b, i, 0))
-    m_spec = pl.BlockSpec((1, 1, k), lambda b, i: (b, 0, 0))
-    w_spec = pl.BlockSpec((k, n_out), lambda b, i: (0, 0))
-    out_spec = pl.BlockSpec((1, br, n_out), lambda b, i: (b, i, 0))
-    out_shape = jax.ShapeDtypeStruct((n, xp.shape[1], n_out), x.dtype)
-    if mul is None:
-        fn = pl.pallas_call(
-            functools.partial(_masked_act_matmul_kernel_b, kind=kind),
-            grid=grid, in_specs=[x_spec, m_spec, w_spec],
-            out_specs=out_spec, out_shape=out_shape, interpret=interpret)
-        out = fn(xp, mask.reshape(n, 1, k), w)
-    else:
-        up = jnp.pad(mul, ((0, 0), (0, pr), (0, 0))) if pr else mul
-        fn = pl.pallas_call(
-            functools.partial(_masked_act_matmul_mul_kernel_b, kind=kind),
-            grid=grid, in_specs=[x_spec, m_spec, x_spec, w_spec],
-            out_specs=out_spec, out_shape=out_shape, interpret=interpret)
-        out = fn(xp, mask.reshape(n, 1, k), up, w)
-    return out[:, :rows] if pr else out
-
-
-def _same_pads(size: int, stride: int):
-    """XLA SAME-padding geometry for a 3-tap window: (out, lo, hi)."""
-    out = -(-size // stride)
-    total = max((out - 1) * stride + 3 - size, 0)
-    lo = total // 2
-    return out, lo, total - lo
-
-
-def _conv3x3_tile(g, w_flat, *, stride: int, out_dtype):
-    """im2col 3x3 conv of one gated sample g: (H, W, Cin) -> (Ho, Wo, Cout).
-
-    Static-slice decomposition: 9 strided taps concatenated to a
-    (Ho*Wo, 9*Cin) patch matrix, one MXU matmul against the (9*Cin, Cout)
-    flattened weights.  Tap-major (ky, kx, cin) column order matches
-    ``w.reshape(9*Cin, Cout)`` of HWIO weights.
+    Static-slice decomposition: 9 unit-stride taps of the 1-padded sample
+    concatenated to a (H*W, 9*Cin) patch matrix, one MXU matmul against the
+    (9*Cin, Cout) flattened weights.  Tap-major (ky, kx, cin) column order
+    matches ``w.reshape(9*Cin, Cout)`` of HWIO weights.
     """
     h, wd, cin = g.shape
-    ho, plo_h, phi_h = _same_pads(h, stride)
-    wo, plo_w, phi_w = _same_pads(wd, stride)
-    xp = jnp.pad(g, ((plo_h, phi_h), (plo_w, phi_w), (0, 0)))
-    cols = []
-    for ky in range(3):
-        for kx in range(3):
-            sl = jax.lax.slice(
-                xp, (ky, kx, 0),
-                (ky + (ho - 1) * stride + 1, kx + (wo - 1) * stride + 1, cin),
-                (stride, stride, 1))
-            cols.append(sl.reshape(ho * wo, cin))
-    patches = jnp.concatenate(cols, axis=1)
+    xp = jnp.pad(g, ((1, 1), (1, 1), (0, 0)))
+    patches = jnp.concatenate(
+        [xp[ky:ky + h, kx:kx + wd, :].reshape(h * wd, cin)
+         for ky in range(3) for kx in range(3)], axis=1)
     out = jnp.dot(patches, w_flat, preferred_element_type=jnp.float32)
-    return out.astype(out_dtype).reshape(ho, wo, -1)
+    return out.astype(out_dtype).reshape(h, wd, -1)
 
 
-def _masked_act_conv3x3_kernel(x_ref, m_ref, w_ref, o_ref, *, kind: str,
-                               stride: int):
+def _masked_act_conv3x3_kernel(x_ref, m_ref, w_ref, o_ref, *, kind: str):
     x = x_ref[0]                          # (H, W, Cin) — one sample
-    m = m_ref[...].astype(x.dtype)        # (H, W, Cin) — full site mask
-    g = m * _act_tile(x, kind) + (1.0 - m) * x
-    o_ref[0] = _conv3x3_tile(g, w_ref[...], stride=stride,
-                             out_dtype=o_ref.dtype)
+    g = _gate(x, m_ref[...].astype(x.dtype), kind)  # full site mask
+    o_ref[0] = _conv3x3_tile(g, w_ref[...], out_dtype=o_ref.dtype)
 
 
-def _masked_act_conv3x3_kernel_b(x_ref, m_ref, w_ref, o_ref, *, kind: str,
-                                 stride: int):
+def _masked_act_conv3x3_kernel_b(x_ref, m_ref, w_ref, o_ref, *, kind: str):
     x = x_ref[0, 0]                       # (H, W, Cin) of (cand, sample)
-    m = m_ref[0].astype(x.dtype)          # (H, W, Cin) — candidate's mask
-    g = m * _act_tile(x, kind) + (1.0 - m) * x
-    o_ref[0, 0] = _conv3x3_tile(g, w_ref[...], stride=stride,
-                                out_dtype=o_ref.dtype)
+    g = _gate(x, m_ref[0].astype(x.dtype), kind)    # candidate's mask
+    o_ref[0, 0] = _conv3x3_tile(g, w_ref[...], out_dtype=o_ref.dtype)
 
 
 def masked_act_conv3x3(
@@ -395,28 +370,24 @@ def masked_act_conv3x3(
     mask: jax.Array,
     w: jax.Array,
     *,
-    stride: int = 1,
     kind: str = "relu",
     interpret: bool = False,
 ) -> jax.Array:
-    """Fused gate + SAME 3x3 conv: x (B, H, W, Cin), mask (H, W, Cin) — the
-    paper's full per-pixel site mask, shared over the batch — w HWIO
-    (3, 3, Cin, Cout).  Grid is one program per sample."""
+    """Fused gate + SAME stride-1 3x3 conv: x (B, H, W, Cin), mask
+    (H, W, Cin) — the paper's full per-pixel site mask, shared over the
+    batch — w HWIO (3, 3, Cin, Cout).  Grid is one program per sample."""
     b, h, wd, cin = x.shape
     assert mask.shape == (h, wd, cin), (mask.shape, x.shape)
     assert w.shape[:3] == (3, 3, cin), (w.shape, x.shape)
     cout = w.shape[3]
-    ho, _, _ = _same_pads(h, stride)
-    wo, _, _ = _same_pads(wd, stride)
     fn = pl.pallas_call(
-        functools.partial(_masked_act_conv3x3_kernel, kind=kind,
-                          stride=stride),
+        functools.partial(_masked_act_conv3x3_kernel, kind=kind),
         grid=(b,),
         in_specs=[pl.BlockSpec((1, h, wd, cin), lambda i: (i, 0, 0, 0)),
                   pl.BlockSpec((h, wd, cin), lambda i: (0, 0, 0)),
                   pl.BlockSpec((9 * cin, cout), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, ho, wo, cout), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, ho, wo, cout), x.dtype),
+        out_specs=pl.BlockSpec((1, h, wd, cout), lambda i: (i, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, h, wd, cout), x.dtype),
         interpret=interpret)
     return fn(x, mask, w.reshape(9 * cin, cout))
 
@@ -426,7 +397,6 @@ def masked_act_conv3x3_batched(
     mask: jax.Array,
     w: jax.Array,
     *,
-    stride: int = 1,
     kind: str = "relu",
     interpret: bool = False,
 ) -> jax.Array:
@@ -435,18 +405,15 @@ def masked_act_conv3x3_batched(
     n, b, h, wd, cin = x.shape
     assert mask.shape == (n, h, wd, cin), (mask.shape, x.shape)
     cout = w.shape[3]
-    ho, _, _ = _same_pads(h, stride)
-    wo, _, _ = _same_pads(wd, stride)
     fn = pl.pallas_call(
-        functools.partial(_masked_act_conv3x3_kernel_b, kind=kind,
-                          stride=stride),
+        functools.partial(_masked_act_conv3x3_kernel_b, kind=kind),
         grid=(n, b),
         in_specs=[pl.BlockSpec((1, 1, h, wd, cin),
                                lambda c, i: (c, i, 0, 0, 0)),
                   pl.BlockSpec((1, h, wd, cin), lambda c, i: (c, 0, 0, 0)),
                   pl.BlockSpec((9 * cin, cout), lambda c, i: (0, 0))],
-        out_specs=pl.BlockSpec((1, 1, ho, wo, cout),
+        out_specs=pl.BlockSpec((1, 1, h, wd, cout),
                                lambda c, i: (c, i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, b, ho, wo, cout), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, b, h, wd, cout), x.dtype),
         interpret=interpret)
     return fn(x, mask, w.reshape(9 * cin, cout))

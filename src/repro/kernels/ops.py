@@ -1,9 +1,9 @@
 """Public jit'd entry points for the kernels.
 
 Dispatch policy: on TPU backends the Pallas kernels run compiled; elsewhere
-(this CPU container) callers get the pure-jnp oracle unless they explicitly ask
-for ``interpret=True`` (kernel-correctness tests do).  Model code calls these
-wrappers only — it never touches pallas directly.
+(the CPU) callers get the pure-jnp oracle unless they explicitly ask for
+``force_pallas=True`` / ``interpret=True`` (kernel-correctness tests do).
+Model code calls these wrappers only — it never touches pallas directly.
 """
 from __future__ import annotations
 
@@ -30,18 +30,75 @@ def fused_dispatch_enabled() -> bool:
     return _use_pallas()
 
 
+def _per_shard(kernel, args, roles):
+    """``kernel(*args)``, run on each device's shard under a multi-device
+    mesh.
+
+    Mosaic kernels cannot be partitioned automatically, so where a caller
+    traces under ``jax.set_mesh`` (the BCD engines over a mesh) the kernel
+    runs inside ``shard_map``.  ``roles`` names each argument's leading
+    axes: ``"nr"`` (candidates, rows, …), ``"n"`` (candidates, …), ``"r"``
+    (rows, …) or ``None`` (replicated); ``None`` arguments are dropped.
+    The split follows the engines' ``("cand", "batch")`` convention: the
+    candidate axis spans every mesh axis when the mesh size divides it (the
+    joint layout), else the non-``"batch"`` axes with rows over
+    ``"batch"``.  Every kernel here is elementwise or per-sample over those
+    axes, so any split computes the same values; where the operands sit
+    elsewhere, ``shard_map`` moves them.
+    """
+    from jax.sharding import PartitionSpec as P
+    roles = [r for a, r in zip(args, roles) if a is not None]
+    args = [a for a in args if a is not None]
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return kernel(*args)
+    axes = tuple(mesh.axis_names)
+    batch = "batch" if "batch" in axes else None
+    if roles[0] == "nr" and args[0].shape[0] % mesh.size == 0:
+        cand, batch = axes, None
+    else:
+        cand = tuple(a for a in axes if a != "batch")
+    spec = {"nr": P(cand, batch), "n": P(cand), "r": P(batch), None: P()}
+    return jax.shard_map(kernel, in_specs=tuple(spec[r] for r in roles),
+                         out_specs=spec[roles[0]], check_vma=False)(*args)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _masked_act_2d_vjp(x2, mask, poly, kind, interpret):
+    """The Pallas kernel forward, differentiated through the jnp oracle:
+    ``pallas_call`` has no reverse-mode rule, and training, SNL/BCD
+    finetuning and the LM train step differentiate hard-mask forwards."""
+    return _per_shard(
+        lambda *a: masked_act_2d(*a, kind=kind, interpret=interpret),
+        (x2, mask, poly), ("r", None, None))
+
+
+def _masked_act_2d_fwd(x2, mask, poly, kind, interpret):
+    return (_masked_act_2d_vjp(x2, mask, poly, kind, interpret),
+            (x2, mask, poly))
+
+
+def _masked_act_2d_bwd(kind, interpret, res, g):
+    _, vjp = jax.vjp(
+        lambda x, m, p: ref.masked_act_ref(x, m, kind=kind, poly=p), *res)
+    return vjp(g)
+
+
+_masked_act_2d_vjp.defvjp(_masked_act_2d_fwd, _masked_act_2d_bwd)
+
+
 def masked_act(x, mask, *, kind: str = "relu", poly=None,
                force_pallas: bool = False, interpret: bool = False):
     """y = mask·act(x) + (1−mask)·g(x) over (..., C) with per-channel mask.
 
     Accepts any leading shape; flattens to (rows, C) for the kernel.
+    Differentiable: the kernel path's VJP is the oracle's.
     """
     if not (force_pallas or _use_pallas()):
         return ref.masked_act_ref(x, mask, kind=kind, poly=poly)
     shape = x.shape
-    x2 = x.reshape(-1, shape[-1])
-    out = masked_act_2d(x2, mask, poly, kind=kind,
-                        interpret=interpret or not _use_pallas())
+    out = _masked_act_2d_vjp(x.reshape(-1, shape[-1]), mask, poly, kind,
+                             interpret or not _use_pallas())
     return out.reshape(shape)
 
 
@@ -72,9 +129,10 @@ def masked_act_batched(x, masks, *, kind: str = "relu", poly=None,
         m = masks.reshape((n,) + (1,) * (x.ndim - 2) + (masks.shape[-1],))
         return ref.masked_act_ref(x, m, kind=kind, poly=poly)
     shape = x.shape
-    x3 = x.reshape(n, -1, shape[-1])
-    out = masked_act_2d_batched(x3, masks, poly, kind=kind,
-                                interpret=interpret or not _use_pallas())
+    interpret = interpret or not _use_pallas()
+    out = _per_shard(
+        lambda *a: masked_act_2d_batched(*a, kind=kind, interpret=interpret),
+        (x.reshape(n, -1, shape[-1]), masks, poly), ("nr", "n", None))
     return out.reshape(shape)
 
 
@@ -185,10 +243,11 @@ def masked_act_matmul(x, mask, w, mul=None, *, kind: str = "relu",
     if not (force_pallas or _use_pallas()):
         return ref.masked_act_matmul_ref(x, mask, w, mul, kind=kind)
     shape = x.shape
-    x2 = x.reshape(-1, shape[-1])
+    interpret = interpret or not _use_pallas()
     u2 = None if mul is None else mul.reshape(-1, shape[-1])
-    out = masked_act_matmul_2d(x2, mask, w, u2, kind=kind,
-                               interpret=interpret or not _use_pallas())
+    out = _per_shard(
+        lambda *a: masked_act_matmul_2d(*a, kind=kind, interpret=interpret),
+        (x.reshape(-1, shape[-1]), mask, w, u2), ("r", None, None, "r"))
     return out.reshape(shape[:-1] + (w.shape[-1],))
 
 
@@ -207,30 +266,32 @@ def masked_act_matmul_batched(x, masks, w, mul=None, *, kind: str = "relu",
             g = g * mul
         return g @ w
     shape = x.shape
-    x3 = x.reshape(n, -1, shape[-1])
+    interpret = interpret or not _use_pallas()
     u3 = None if mul is None else mul.reshape(n, -1, shape[-1])
-    out = masked_act_matmul_2d_batched(
-        x3, masks, w, u3, kind=kind, interpret=interpret or not _use_pallas())
+    out = _per_shard(
+        lambda *a: masked_act_matmul_2d_batched(*a, kind=kind,
+                                                interpret=interpret),
+        (x.reshape(n, -1, shape[-1]), masks, w, u3), ("nr", "n", None, "nr"))
     return out.reshape(shape[:-1] + (w.shape[-1],))
 
 
-def masked_act_conv3x3(x, mask, w, *, stride: int = 1, kind: str = "relu",
+def masked_act_conv3x3(x, mask, w, *, kind: str = "relu",
                        force_pallas: bool = False, interpret: bool = False):
     """Fused ``conv3x3(gate(x))`` — the suffix megakernel for a CNN's
-    masked ReLU feeding a SAME 3x3 conv.
+    masked ReLU feeding a SAME stride-1 3x3 conv.
 
     x: (B, H, W, Cin); mask: (H, W, Cin) full per-pixel site mask; w HWIO.
     Off-TPU (without force) this is the unfused oracle (gate +
     lax.conv)."""
     if not (force_pallas or _use_pallas()):
-        return ref.masked_act_conv3x3_ref(x, mask, w, stride=stride,
-                                          kind=kind)
-    return _fused_conv3x3(x, mask, w, stride=stride, kind=kind,
-                          interpret=interpret or not _use_pallas())
+        return ref.masked_act_conv3x3_ref(x, mask, w, kind=kind)
+    interpret = interpret or not _use_pallas()
+    return _per_shard(
+        lambda *a: _fused_conv3x3(*a, kind=kind, interpret=interpret),
+        (x, mask, w), ("r", None, None))
 
 
-def masked_act_conv3x3_batched(x, masks, w, *, stride: int = 1,
-                               kind: str = "relu",
+def masked_act_conv3x3_batched(x, masks, w, *, kind: str = "relu",
                                force_pallas: bool = False,
                                interpret: bool = False):
     """Stacked-candidate :func:`masked_act_conv3x3`: x (N, B, H, W, Cin),
@@ -238,15 +299,12 @@ def masked_act_conv3x3_batched(x, masks, w, *, stride: int = 1,
     n = masks.shape[0]
     assert x.shape[0] == n, (x.shape, masks.shape)
     if not (force_pallas or _use_pallas()):
-        m = masks[:, None].astype(x.dtype)
-        g = m * ref._act(x, kind) + (1.0 - m) * x
-        conv = functools.partial(
-            jax.lax.conv_general_dilated, rhs=w,
-            window_strides=(stride, stride), padding="SAME",
-            dimension_numbers=("NHWC", "HWIO", "NHWC"))
-        return jax.vmap(lambda g_i: conv(g_i))(g)
-    return _fused_conv3x3_b(x, masks, w, stride=stride, kind=kind,
-                            interpret=interpret or not _use_pallas())
+        return jax.vmap(lambda x_i, m_i: ref.masked_act_conv3x3_ref(
+            x_i, m_i, w, kind=kind))(x, masks)
+    interpret = interpret or not _use_pallas()
+    return _per_shard(
+        lambda *a: _fused_conv3x3_b(*a, kind=kind, interpret=interpret),
+        (x, masks, w), ("nr", "n", None))
 
 
 # Routed (custom_vmap) fused entries: same contract as
@@ -317,12 +375,12 @@ def masked_act_matmul_routed(x, mask, w, mul=None, *, kind: str = "relu",
 
 
 @functools.lru_cache(maxsize=None)
-def _routed_conv3x3(kind: str, stride: int, interpret: bool):
+def _routed_conv3x3(kind: str, interpret: bool):
     from jax import custom_batching
 
     @custom_batching.custom_vmap
     def f(x, mask, w):
-        return masked_act_conv3x3(x, mask, w, stride=stride, kind=kind,
+        return masked_act_conv3x3(x, mask, w, kind=kind,
                                   force_pallas=True, interpret=interpret)
 
     @f.def_vmap
@@ -334,19 +392,19 @@ def _routed_conv3x3(kind: str, stride: int, interpret: bool):
                 "axis has no stacked-kernel layout")
         x = _bcast_cand(axis_size, xb, x)
         mask = _bcast_cand(axis_size, mb, mask)
-        out = masked_act_conv3x3_batched(x, mask, w, stride=stride,
-                                         kind=kind, force_pallas=True,
+        out = masked_act_conv3x3_batched(x, mask, w, kind=kind,
+                                         force_pallas=True,
                                          interpret=interpret)
         return out, True
     return f
 
 
-def masked_act_conv3x3_routed(x, mask, w, *, stride: int = 1,
-                              kind: str = "relu", interpret: bool = False):
+def masked_act_conv3x3_routed(x, mask, w, *, kind: str = "relu",
+                              interpret: bool = False):
     """:func:`masked_act_conv3x3` with a custom-vmap rule lowering a
     candidate-axis vmap to the stacked fused kernel.  Not differentiable —
     suffix-engine tracing only (``linearize.fused_suffix_route``)."""
-    return _routed_conv3x3(kind, int(stride), bool(interpret))(x, mask, w)
+    return _routed_conv3x3(kind, bool(interpret))(x, mask, w)
 
 
 def rwkv6(r, k, v, w, u, state, *, chunk: int = 32,

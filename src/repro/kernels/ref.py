@@ -52,11 +52,10 @@ def masked_act_matmul_ref(x, mask, w, mul=None, *, kind: str = "relu"):
     return g @ w
 
 
-def masked_act_conv3x3_ref(x, mask, w, *, stride: int = 1,
-                           kind: str = "relu"):
+def masked_act_conv3x3_ref(x, mask, w, *, kind: str = "relu"):
     """Oracle for the fused gate→3x3-conv suffix kernel: the unfused pair —
-    full-site gate then ``lax.conv_general_dilated`` (SAME, NHWC/HWIO),
-    exactly the primitives the CNN's unfused forward traces.
+    full-site gate then ``lax.conv_general_dilated`` (SAME, stride 1,
+    NHWC/HWIO), exactly the primitives the CNN's unfused forward traces.
 
     x: (B, H, W, Cin); mask: (H, W, Cin) per-pixel site mask (batch-shared);
     w: (3, 3, Cin, Cout).
@@ -64,7 +63,7 @@ def masked_act_conv3x3_ref(x, mask, w, *, stride: int = 1,
     m = mask.astype(x.dtype)
     g = m * _act(x, kind) + (1.0 - m) * x
     return jax.lax.conv_general_dilated(
-        g, w, (stride, stride), "SAME",
+        g, w, (1, 1), "SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
 

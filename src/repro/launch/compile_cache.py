@@ -1,17 +1,21 @@
-"""Opt-in JAX persistent compilation cache for sweep/bench restarts.
+"""JAX's persistent compilation cache, kept at one fixed place.
 
-A resumed sweep (or a repeated benchmark run) re-traces the exact same
-jitted evaluators and pays full XLA re-compilation for every one of them —
-on the CPU containers this repo's smoke sweeps run in, recompiles dominate
-restart latency.  :func:`enable` points jax's persistent compilation cache
-at a directory (with the entry-size / compile-time thresholds dropped to
-zero so the small smoke-scale executables qualify), and :func:`hit_counter`
-subscribes to jax's cache telemetry so runners can log how much a restart
-actually reused.
+A resumed sweep, a repeated benchmark run or a second smoke run re-traces
+the same jitted programs and would pay full XLA compilation for each.  The
+cache key includes the directory, so it only hits when every run uses the
+same one:
 
-Wired behind ``--compile-cache DIR`` in ``examples/resnet18_bcd_pipeline.py``
-and ``benchmarks/bench_bcd_eval.py``.  Cache keys include jax/XLA versions
-and compile options, so a stale directory is never incorrect — just cold.
+- ``JAX_COMPILATION_CACHE_DIR``, when set, is the directory (the machine's
+  own setting wins; nothing here overrides it);
+- otherwise ``<checkout>/.jax_cache`` (listed in ``.gitignore``).
+
+:func:`enable` applies that rule; ``chip_smoke.py``,
+``examples/resnet18_bcd_pipeline.py``, ``examples/family_bcd_sweep.py`` and
+``benchmarks/bench_bcd_eval.py`` call it before their first jit.
+:class:`HitCounter` counts cache hits and misses from JAX's monitoring
+events so runners can log how much a restart reused.  Cache keys include
+jax/XLA versions and compile options, so a stale directory is never
+incorrect — just cold.
 """
 from __future__ import annotations
 
@@ -21,29 +25,40 @@ from typing import Dict, Optional
 _HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
+#: The fixed in-checkout cache directory (used when the environment names
+#: none).
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
-def enable(cache_dir: str) -> None:
-    """Turn on jax's persistent compilation cache rooted at ``cache_dir``.
+
+def cache_dir() -> str:
+    """The cache directory: ``JAX_COMPILATION_CACHE_DIR`` if set, else
+    :data:`REPO_CACHE_DIR`."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or REPO_CACHE_DIR
+
+
+def enable() -> str:
+    """Turn on JAX's persistent compilation cache at :func:`cache_dir`;
+    returns the directory.
 
     Safe to call before or after the first jit; creates the directory.
-    Thresholds are zeroed so every executable is cached — the sweeps this
-    serves re-jit many small programs, exactly the population the default
+    Thresholds are zeroed so every executable is cached — sweeps and smoke
+    runs re-jit many small programs, exactly the population the default
     "only big/slow compiles" policy would skip.
     """
     import jax
+    from jax.experimental.compilation_cache import compilation_cache
 
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    d = cache_dir()
+    os.makedirs(d, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", d)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    try:
-        # jax latches "is the cache used?" on the first compile of the
-        # process; if any jit ran before enable(), unlatch it so the new
-        # directory takes effect (no-op on a fresh process)
-        from jax._src import compilation_cache
-        compilation_cache.reset_cache()
-    except Exception:
-        pass
+    # jax latches "is the cache used?" on the first compile of the process;
+    # unlatch it so the directory takes effect even after an earlier jit
+    compilation_cache.reset_cache()
+    return d
 
 
 class HitCounter:
@@ -56,22 +71,18 @@ class HitCounter:
 
     def __init__(self) -> None:
         """Subscribe to the cache-hit/miss monitoring events."""
+        import jax
+
         self.hits = 0
         self.misses = 0
-        self._ok = False
-        try:
-            from jax._src import monitoring
 
-            def _on_event(event: str, **kw) -> None:
-                if event == _HIT_EVENT:
-                    self.hits += 1
-                elif event == _MISS_EVENT:
-                    self.misses += 1
+        def _on_event(event: str, **kw) -> None:
+            if event == _HIT_EVENT:
+                self.hits += 1
+            elif event == _MISS_EVENT:
+                self.misses += 1
 
-            monitoring.register_event_listener(_on_event)
-            self._ok = True
-        except Exception:           # jax internals moved: count nothing,
-            pass                    # never break the run for telemetry
+        jax.monitoring.register_event_listener(_on_event)
 
     def summary(self) -> Dict[str, int]:
         """``{"hits": N, "misses": M}`` observed since construction."""
@@ -79,8 +90,6 @@ class HitCounter:
 
     def log_line(self) -> str:
         """One human-readable line for the runner's exit log."""
-        if not self._ok:
-            return "[compile-cache] hit telemetry unavailable in this jax"
         total = self.hits + self.misses
         return (f"[compile-cache] {self.hits}/{total} compile requests "
                 f"served from the persistent cache")

@@ -128,7 +128,9 @@ def run_cell(arch_id: str, shape_name: str, *, multi_pod: bool,
                                           remat=remat)
     roof = rl.Roofline(
         arch=arch_id, shape=shape_name, mesh=meta["mesh"],
-        chips=meta["chips"], flops_per_device=flops_dev,
+        chips=meta["chips"],
+        device_kind="TPU v5 lite",   # the v5e pods make_production_mesh models
+        flops_per_device=flops_dev,
         bytes_per_device=bytes_dev,
         collective_bytes_global=coll.bytes_moved_global,
         model_flops_global=rl.model_flops(cfg, shape, meta["mode"]),

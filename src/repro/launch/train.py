@@ -57,7 +57,7 @@ def main(argv=None):
     masks = M.as_device(linearize.init_masks(model.mask_sites()))
     sl = host_slice(args.global_batch)
 
-    with jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh:
+    with jax.set_mesh(mesh):
         step_jit = train_lib.jit_train_step(model, opt, mesh, tcfg)
 
         def init_state():

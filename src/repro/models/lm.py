@@ -60,33 +60,6 @@ def _sub(tree: Dict, prefix: str) -> Dict:
             if k.startswith(prefix + ".")}
 
 
-def _register_barrier_rules():
-    """``optimization_barrier`` ships without vmap/AD rules in jax 0.4.x;
-    the fence sits on paths the candidate engines vmap (stacked masks) and
-    training differentiates, so register the trivial ones: batching maps
-    the barrier over the batched operands, and the JVP passes tangents
-    through unfenced (the fence constrains compilation, not math)."""
-    from jax.interpreters import ad, batching
-    from jax._src.lax import lax as _lax
-    p = getattr(_lax, "optimization_barrier_p", None)
-    if p is None:                           # newer jax: rules built in
-        return
-    if p not in batching.primitive_batchers:
-        batching.primitive_batchers[p] = lambda args, dims: (
-            p.bind(*args), dims)
-    if p not in ad.primitive_jvps:
-        def _jvp(primals, tangents):
-            outs = p.bind(*primals)
-            tans = [jnp.zeros(o.shape, o.dtype)
-                    if isinstance(t, ad.Zero) else t
-                    for o, t in zip(outs, tangents)]
-            return outs, tans
-        ad.primitive_jvps[p] = _jvp
-
-
-_register_barrier_rules()
-
-
 def _fence(x):
     """Segment-boundary compilation fence (``lax.optimization_barrier``).
 

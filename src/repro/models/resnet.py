@@ -159,8 +159,8 @@ class CNN:
             x, masks[name], site,
             poly=None if poly is None else poly.get(name), soft=soft)
 
-    def _relu_conv(self, x, masks, name, ply, soft, w, stride=1):
-        """Masked ReLU at ``name`` feeding a 3x3 conv.  Under
+    def _relu_conv(self, x, masks, name, ply, soft, w):
+        """Masked ReLU at ``name`` feeding a stride-1 3x3 conv.  Under
         ``linearize.fused_suffix_route`` (the suffix engine traces its
         suffix jits with it armed) hard-mask sites run gate + conv as one
         Pallas megakernel (``kernels.ops.masked_act_conv3x3_routed``) — the
@@ -174,9 +174,8 @@ class CNN:
             interpret = mode == "interpret"
             if interpret or ops.fused_dispatch_enabled():
                 return ops.masked_act_conv3x3_routed(
-                    x, masks[name], w, stride=stride, kind="relu",
-                    interpret=interpret)
-        return _conv(self._relu(x, masks, name, ply, soft), w, stride)
+                    x, masks[name], w, kind="relu", interpret=interpret)
+        return _conv(self._relu(x, masks, name, ply, soft), w)
 
     def _stem_pre(self, p, x):
         """Mask-independent stem fold: input -> the first gate's

@@ -366,6 +366,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np, jax
 from repro.core import engine, linearize, masks as M
 from repro.data import ImageDatasetCfg, SyntheticImages
+from jax.sharding import PartitionSpec as P
 from repro.launch import mesh as mesh_lib
 from repro.models.resnet import CNN, CNNConfig
 
@@ -389,9 +390,9 @@ seq = engine.SequentialEvaluator(model.make_eval_acc(params, batch))
 # take the cand-only layout (batch axis splits the forward); a full chunk
 # takes the joint layout over both axes
 n2, s2 = ev._chunk_sharding(2)
-assert (n2, tuple(s2.spec)) == (2, (("cand",),)), (n2, s2.spec)
+assert (n2, s2.spec) == (2, P("cand")), (n2, s2.spec)
 n8, s8 = ev._chunk_sharding(8)
-assert (n8, tuple(s8.spec)) == (8, (("cand", "batch"),)), (n8, s8.spec)
+assert (n8, s8.spec) == (8, P(("cand", "batch"))), (n8, s8.spec)
 
 small = M.slice_stacked(stacked, 0, 2)
 np.testing.assert_allclose(ev.evaluate(small), seq.evaluate(small), atol=1e-4)
@@ -427,6 +428,81 @@ def test_joint_cand_batch_sharding_on_forced_multi_device_mesh():
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "JOINT_OK" in out.stdout
+
+
+_KERNEL_MESH_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np, jax
+from jax.experimental import pallas as pl
+from repro.core import engine, linearize, masks as M
+from repro.data import ImageDatasetCfg, SyntheticImages
+from repro.kernels import ops
+from repro.launch import mesh as mesh_lib
+from repro.models.resnet import CNN, CNNConfig
+
+model = CNN(CNNConfig("tiny", 4, 8, ((4, 1, 1), (8, 1, 2)), stem_channels=4))
+data = SyntheticImages(ImageDatasetCfg(n_classes=4, image_size=8,
+                                       n_train=64, n_test=32))
+params = model.init(jax.random.PRNGKey(0))
+batch = data.train_eval_set(16)
+masks0 = linearize.init_masks(model.mask_sites())
+stacked = M.sample_removal_blocks(np.random.default_rng(0), masks0, 8, 6)
+deep = model.site_order()[-1]
+sited = M.materialize_candidates(masks0, M.sample_removal_indices_within(
+    np.random.default_rng(1), masks0, 4, 6, [deep]))
+seq = engine.SequentialEvaluator(model.make_eval_acc(params, batch))
+want, want_deep = seq.evaluate(stacked), seq.evaluate(sited)
+
+# the TPU dispatch on host devices: every hard-mask site and fused suffix
+# site runs its Pallas kernel (interpret mode), per device shard
+_pallas_call = pl.pallas_call
+pl.pallas_call = lambda *a, **k: _pallas_call(*a, **{**k, "interpret": True})
+ops._use_pallas = lambda: True
+shard_meshes = []
+_per_shard = ops._per_shard
+def counting(kernel, args, roles):
+    shard_meshes.append(jax.sharding.get_abstract_mesh().size)
+    return _per_shard(kernel, args, roles)
+ops._per_shard = counting
+
+mesh = mesh_lib.make_cand_batch_mesh(cand=2, batch=2)
+ctx = {"params": params, "batch": {k: np.asarray(v) for k, v in batch.items()}}
+specs = engine.context_batch_specs(ctx)
+ev = engine.ShardedEvaluator(model.make_joint_eval_fn(), mesh, context=ctx,
+                             context_specs=specs)
+# a full chunk (joint layout) and a 2-candidate chunk (cand-only layout)
+np.testing.assert_allclose(ev.evaluate(stacked), want, atol=1e-4)
+np.testing.assert_allclose(ev.evaluate(M.slice_stacked(stacked, 0, 2)),
+                           want[:2], atol=1e-4)
+sfx = engine.make_evaluator("suffix", split=model.make_suffix_eval_fns(),
+                            mesh=mesh, context=ctx, context_specs=specs,
+                            pad_to=4, fused_kernels=True)
+sfx.begin_step(masks0)
+np.testing.assert_allclose(sfx.evaluate(engine.SitedChunk(deep, sited)),
+                           want_deep, atol=1e-4)
+assert shard_meshes and set(shard_meshes) == {4}, shard_meshes
+print("KERNEL_MESH_OK")
+"""
+
+
+def test_kernels_run_per_shard_on_forced_multi_device_mesh():
+    """The TPU kernel dispatch under a 4-device ("cand", "batch") mesh:
+    Mosaic kernels cannot be partitioned automatically, so every kernel
+    runs per device shard (``ops._per_shard``), in the sharded engine's
+    joint and cand-only layouts and in the suffix engine's fused suffix —
+    and still matches the sequential reference."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + \
+        env.get("PYTHONPATH", "")
+    out = subprocess.run([sys.executable, "-c", _KERNEL_MESH_SCRIPT],
+                         env=env, capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "KERNEL_MESH_OK" in out.stdout
 
 
 # ------------------------------------------------- prefetch auto-tuning

@@ -49,6 +49,33 @@ def test_masked_act_poly_matches_oracle(kind):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("kind", KINDS)
+@pytest.mark.parametrize("with_poly", [False, True])
+def test_masked_act_kernel_grad_matches_oracle_grad(kind, with_poly):
+    """The hard-mask kernel entry differentiates (training, SNL/BCD
+    finetune and the LM train step run it on the TPU): its gradients with
+    respect to x, mask and poly are the oracle's."""
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.normal(size=(2, 9, 130)).astype(np.float32))
+    m = jnp.asarray((rng.random(130) > 0.5).astype(np.float32))
+    poly = (jnp.asarray(rng.normal(size=(3, 130)).astype(np.float32) * 0.1)
+            if with_poly else None)
+    cot = jnp.asarray(rng.normal(size=x.shape).astype(np.float32))
+
+    def loss(f):
+        return lambda x, m, p: jnp.sum(f(x, m, p) * cot)
+
+    kernel = loss(lambda x, m, p: ops.masked_act(
+        x, m, kind=kind, poly=p, force_pallas=True, interpret=True))
+    oracle = loss(lambda x, m, p: ref.masked_act_ref(x, m, kind=kind,
+                                                     poly=p))
+    argnums = (0, 1, 2) if with_poly else (0, 1)
+    got = jax.jit(jax.grad(kernel, argnums))(x, m, poly)
+    want = jax.jit(jax.grad(oracle, argnums))(x, m, poly)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6)
+
+
 if HAS_HYPOTHESIS:
     @given(rows=st.integers(1, 64), cols=st.integers(1, 300),
            frac=st.floats(0, 1), seed=st.integers(0, 2**31 - 1))

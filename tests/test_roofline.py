@@ -111,17 +111,35 @@ def test_model_flops_6nd():
 
 
 def test_roofline_bottleneck_and_fraction():
+    peak = rl.peaks("TPU v5 lite").flops
     r = rl.Roofline(arch="a", shape="s", mesh="m", chips=256,
+                    device_kind="TPU v5 lite",
                     flops_per_device=0, bytes_per_device=0,
                     collective_bytes_global=256 * 50e9,   # exactly 1s
-                    model_flops_global=256 * rl.PEAK_FLOPS * 0.25,
-                    analytic_flops_global=256 * rl.PEAK_FLOPS * 0.5,
+                    model_flops_global=256 * peak * 0.25,
+                    analytic_flops_global=256 * peak * 0.5,
                     analytic_bytes_global=1.0)
     assert r.t_collective == pytest.approx(1.0)
     assert r.t_compute == pytest.approx(0.5)
     assert r.bottleneck == "collective"
     assert r.roofline_fraction == pytest.approx(0.25)
     assert r.useful_flops_ratio == pytest.approx(0.5)
+
+
+def test_peaks_table_is_keyed_by_device_kind():
+    """v5e's published peaks are in the table; any other device kind is an
+    error rather than a silent v5e default."""
+    v5e = rl.peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw) == (197e12, 819e9)
+    for kind in ("cpu", "TPU v4", ""):
+        with pytest.raises(ValueError, match="no published peaks"):
+            rl.peaks(kind)
+    r = rl.Roofline(arch="a", shape="s", mesh="m", chips=1,
+                    device_kind="cpu", flops_per_device=1.0,
+                    bytes_per_device=1.0, collective_bytes_global=0.0,
+                    model_flops_global=1.0)
+    with pytest.raises(ValueError, match="no published peaks"):
+        r.row()
 
 
 # --------------------------------------------- measured suffix cost model

@@ -18,6 +18,8 @@ Three layers of guarantees under test:
    batch-sharded (never gathered) on a forced 4-device
    ``("cand", "batch")`` mesh.
 """
+import os
+
 import numpy as np
 import jax
 import pytest
@@ -244,13 +246,13 @@ if HAS_HYPOTHESIS:
                 pattern=(Block("dense"),), head_blocks=(Block("dense"),),
                 dtype="float32")
             model = LM(cfg)
-            assert cfg.n_repeats == 4
+            assert cfg.n_repeats == 5      # 6 layers, one head block
             _PROP_LM["model"] = model
             _PROP_LM["params"] = model.init(jax.random.PRNGKey(0))
         return _PROP_LM["model"], _PROP_LM["params"]
 
     @settings(deadline=None, max_examples=10)
-    @given(r=st.integers(min_value=0, max_value=3),
+    @given(r=st.integers(min_value=0, max_value=4),
            seed=st.integers(min_value=0, max_value=2 ** 16))
     def test_property_cut_at_any_repeat_matches_unsegmented(r, seed):
         """Cutting the scanned stack at an arbitrary repeat r is bitwise
@@ -805,13 +807,30 @@ def test_suffix_prefix_cache_batch_sharded_on_forced_mesh():
 # -------------------------------------------------------- compile cache
 
 
-def test_compile_cache_enable_and_hit_counter(tmp_path):
+@pytest.fixture
+def restore_compile_cache():
+    """Put JAX's persistent-cache settings back after a test enables it,
+    so later tests in the same worker do not write to its directory."""
+    from jax.experimental.compilation_cache import compilation_cache
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_persistent_cache_min_compile_time_secs")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    yield
+    for k, v in saved.items():
+        jax.config.update(k, v)
+    compilation_cache.reset_cache()
+
+
+def test_compile_cache_enable_and_hit_counter(tmp_path, monkeypatch,
+                                              restore_compile_cache):
     """enable() + clear_caches() round trip: the second compile of an
     identical program is served from the persistent cache and the counter
     sees it."""
     from repro.launch import compile_cache
     d = str(tmp_path / "cc")
-    compile_cache.enable(d)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    assert compile_cache.enable() == d
     ctr = compile_cache.hit_counter()
     import jax.numpy as jnp
     f = jax.jit(lambda x: (x * 3).sum())
@@ -824,3 +843,32 @@ def test_compile_cache_enable_and_hit_counter(tmp_path):
     assert "served from the persistent cache" in ctr.log_line()
     assert set(ctr.summary()) == {"hits", "misses"}
     assert compile_cache.entry_count(None) == 0
+
+
+def test_compile_cache_env_dir_is_the_directory(tmp_path, monkeypatch,
+                                                restore_compile_cache):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache directory and
+    nothing in code replaces it."""
+    from repro.launch import compile_cache
+    d = str(tmp_path / "from_env")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    assert compile_cache.cache_dir() == d
+    assert compile_cache.enable() == d
+    assert jax.config.jax_compilation_cache_dir == d
+    assert os.path.isdir(d)
+
+
+def test_compile_cache_defaults_to_fixed_checkout_path(
+        monkeypatch, restore_compile_cache):
+    """Without the variable the cache is <checkout>/.jax_cache — one fixed
+    path (the directory is part of the cache key), listed in .gitignore."""
+    from repro.launch import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(root, ".jax_cache")
+    assert compile_cache.REPO_CACHE_DIR == want
+    assert compile_cache.cache_dir() == want
+    assert compile_cache.enable() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    with open(os.path.join(root, ".gitignore")) as fh:
+        assert ".jax_cache/" in fh.read().split()
