@@ -67,7 +67,7 @@ import jax
 
 from repro.analysis.roofline import SuffixCostModel
 from repro.configs import ARCH_IDS, get_config
-from repro.core import engine, linearize, masks as M
+from repro.core import engine, linearize, masks as M, tracing
 from repro.data import ImageDatasetCfg, MarkovTokens, SyntheticImages
 from repro.launch import compile_cache, mesh as mesh_lib
 from repro.models.lm import LM
@@ -221,8 +221,13 @@ def main():
     args = ap.parse_args()
 
     compile_cache.enable()
-    counter = compile_cache.hit_counter()
+    with tracing.recording() as rec:
+        report = measure(args)
+    print(compile_cache.log_line(rec.counts))
+    return report
 
+
+def measure(args):
     if args.arch == "resnet":
         model, params, batch, masks0 = build_pipeline(
             eval_batch=args.eval_batch)
@@ -435,7 +440,6 @@ def main():
           f"{report['speedup_suffix_vs_batched_mean']:.2f}x, aggregate "
           f"{report['speedup_suffix_vs_batched_aggregate']:.2f}x"
           f"  -> {args.out}")
-    print(counter.log_line())
     return report
 
 

@@ -43,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import ARCH_IDS, get_config
-from repro.core import bcd, engine, linearize, masks as M, runner
+from repro.core import bcd, engine, linearize, masks as M, runner, tracing
 from repro.core.snl import SNLConfig, finetune, run_snl
 from repro.data import MarkovTokens
 from repro.launch import compile_cache
@@ -235,8 +235,13 @@ def record_midscan_speedup(args, model, masks, params, eval_b):
 def main():
     args = parse_args()
     compile_cache.enable()
-    counter = compile_cache.hit_counter()
+    with tracing.recording() as rec:
+        payload = run(args)
+    print(compile_cache.log_line(rec.counts))
+    return payload
 
+
+def run(args):
     cfg = get_config(args.arch).reduced()
     model = LM(cfg)
     mt = MarkovTokens(cfg.vocab, seed=0)
@@ -319,7 +324,6 @@ def main():
     if coordinator.is_writer:
         record_midscan_speedup(args, model, payload["final_masks"],
                                holder["params"], eval_b)
-    print(counter.log_line())
     return payload
 
 

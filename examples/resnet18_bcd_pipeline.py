@@ -57,7 +57,7 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro.core import bcd, linearize, masks as M, runner
+from repro.core import bcd, linearize, masks as M, runner, tracing
 from repro.core.snl import SNLConfig, finetune, run_snl
 from repro.data import ImageDatasetCfg, SyntheticImages
 from repro.launch import compile_cache
@@ -330,12 +330,12 @@ def main():
     # executables instead of paying re-jit (the cache key covers jax/XLA
     # versions + options, so stale dirs are cold, not wrong)
     compile_cache.enable()
-    counter = compile_cache.hit_counter()
-    if args.sweep is not None:
-        run_sweep_mode(args)
-    else:
-        run_head_to_head(args)
-    print(counter.log_line())
+    with tracing.recording() as rec:
+        if args.sweep is not None:
+            run_sweep_mode(args)
+        else:
+            run_head_to_head(args)
+    print(compile_cache.log_line(rec.counts))
 
 
 if __name__ == "__main__":

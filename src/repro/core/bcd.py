@@ -20,6 +20,7 @@ from typing import Callable, List, Optional, Tuple
 import numpy as np
 
 from . import masks as M
+from . import tracing
 
 
 @dataclasses.dataclass
@@ -190,10 +191,11 @@ def _select_block(
     """
     from . import engine
 
-    moves = M.sample_moves(rng, masks, drc_t, cfg.rt, kinds=cfg.moves,
-                           proposal=cfg.proposal, move_stats=move_stats,
-                           max_remove=max_remove)
-    flat, layout = M._flatten(masks)     # once per step, not per chunk
+    with tracing.span("bcd.sample"):
+        moves = M.sample_moves(rng, masks, drc_t, cfg.rt, kinds=cfg.moves,
+                               proposal=cfg.proposal, move_stats=move_stats,
+                               max_remove=max_remove)
+        flat, layout = M._flatten(masks)     # once per step, not per chunk
     # Backends may cap the chunk (engine.effective_chunk); selection is
     # invariant under chunking either way.
     chunk_size = engine.effective_chunk(evaluator, cfg.chunk_size)
@@ -316,35 +318,39 @@ def bcd_steps(
         evaluator = engine.SequentialEvaluator(eval_acc)
     t_cap = total_steps(state.b_ref, cfg)
     while state.step < t_cap:
-        t0 = time.perf_counter()
-        budget = M.relu_cost(state.masks)
-        drc_t = min(cfg.drc, budget - cfg.b_target)
-        if drc_t <= 0:
-            return
-        acc_base = float(eval_acc(state.masks))
-        masks, best_idx, best_drop, n, found, moves = _select_block(
-            state.masks, cfg, state.rng, evaluator, drc_t, acc_base,
-            move_stats=state.move_stats,
-            max_remove=budget - cfg.b_target)
-        _, layout = M._flatten(state.masks)
-        record_move_stats(state.move_stats, moves, best_idx, layout)
-        state.masks = masks
-        acc_after = None
-        if finetune is not None and cfg.finetune_every_step:
-            finetune(state.masks)
-            acc_after = float(eval_acc(state.masks))
-        log = BCDStepLog(
-            step=state.step, budget_before=budget,
-            budget_after=M.relu_cost(state.masks),
-            trials=n, found_early=found, best_drop=best_drop,
-            acc_before=acc_base, acc_after_finetune=acc_after,
-            wall_s=time.perf_counter() - t0,
-            move_kind=moves[best_idx].kind)
-        state.step += 1
-        state.history.append(log)
-        if keep_snapshots:
-            state.snapshots.append(
-                {k: v.copy() for k, v in state.masks.items()})
+        with tracing.span("bcd.step"):
+            t0 = time.perf_counter()
+            budget = M.relu_cost(state.masks)
+            drc_t = min(cfg.drc, budget - cfg.b_target)
+            if drc_t <= 0:
+                return
+            with tracing.span("bcd.base_eval"):
+                acc_base = float(eval_acc(state.masks))
+            with tracing.span("bcd.select"):
+                masks, best_idx, best_drop, n, found, moves = _select_block(
+                    state.masks, cfg, state.rng, evaluator, drc_t, acc_base,
+                    move_stats=state.move_stats,
+                    max_remove=budget - cfg.b_target)
+            _, layout = M._flatten(state.masks)
+            record_move_stats(state.move_stats, moves, best_idx, layout)
+            state.masks = masks
+            acc_after = None
+            if finetune is not None and cfg.finetune_every_step:
+                finetune(state.masks)
+                with tracing.span("bcd.post_eval"):
+                    acc_after = float(eval_acc(state.masks))
+            log = BCDStepLog(
+                step=state.step, budget_before=budget,
+                budget_after=M.relu_cost(state.masks),
+                trials=n, found_early=found, best_drop=best_drop,
+                acc_before=acc_base, acc_after_finetune=acc_after,
+                wall_s=time.perf_counter() - t0,
+                move_kind=moves[best_idx].kind)
+            state.step += 1
+            state.history.append(log)
+            if keep_snapshots:
+                state.snapshots.append(
+                    {k: v.copy() for k, v in state.masks.items()})
         if verbose:
             print(f"[bcd] t={log.step} budget "
                   f"{log.budget_before}->{log.budget_after}"

@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 
 from . import masks as M
+from . import tracing
 
 
 def _donate_mask_arg():
@@ -337,7 +338,8 @@ class BatchedEvaluator:
 
     def evaluate_staged(self, staged: StagedChunk) -> np.ndarray:
         """Block on a staged chunk's result and strip its padding."""
-        return np.asarray(staged.accs, dtype=np.float64)[:staged.n]
+        with tracing.span("engine.wait"):
+            return np.asarray(staged.accs, dtype=np.float64)[:staged.n]
 
     def evaluate(self, stacked: M.MaskTree) -> np.ndarray:
         return self.evaluate_staged(self.stage(stacked))
@@ -575,7 +577,9 @@ class PrefixTrie:
     entry last (an entry that alone exceeds the budget is dropped too — the
     caller still holds the returned reference for its in-flight dispatches).
     ``budget_bytes=None`` disables eviction.  Counters (``hits`` /
-    ``extensions`` / ``misses`` / ``evictions``) feed the bench report.
+    ``extensions`` / ``misses`` / ``evictions``) are bumped through
+    :meth:`_note`, which also counts them as ``engine.prefix_<counter>``
+    while ``core.tracing`` records.
     """
 
     def __init__(self, budget_bytes: Optional[int] = None):
@@ -635,6 +639,10 @@ class PrefixTrie:
         self._nbytes.clear()
         self._tick.clear()
 
+    def _note(self, kind: str) -> None:
+        setattr(self, kind, getattr(self, kind) + 1)
+        tracing.count("engine.prefix_" + kind)
+
     def _drop(self, depth: int) -> None:
         del self._entries[depth]
         del self._nbytes[depth]
@@ -648,7 +656,7 @@ class PrefixTrie:
                              key=lambda d: (self._tick[d], d))
             victim = victims[0] if victims else newest
             self._drop(victim)
-            self.evictions += 1
+            self._note("evictions")
             if victim == newest:
                 break
 
@@ -891,30 +899,32 @@ class SuffixEvaluator:
         seg = self._split.site_segment[site]
         hit = self.trie.lookup(seg)
         if hit is not None and hit[0] == seg:
-            self.trie.hits += 1
+            self.trie._note("hits")
             return hit[1]
-        base = self._base_masks_dev()
-        if hit is not None and self._split.prefix_ext is not None:
-            # deepest-ancestor extension: fold only segments [hit_depth, seg)
-            from_seg, ancestor = hit
-            key = (from_seg, seg)
-            jit_fn = self._prefix_ext_jits.get(key)
-            if jit_fn is None:
-                jit_fn = jax.jit(functools.partial(
-                    self._split.prefix_ext, self._segment_site[from_seg],
-                    self._segment_site[seg]))
-                self._prefix_ext_jits[key] = jit_fn
-            cached = self._pin(jit_fn(base, ancestor, self.context))
-            self.trie.extensions += 1
-        else:
-            jit_fn = self._prefix_jits.get(seg)
-            if jit_fn is None:
-                jit_fn = jax.jit(functools.partial(
-                    self._split.prefix, self._segment_site[seg]))
-                self._prefix_jits[seg] = jit_fn
-            cached = self._pin(jit_fn(base, self.context))
-            self.trie.misses += 1
-        self.trie.insert(seg, cached)
+        with tracing.span("engine.prefix"):
+            base = self._base_masks_dev()
+            if hit is not None and self._split.prefix_ext is not None:
+                # deepest-ancestor extension: fold only segments
+                # [hit_depth, seg)
+                from_seg, ancestor = hit
+                key = (from_seg, seg)
+                jit_fn = self._prefix_ext_jits.get(key)
+                if jit_fn is None:
+                    jit_fn = jax.jit(functools.partial(
+                        self._split.prefix_ext, self._segment_site[from_seg],
+                        self._segment_site[seg]))
+                    self._prefix_ext_jits[key] = jit_fn
+                cached = self._pin(jit_fn(base, ancestor, self.context))
+                self.trie._note("extensions")
+            else:
+                jit_fn = self._prefix_jits.get(seg)
+                if jit_fn is None:
+                    jit_fn = jax.jit(functools.partial(
+                        self._split.prefix, self._segment_site[seg]))
+                    self._prefix_jits[seg] = jit_fn
+                cached = self._pin(jit_fn(base, self.context))
+                self.trie._note("misses")
+            self.trie.insert(seg, cached)
         return cached
 
     def _suffix_for(self, site: str):
@@ -932,23 +942,24 @@ class SuffixEvaluator:
         return jit_fn
 
     def _stage_sited(self, site: str, stacked: M.MaskTree) -> StagedChunk:
-        n = M.stacked_len(stacked)
-        # ship only the masks the suffix consumes (sites at/after the cut)
-        sub = {k: stacked[k] for k in self._split.suffix_sites(site)}
-        n_pad = max(n, self._pad_to or 0)
-        if self._mesh is not None:
-            n_pad += -n_pad % self._cand
-        if n_pad > n:
-            sub = M.pad_stacked(sub, n_pad)
-        put = (jax.device_put if self._mesh is None else
-               functools.partial(jax.device_put,
-                                 device=self._cand_sharding))
-        batch = {k: put(np.asarray(v, dtype=np.float32))
-                 for k, v in sub.items()}
-        with _mesh_scope(self._mesh):
-            cached = self._prefix_for(site)
-            accs = self._suffix_for(site)(batch, cached, self.context)
-        return StagedChunk(n, accs)
+        with tracing.span("engine.stage"):
+            n = M.stacked_len(stacked)
+            # ship only the masks the suffix consumes (sites at/after the cut)
+            sub = {k: stacked[k] for k in self._split.suffix_sites(site)}
+            n_pad = max(n, self._pad_to or 0)
+            if self._mesh is not None:
+                n_pad += -n_pad % self._cand
+            if n_pad > n:
+                sub = M.pad_stacked(sub, n_pad)
+            put = (jax.device_put if self._mesh is None else
+                   functools.partial(jax.device_put,
+                                     device=self._cand_sharding))
+            batch = {k: put(np.asarray(v, dtype=np.float32))
+                     for k, v in sub.items()}
+            with _mesh_scope(self._mesh):
+                cached = self._prefix_for(site)
+                accs = self._suffix_for(site)(batch, cached, self.context)
+            return StagedChunk(n, accs)
 
     # ------------------------------------------------------------- protocol
 

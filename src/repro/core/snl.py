@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.training import optimizer as opt_lib
 from . import masks as M
+from . import tracing
 
 
 @dataclasses.dataclass
@@ -118,26 +119,27 @@ def finetune(params, hard_masks: M.MaskTree, loss_fn, batches,
              *, steps: int, lr: float = 1e-3, start_step: int = 0,
              use_adam: bool = False):
     """Finetune θ under fixed binary masks (shared by SNL / BCD / AutoReP)."""
-    opt = (opt_lib.adamw(lr=lr, schedule=opt_lib.cosine(lr, steps))
-           if use_adam else
-           opt_lib.sgd(lr=lr, momentum=0.9,
-                       schedule=opt_lib.cosine(lr, steps)))
-    masks_dev = M.as_device(hard_masks)
+    with tracing.span("snl.finetune"):
+        opt = (opt_lib.adamw(lr=lr, schedule=opt_lib.cosine(lr, steps))
+               if use_adam else
+               opt_lib.sgd(lr=lr, momentum=0.9,
+                           schedule=opt_lib.cosine(lr, steps)))
+        masks_dev = M.as_device(hard_masks)
 
-    # masks are a jit argument, not a closure constant: every call then
-    # lowers the same program, which the persistent compilation cache serves
-    # instead of recompiling per BCD step
-    @jax.jit
-    def step(p, ostate, batch, masks):
-        def l(p):
-            loss, _ = loss_fn(p, masks, batch, False)
-            return loss
-        grads = jax.grad(l)(p)
-        updates, ostate = opt.update(grads, ostate, p)
-        return opt_lib.apply_updates(p, updates), ostate
+        # masks are a jit argument, not a closure constant: every call
+        # then lowers the same program, which the persistent compilation
+        # cache serves instead of recompiling per BCD step
+        @jax.jit
+        def step(p, ostate, batch, masks):
+            def l(p):
+                loss, _ = loss_fn(p, masks, batch, False)
+                return loss
+            grads = jax.grad(l)(p)
+            updates, ostate = opt.update(grads, ostate, p)
+            return opt_lib.apply_updates(p, updates), ostate
 
-    ostate = opt.init(params)
-    for i in range(steps):
-        params, ostate = step(params, ostate, batches(start_step + i),
-                              masks_dev)
-    return params
+        ostate = opt.init(params)
+        for i in range(steps):
+            params, ostate = step(params, ostate, batches(start_step + i),
+                                  masks_dev)
+        return params

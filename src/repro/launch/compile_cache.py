@@ -11,19 +11,16 @@ same one:
 
 :func:`enable` applies that rule; ``chip_smoke.py``,
 ``examples/resnet18_bcd_pipeline.py``, ``examples/family_bcd_sweep.py`` and
-``benchmarks/bench_bcd_eval.py`` call it before their first jit.
-:class:`HitCounter` counts cache hits and misses from JAX's monitoring
-events so runners can log how much a restart reused.  Cache keys include
-jax/XLA versions and compile options, so a stale directory is never
-incorrect — just cold.
+``benchmarks/bench_bcd_eval.py`` call it before their first jit.  The
+runners count cache hits and misses with ``core.tracing.recording()`` and
+print :func:`log_line` of its counts, so a restart shows how much it
+reused.  Cache keys include jax/XLA versions and compile options, so a
+stale directory is never incorrect — just cold.
 """
 from __future__ import annotations
 
 import os
-from typing import Dict, Optional
-
-_HIT_EVENT = "/jax/compilation_cache/cache_hits"
-_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+from typing import Mapping, Optional
 
 #: The fixed in-checkout cache directory (used when the environment names
 #: none).
@@ -61,49 +58,18 @@ def enable() -> str:
     return d
 
 
-class HitCounter:
-    """Counts persistent-cache hits/misses via jax's monitoring events.
-
-    jax only exposes the persistent compilation cache's effectiveness as
-    telemetry events; this adapter turns them into a queryable counter so
-    runners can print "N of M compiles served from cache" at exit.
-    """
-
-    def __init__(self) -> None:
-        """Subscribe to the cache-hit/miss monitoring events."""
-        import jax
-
-        self.hits = 0
-        self.misses = 0
-
-        def _on_event(event: str, **kw) -> None:
-            if event == _HIT_EVENT:
-                self.hits += 1
-            elif event == _MISS_EVENT:
-                self.misses += 1
-
-        jax.monitoring.register_event_listener(_on_event)
-
-    def summary(self) -> Dict[str, int]:
-        """``{"hits": N, "misses": M}`` observed since construction."""
-        return {"hits": self.hits, "misses": self.misses}
-
-    def log_line(self) -> str:
-        """One human-readable line for the runner's exit log."""
-        total = self.hits + self.misses
-        return (f"[compile-cache] {self.hits}/{total} compile requests "
-                f"served from the persistent cache")
-
-
-def hit_counter() -> HitCounter:
-    """Construct a :class:`HitCounter` (call before the jits you care
-    about; events fired earlier are not replayed)."""
-    return HitCounter()
+def log_line(counts: Mapping[str, int]) -> str:
+    """One line for a runner's exit log from a ``core.tracing`` recorder's
+    ``counts``: how many compile requests the persistent cache served."""
+    hits = counts.get("jax.cache_hit", 0)
+    total = hits + counts.get("jax.cache_miss", 0)
+    return (f"[compile-cache] {hits}/{total} compile requests served from "
+            "the persistent cache")
 
 
 def entry_count(cache_dir: Optional[str]) -> int:
     """Number of cached executables under ``cache_dir`` (0 if unset or
-    missing) — a coarse cross-process complement to :class:`HitCounter`.
+    missing) — a coarse cross-process complement to :func:`log_line`.
     """
     if not cache_dir or not os.path.isdir(cache_dir):
         return 0

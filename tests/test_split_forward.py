@@ -825,23 +825,26 @@ def restore_compile_cache():
 def test_compile_cache_enable_and_hit_counter(tmp_path, monkeypatch,
                                               restore_compile_cache):
     """enable() + clear_caches() round trip: the second compile of an
-    identical program is served from the persistent cache and the counter
-    sees it."""
+    identical program is served from the persistent cache, and the
+    recorder of ``core.tracing`` counts it as ``jax.cache_hit``."""
+    from repro.core import tracing
     from repro.launch import compile_cache
     d = str(tmp_path / "cc")
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
     assert compile_cache.enable() == d
-    ctr = compile_cache.hit_counter()
     import jax.numpy as jnp
-    f = jax.jit(lambda x: (x * 3).sum())
-    f(jnp.ones((4, 4)))
-    assert compile_cache.entry_count(d) > 0
-    before_hits = ctr.hits
-    jax.clear_caches()
-    jax.jit(lambda x: (x * 3).sum())(jnp.ones((4, 4)))
-    assert ctr.hits > before_hits
-    assert "served from the persistent cache" in ctr.log_line()
-    assert set(ctr.summary()) == {"hits", "misses"}
+    with tracing.recording() as rec:
+        f = jax.jit(lambda x: (x * 3).sum())
+        f(jnp.ones((4, 4)))
+        assert compile_cache.entry_count(d) > 0
+        before_hits = rec.counts["jax.cache_hit"]
+        jax.clear_caches()
+        jax.jit(lambda x: (x * 3).sum())(jnp.ones((4, 4)))
+    assert rec.counts["jax.cache_hit"] > before_hits
+    line = compile_cache.log_line(rec.counts)
+    assert "served from the persistent cache" in line
+    hits = rec.counts["jax.cache_hit"]
+    assert f"{hits}/{hits + rec.counts['jax.cache_miss']} " in line
     assert compile_cache.entry_count(None) == 0
 
 
