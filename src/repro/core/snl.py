@@ -10,6 +10,7 @@ BCD.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Dict, List
 
 import jax
@@ -115,31 +116,50 @@ def run_snl(
     return SNLResult(params, hard, a_host, snapshots, budgets, lams)
 
 
+def _finetune_opt(lr: float, steps: int, use_adam: bool):
+    """The finetune's optimizer: SGD (momentum 0.9) or AdamW, cosine
+    schedule over ``steps``."""
+    schedule = opt_lib.cosine(lr, steps)
+    if use_adam:
+        return opt_lib.adamw(lr=lr, schedule=schedule)
+    return opt_lib.sgd(lr=lr, momentum=0.9, schedule=schedule)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("loss_fn", "lr", "steps", "use_adam"))
+def _finetune_step(p, ostate, batch, masks, *, loss_fn, lr, steps,
+                   use_adam):
+    # a Python side effect: it runs only while JAX traces this step
+    tracing.count("snl.finetune_trace")
+
+    def l(p):
+        loss, _ = loss_fn(p, masks, batch, False)
+        return loss
+    grads = jax.grad(l)(p)
+    updates, ostate = _finetune_opt(lr, steps, use_adam).update(
+        grads, ostate, p)
+    return opt_lib.apply_updates(p, updates), ostate
+
+
 def finetune(params, hard_masks: M.MaskTree, loss_fn, batches,
              *, steps: int, lr: float = 1e-3, start_step: int = 0,
              use_adam: bool = False):
-    """Finetune θ under fixed binary masks (shared by SNL / BCD / AutoReP)."""
+    """Finetune θ under fixed binary masks (shared by SNL / BCD / AutoReP).
+
+    The jitted step is built once per (``loss_fn``, ``lr``, ``steps``,
+    ``use_adam``): a caller that passes the same loss function object on
+    every call traces, lowers and compiles (or loads) the step once, and
+    later calls reuse it from JAX's in-memory cache.  The masks are a jit
+    argument, so new masks reuse it too.  That cache holds each loss
+    function strongly, one entry per distinct function, up to JAX's own
+    cache size; a loss function made anew on every call (a fresh lambda)
+    traces the step on every call.
+    """
     with tracing.span("snl.finetune"):
-        opt = (opt_lib.adamw(lr=lr, schedule=opt_lib.cosine(lr, steps))
-               if use_adam else
-               opt_lib.sgd(lr=lr, momentum=0.9,
-                           schedule=opt_lib.cosine(lr, steps)))
         masks_dev = M.as_device(hard_masks)
-
-        # masks are a jit argument, not a closure constant: every call
-        # then lowers the same program, which the persistent compilation
-        # cache serves instead of recompiling per BCD step
-        @jax.jit
-        def step(p, ostate, batch, masks):
-            def l(p):
-                loss, _ = loss_fn(p, masks, batch, False)
-                return loss
-            grads = jax.grad(l)(p)
-            updates, ostate = opt.update(grads, ostate, p)
-            return opt_lib.apply_updates(p, updates), ostate
-
-        ostate = opt.init(params)
+        ostate = _finetune_opt(lr, steps, use_adam).init(params)
         for i in range(steps):
-            params, ostate = step(params, ostate, batches(start_step + i),
-                                  masks_dev)
+            params, ostate = _finetune_step(
+                params, ostate, batches(start_step + i), masks_dev,
+                loss_fn=loss_fn, lr=lr, steps=steps, use_adam=use_adam)
         return params

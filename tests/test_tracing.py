@@ -182,8 +182,8 @@ def test_bcd_steps_span_tree_and_prefix_counts(tiny_bcd):
             assert ancestors(j)[0] == "engine.stage"
     assert {"engine.stage", "engine.prefix", "engine.wait",
             "bcd.sample"} <= set(names)
-    # the finetune re-jits its step on every call: its compile phases sit
-    # inside snl.finetune
+    # the finetune traces its step at most on its first call, and any
+    # compile phase of that step sits inside snl.finetune
     for j, n in enumerate(names):
         if n.startswith("jax.") and spans[j].fun_name and \
                 "step" in spans[j].fun_name:
