@@ -250,7 +250,17 @@ def block_fwd_flops(cfg, blk, new_tokens: float, ctx: float,
     hd, H, KV = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     k = blk.kind
     cache_bytes = 0.0
-    if k in ("dense", "moe", "attn_only"):
+    if k in ("dense", "moe", "attn_only") and cfg.kv_lora_rank:
+        # latent attention: q, [c_kv; k_pe], [k_nope; v], out projections;
+        # scores over the qk head dims, values over v_head_dim
+        r, dq = cfg.kv_lora_rank, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        dv = cfg.v_head_dim
+        wq = d * H * dq + d * (r + cfg.qk_rope_head_dim) \
+            + r * H * (cfg.qk_nope_head_dim + dv) + H * dv * d
+        span = ctx if mode == "decode" else ctx / 2
+        f = 2 * new_tokens * wq + 2 * new_tokens * H * (dq + dv) * span
+        wb = wq * 2
+    elif k in ("dense", "moe", "attn_only"):
         f_attn_proj = 2 * new_tokens * d * (H + 2 * KV) * hd \
             + 2 * new_tokens * H * hd * d
         kv_len = min(ctx, blk.window or ctx)
@@ -265,15 +275,22 @@ def block_fwd_flops(cfg, blk, new_tokens: float, ctx: float,
         wb = (d * (H + 2 * KV) * hd + H * hd * d) * 2
         if mode == "decode":
             cache_bytes += new_tokens * kv_len * KV * hd * 2 * 2
+    if k in ("dense", "moe", "attn_only"):
         if k == "dense":
             nf = 3 if cfg.gated_ffn else 2
             f += 2 * new_tokens * d * cfg.d_ff * nf
             wb += d * cfg.d_ff * nf * 2
         elif k == "moe":
-            cap = cfg.top_k * cfg.capacity_factor
+            held = cfg.moe_dispatch == "held"
+            E = (cfg.n_experts_held or cfg.n_experts) if held \
+                else cfg.n_experts
+            # drop-free held experts: the pairs routed here (uniform
+            # routing); capacity modes: every padded slot
+            cap = cfg.top_k * E / cfg.n_experts if held \
+                else cfg.top_k * cfg.capacity_factor
             f += 2 * new_tokens * d * cfg.n_experts          # router
             f += 2 * new_tokens * cap * 3 * d * cfg.d_ff_expert
-            wb += 3 * cfg.n_experts * d * cfg.d_ff_expert * 2
+            wb += 3 * E * d * cfg.d_ff_expert * 2
             if cfg.n_shared_experts:
                 f += 2 * new_tokens * 3 * d * cfg.d_ff_shared
                 wb += 3 * d * cfg.d_ff_shared * 2
@@ -336,7 +353,7 @@ def lm_segment_fwd_flops(cfg, *, seq_len: int) -> list:
     """
     def f(blk):
         fl = block_fwd_flops(cfg, blk, seq_len, seq_len, "prefill")[0]
-        if blk.kind == "moe":
+        if blk.kind == "moe" and cfg.moe_dispatch != "held":
             analytic = seq_len * cfg.top_k * cfg.capacity_factor
             slots = cfg.n_experts * moe_capacity_slots(cfg, seq_len)
             fl += 2 * max(slots - analytic, 0.0) * 3 * cfg.d_model \

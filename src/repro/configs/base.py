@@ -25,6 +25,20 @@ class Block:
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rope scaling (Peng et al. 2023, arXiv:2309.00071), in the form
+    of DeepSeek-V2's ``rope_scaling`` (``type: yarn``).  Its ``mscale``
+    scales cos and sin by ``mscale(factor, mscale) / mscale(factor,
+    mscale_all_dim)``, which is 1 where the two are equal (DeepSeek-V2-Lite:
+    both 0.707), the one case held here."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
     family: str                    # dense | moe | ssm | hybrid | vlm | audio
@@ -47,12 +61,25 @@ class ArchConfig:
     n_shared_experts: int = 0
     d_ff_shared: int = 0
     capacity_factor: float = 1.25
+    norm_topk: bool = True         # renormalize the top-k gates to sum to 1
+    # experts this chip holds, [expert_offset, expert_offset + n); 0 = all
+    # (only the drop-free 'held' dispatch holds a share)
+    n_experts_held: int = 0
+    expert_offset: int = 0
+    # latent attention (MLA, DeepSeek-V2 §2.1) when kv_lora_rank > 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    yarn: Optional[Yarn] = None    # rope scaling of the MLA rope part
     # ssm
     ssm_state: int = 0
     mamba_head_dim: int = 64
     rwkv_head_dim: int = 64
     # performance knobs (§Perf hillclimb variants; defaults = baseline)
-    moe_dispatch: str = "scatter"  # 'scatter' | 'gather' (see models.moe)
+    # 'scatter' | 'gather' (capacity-bounded) | 'held' (drop-free, the
+    # experts this chip holds; see models.moe)
+    moe_dispatch: str = "scatter"
     remat_group: int = 1           # layers per remat group in the train scan
     # io / modality
     prefix_len: int = 0            # stubbed frontend embeddings (vlm)
@@ -75,6 +102,34 @@ class ArchConfig:
     def d_inner(self) -> int:      # mamba inner width
         return 2 * self.d_model
 
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
+
+    def chip_share(self, *, moe_layers: int = 4, ways: int = 8,
+                   shard: int = 0) -> "ArchConfig":
+        """One chip's share of a deployment in which every expert layer is
+        divided over ``ways`` chips by expert and the vocabulary ``ways``
+        ways: every width as published, the leading dense layers and
+        ``moe_layers`` expert layers (the layers cut away would be further
+        pipeline stages), the experts of shard ``shard`` (the router still
+        scores all of them), and that shard's slice of the vocabulary."""
+        if self.moe_dispatch != "held":
+            raise ValueError(f"{self.name}: a chip's share of experts needs "
+                             "the 'held' MoE dispatch")
+        if self.n_experts % ways or self.vocab % ways:
+            raise ValueError(f"{self.name}: {self.n_experts} experts or "
+                             f"{self.vocab} ids do not split {ways} ways")
+        held = self.n_experts // ways
+        return dataclasses.replace(
+            self, n_layers=len(self.head_blocks) + moe_layers,
+            n_experts_held=held, expert_offset=shard * held,
+            vocab=self.vocab // ways)
+
     def reduced(self) -> "ArchConfig":
         """Small same-family config for CPU smoke tests."""
         pat = self.pattern
@@ -91,6 +146,11 @@ class ArchConfig:
             mamba_head_dim=16, rwkv_head_dim=16,
             prefix_len=8 if self.prefix_len else 0,
             dtype="float32",
+            n_experts_held=0, expert_offset=0,
+            kv_lora_rank=32 if self.mla else 0,
+            qk_nope_head_dim=16 if self.mla else 0,
+            qk_rope_head_dim=8 if self.mla else 0,
+            v_head_dim=16 if self.mla else 0,
         )
 
 
@@ -112,7 +172,7 @@ SHAPES: Dict[str, ShapeCell] = {
 ARCH_IDS = [
     "zamba2_2p7b", "stablelm_1p6b", "mistral_nemo_12b", "qwen3_32b",
     "gemma3_27b", "mixtral_8x22b", "deepseek_moe_16b", "rwkv6_3b",
-    "paligemma_3b", "musicgen_large",
+    "paligemma_3b", "musicgen_large", "deepseek_v2_lite",
 ]
 
 

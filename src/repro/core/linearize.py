@@ -147,6 +147,25 @@ def _apply_share_ties(x, mask, out):
     return jnp.where(tied, x * drv, out)
 
 
+def masked_act_plain(x, mask, site: MaskSite, poly=None, soft: bool = False):
+    """The masked activation as one broadcast expression (no kernel):
+    ``m·act(x) + (1−m)·lin(x)``, ``lin`` the identity or the poly2
+    replacement; ``mask`` (and ``poly``) broadcast against ``x`` — a mask
+    per row serves the held-expert MoE's sorted rows."""
+    from repro.kernels import ref
+    if soft:
+        mask = jnp.clip(mask, 0.0, 1.0)
+    y = ref._act(x, site.kind)
+    if poly is None:
+        lin = x
+    else:
+        a, b, c = poly[0], poly[1], poly[2]
+        lin = a * x * x + b * x + c
+    m = mask.astype(x.dtype)
+    out = m * y + (1.0 - m) * lin
+    return out if soft else _apply_share_ties(x, mask, out)
+
+
 def apply_masked_act(x, mask, site: MaskSite, poly=None, soft: bool = False):
     """Apply the (possibly soft, for SNL) masked activation at a site.
 
@@ -157,7 +176,6 @@ def apply_masked_act(x, mask, site: MaskSite, poly=None, soft: bool = False):
     :func:`_apply_share_ties`; soft mode treats every real value as an SNL
     relaxation weight and never ties.
     """
-    from repro.kernels import ref
     p = None
     if poly is not None and (soft or site.replacement == "poly2"):
         p = poly
@@ -169,15 +187,7 @@ def apply_masked_act(x, mask, site: MaskSite, poly=None, soft: bool = False):
         # mixed one and forces GSPMD to fully rematerialize the activation
         # (EXPERIMENTS.md §Perf, mixtral hillclimb).  Pallas needs the 2D
         # layout, but it only runs on TPU where the kernel owns the tiling.
-        y = ref._act(x, site.kind)
-        if p is None:
-            lin = x
-        else:
-            a, b, c = p[0], p[1], p[2]
-            lin = a * x * x + b * x + c
-        m = mask.astype(x.dtype)
-        out = m * y + (1.0 - m) * lin
-        return out if soft else _apply_share_ties(x, mask, out)
+        return masked_act_plain(x, mask, site, poly=p, soft=soft)
     if stacked_route_active():
         out = ops.masked_act_sited_routed(x, mask, kind=site.kind, poly=p)
     else:

@@ -301,6 +301,10 @@ class ServeLoop:
                  retries: Optional[Dict[str, faults_lib.RetryPolicy]] = None,
                  proto: pi_cost.PIProtocol = pi_cost.PIProtocol()):
         """Build lanes (one resident decode cache per SLO class) and jits."""
+        if model.cfg.mla:
+            raise NotImplementedError(
+                f"model {model.cfg.name!r} uses latent attention (MLA), "
+                "which has no decode cache yet: it cannot be served")
         if not classes:
             raise ValueError("ServeLoop needs at least one SLO class")
         for c in classes:

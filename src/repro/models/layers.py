@@ -1,4 +1,5 @@
-"""Shared transformer layers: norms, RoPE, GQA attention, gated FFN.
+"""Shared transformer layers: norms, RoPE (and YaRN), GQA attention, latent
+attention (MLA), gated FFN.
 
 All functions are pure: params are nested dicts of jnp arrays; mask trees ride
 alongside (core.linearize).  Attention is q-chunked (flash-style, full-row
@@ -8,10 +9,12 @@ materialize (S, S) score tensors.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import linearize
 
@@ -34,12 +37,16 @@ def rmsnorm(p, x, eps=1e-6):
 # ---------------------------------------------------------------- rope
 
 
-def rope(x, positions, theta: float):
-    """x: (..., S, H, hd); positions: (..., S) int32."""
+def rope(x, positions, theta: float, inv_freq=None):
+    """x: (..., S, H, hd); positions: (..., S) int32.  Half-split rotation;
+    ``inv_freq`` (hd/2,) replaces ``theta ** (-2i/hd)`` (YaRN)."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = jnp.exp(-jnp.log(theta) *
-                    jnp.arange(0, half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freqs = jnp.exp(-jnp.log(theta) *
+                        jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, half)
     cos = jnp.cos(ang)[..., None, :]   # (..., S, 1, half)
     sin = jnp.sin(ang)[..., None, :]
@@ -47,6 +54,29 @@ def rope(x, positions, theta: float):
     y1 = x1 * cos - x2 * sin
     y2 = x2 * cos + x1 * sin
     return jnp.concatenate([y1, y2], axis=-1).astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, base: float, yarn) -> np.ndarray:
+    """YaRN's inverse frequencies (DeepSeek-V2's form): a linear ramp over
+    the rotation dims between the correction dims of ``beta_fast`` and
+    ``beta_slow`` blends ``base ** (-2i/dim)`` (extrapolated, fast dims) with
+    that value divided by ``factor`` (interpolated, slow dims)."""
+    def corr(rot):
+        return (dim * math.log(yarn.original_max_position
+                               / (rot * 2 * math.pi))) / (2 * math.log(base))
+    lo = max(math.floor(corr(yarn.beta_fast)), 0)
+    hi = min(math.ceil(corr(yarn.beta_slow)), dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - lo) / (hi - lo),
+                   0.0, 1.0)
+    extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    return (extra / yarn.factor * ramp + extra * (1.0 - ramp)
+            ).astype(np.float32)
 
 
 # ---------------------------------------------------------------- attention
@@ -82,16 +112,20 @@ def attn_init(key, c: AttnCfg, dtype=jnp.bfloat16):
 
 
 def _attend(q, k, v, *, causal_offset, window, scale):
-    """q: (B,Sq,H,hd) k,v: (B,Sk,KV,hd). causal_offset = abs pos of q[0] - abs
-    pos of k[0] (so query i attends keys j with j <= i + causal_offset).
+    """q: (B,Sq,H,hd) k: (B,Sk,KV,hd) v: (B,Sk,KV,hv). causal_offset = abs
+    pos of q[0] - abs pos of k[0] (so query i attends keys j with j <= i +
+    causal_offset).
     causal_offset may be a (B,) vector — per-row offsets for continuous
     batching, where each batch slot sits at its own decode position."""
     B, Sq, H, hd = q.shape
     KV = k.shape[2]
     rep = H // KV
     qh = q.reshape(B, Sq, KV, rep, hd)
-    scores = jnp.einsum("bqkrh,bskh->bkrqs", qh, k).astype(jnp.float32)
-    scores = scores * scale
+    # the scale rides on the queries: XLA folds a scale of the scores into
+    # the dot in some programs and not in others (a scan body against the
+    # same body unrolled), which breaks the split forwards' bitwise contract
+    scores = jnp.einsum("bqkrh,bskh->bkrqs", qh * scale, k).astype(
+        jnp.float32)
     co = jnp.asarray(causal_offset)
     kj = jnp.arange(k.shape[1])[None, :]
     if co.ndim == 1:
@@ -107,8 +141,8 @@ def _attend(q, k, v, *, causal_offset, window, scale):
             mask &= kj > qi - window
         scores = jnp.where(mask[None, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkrqs,bskh->bqkrh", probs, v)
-    return out.reshape(B, Sq, H, hd)
+    out = jnp.einsum("bkrqs,bskv->bqkrv", probs, v)
+    return out.reshape(B, Sq, H, v.shape[-1])
 
 
 def attention(p, c: AttnCfg, x, positions, *, kv_cache=None, cache_len=None):
@@ -155,21 +189,105 @@ def attention(p, c: AttnCfg, x, positions, *, kv_cache=None, cache_len=None):
         out = out.reshape(B, S, h * hd)
         return (out @ p["wo"]), (K, V)
 
-    if S <= c.q_chunk:
-        out = _attend(q, k, v, causal_offset=0, window=c.window, scale=scale)
-    else:
-        assert S % c.q_chunk == 0, (S, c.q_chunk)
-        nch = S // c.q_chunk
-        qs = q.reshape(B, nch, c.q_chunk, h, hd)
-
-        def chunk(i, q_i):
-            return _attend(q_i, k, v, causal_offset=i * c.q_chunk,
-                           window=c.window, scale=scale)
-        out = jax.lax.map(lambda args: chunk(*args),
-                          (jnp.arange(nch), qs.swapaxes(0, 1)))
-        out = out.swapaxes(0, 1).reshape(B, S, h, hd)
+    out = _chunked_causal(q, k, v, c.q_chunk, scale, c.window)
     out = out.reshape(B, S, h * hd)
     return out @ p["wo"], None
+
+
+def _chunked_causal(q, k, v, q_chunk: int, scale: float, window=None):
+    """Causal attention of a whole sequence, queries in chunks of
+    ``q_chunk`` (one ``lax.map`` step each) above that length, so no
+    (S, S) score tensor is held at once."""
+    B, S, h = q.shape[:3]
+    if S <= q_chunk:
+        return _attend(q, k, v, causal_offset=0, window=window, scale=scale)
+    assert S % q_chunk == 0, (S, q_chunk)
+    nch = S // q_chunk
+    qs = q.reshape((B, nch, q_chunk) + q.shape[2:])
+
+    def chunk(i, q_i):
+        return _attend(q_i, k, v, causal_offset=i * q_chunk, window=window,
+                       scale=scale)
+    out = jax.lax.map(lambda args: chunk(*args),
+                      (jnp.arange(nch), qs.swapaxes(0, 1)))
+    return out.swapaxes(0, 1).reshape((B, S) + out.shape[3:])
+
+
+# ---------------------------------------------------------------- MLA
+
+
+@dataclasses.dataclass(frozen=True)
+class MLACfg:
+    """Multi-head latent attention without query compression (DeepSeek-V2
+    §2.1, ``q_lora_rank`` null)."""
+    d_model: int
+    n_heads: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 1e4
+    yarn: Optional[object] = None       # configs.base.Yarn
+    q_chunk: int = 256                  # chunk queries above this seq len
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def softmax_scale(self) -> float:
+        s = self.qk_head_dim ** -0.5
+        if self.yarn is not None and self.yarn.mscale_all_dim:
+            s *= yarn_mscale(self.yarn.factor, self.yarn.mscale_all_dim) ** 2
+        return s
+
+    def inv_freq(self):
+        if self.yarn is None:
+            return None
+        return yarn_inv_freq(self.qk_rope_head_dim, self.rope_theta,
+                             self.yarn)
+
+
+def mla_init(key, c: MLACfg, dtype=jnp.bfloat16):
+    kq, kd, ku, ko = jax.random.split(key, 4)
+    d, h = c.d_model, c.n_heads
+    r = c.kv_lora_rank
+    return {
+        "wq": (jax.random.normal(kq, (d, h * c.qk_head_dim)) * d ** -0.5
+               ).astype(dtype),
+        "w_dkv": (jax.random.normal(kd, (d, r + c.qk_rope_head_dim))
+                  * d ** -0.5).astype(dtype),
+        "kv_norm": rmsnorm_init(r),
+        "w_ukv": (jax.random.normal(
+            ku, (r, h * (c.qk_nope_head_dim + c.v_head_dim))) * r ** -0.5
+            ).astype(dtype),
+        "wo": (jax.random.normal(ko, (h * c.v_head_dim, d))
+               * (h * c.v_head_dim) ** -0.5).astype(dtype),
+    }
+
+
+def mla_attention(p, c: MLACfg, x, positions):
+    """Causal latent attention over the whole sequence (no cache): queries
+    ``x·W_q`` split into nope and rope parts; ``[c_kv; k_pe] = x·W_dkv``,
+    ``c_kv`` normed and expanded to per-head ``[k_nope; v]`` by ``W_ukv``;
+    ``k_pe`` rotated once and shared by every head."""
+    with jax.named_scope("mla"):
+        B, S, _ = x.shape
+        h, dn, dr = c.n_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
+        inv = c.inv_freq()
+        q = (x @ p["wq"]).reshape(B, S, h, dn + dr)
+        q_pe = rope(q[..., dn:], positions, c.rope_theta, inv)
+        q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+        ckv = x @ p["w_dkv"]
+        k_pe = rope(ckv[..., None, c.kv_lora_rank:], positions,
+                    c.rope_theta, inv)                    # (B, S, 1, dr)
+        kv = (rmsnorm(p["kv_norm"], ckv[..., :c.kv_lora_rank]) @ p["w_ukv"]
+              ).reshape(B, S, h, dn + c.v_head_dim)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_pe, (B, S, h, dr))], axis=-1)
+        out = _chunked_causal(q, k, kv[..., dn:], c.q_chunk,
+                              c.softmax_scale)
+        return out.reshape(B, S, h * c.v_head_dim) @ p["wo"]
 
 
 # ---------------------------------------------------------------- gated FFN

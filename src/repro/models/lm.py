@@ -39,7 +39,16 @@ def _moe_cfg(cfg: ArchConfig) -> moe_lib.MoECfg:
         d_ff_expert=cfg.d_ff_expert,
         n_shared=1 if cfg.n_shared_experts else 0,
         d_ff_shared=cfg.d_ff_shared, capacity_factor=cfg.capacity_factor,
-        dispatch=cfg.moe_dispatch)
+        dispatch=cfg.moe_dispatch, norm_topk=cfg.norm_topk,
+        n_held=cfg.n_experts_held, expert_offset=cfg.expert_offset)
+
+
+def _mla_cfg(cfg: ArchConfig, blk: Block) -> layers.MLACfg:
+    return layers.MLACfg(
+        d_model=cfg.d_model, n_heads=cfg.n_heads,
+        kv_lora_rank=cfg.kv_lora_rank, qk_nope_head_dim=cfg.qk_nope_head_dim,
+        qk_rope_head_dim=cfg.qk_rope_head_dim, v_head_dim=cfg.v_head_dim,
+        rope_theta=blk.rope_theta, yarn=cfg.yarn)
 
 
 def _mamba_cfg(cfg: ArchConfig) -> ssm.MambaCfg:
@@ -94,7 +103,7 @@ def _sites_for(cfg: ArchConfig, blk: Block) -> Dict[str, linearize.MaskSite]:
         return {"ffn": linearize.MaskSite((cfg.d_ff,), cfg.act, rep)}
     if blk.kind == "moe":
         out = {"moe": linearize.MaskSite(
-            (cfg.n_experts, cfg.d_ff_expert), cfg.act, rep)}
+            (cfg.experts_held, cfg.d_ff_expert), cfg.act, rep)}
         if cfg.n_shared_experts:
             out["moe_shared"] = linearize.MaskSite(
                 (cfg.d_ff_shared,), cfg.act, rep)
@@ -129,7 +138,9 @@ class LM:
         d = cfg.d_model
         if blk.kind in ("dense", "moe", "attn_only"):
             p = {"ln1": layers.rmsnorm_init(d),
-                 "attn": layers.attn_init(ks[0], _attn_cfg(cfg, blk), dt)}
+                 "attn": (layers.mla_init(ks[0], _mla_cfg(cfg, blk), dt)
+                          if cfg.mla else
+                          layers.attn_init(ks[0], _attn_cfg(cfg, blk), dt))}
             if blk.kind == "dense":
                 p["ln2"] = layers.rmsnorm_init(d)
                 p["ffn"] = layers.ffn_init(ks[1], d, cfg.d_ff,
@@ -159,6 +170,10 @@ class LM:
             "tail": [self._layer_init(jax.random.fold_in(kt, i), blk)
                      for i, blk in enumerate(cfg.tail)],
         }
+        if not cfg.tie_embeddings:
+            params["lm_head"] = (jax.random.normal(
+                jax.random.fold_in(ke, 1), (cfg.d_model, cfg.vocab))
+                * cfg.d_model ** -0.5).astype(dt)
         stack = {}
         R = cfg.n_repeats
         for pos, blk in enumerate(cfg.pattern):
@@ -202,12 +217,7 @@ class LM:
         cfg = self.cfg
         newc = {} if cache is not None else None
         if blk.kind in ("dense", "moe", "attn_only"):
-            h = layers.rmsnorm(p["ln1"], x)
-            kv = None if cache is None else cache["kv"]
-            a, kv2 = layers.attention(p["attn"], _attn_cfg(cfg, blk), h,
-                                      positions, kv_cache=kv,
-                                      cache_len=cache_len)
-            x = x + a
+            x, kv2 = self._attn_apply(blk, p, x, positions, cache, cache_len)
             if cache is not None:
                 newc["kv"] = kv2
             if blk.kind == "dense":
@@ -253,6 +263,27 @@ class LM:
                 newc["pcm"] = c2
             return x + y, newc
         raise ValueError(blk.kind)
+
+    def _attn_apply(self, blk: Block, p, x, positions, cache, cache_len):
+        """The attention half of an attention block: (x + attn, new kv)."""
+        cfg = self.cfg
+        h = layers.rmsnorm(p["ln1"], x)
+        if cfg.mla:
+            if cache is not None:
+                raise NotImplementedError(_MLA_NO_CACHE)
+            return x + layers.mla_attention(p["attn"], _mla_cfg(cfg, blk),
+                                            h, positions), None
+        kv = None if cache is None else cache["kv"]
+        a, kv2 = layers.attention(p["attn"], _attn_cfg(cfg, blk), h,
+                                  positions, kv_cache=kv,
+                                  cache_len=cache_len)
+        return x + a, kv2
+
+    def unembed(self, params):
+        """The output head (D, V): the untied ``lm_head`` or ``embed.T``."""
+        if self.cfg.tie_embeddings:
+            return params["embed"].T
+        return params["lm_head"]
 
     # ------------------------------------------------------------ forward
 
@@ -393,7 +424,7 @@ class LM:
         x = layers.rmsnorm(params["final_norm"], x)
         if return_hidden:
             return x, new_cache
-        logits = x @ params["embed"].T.astype(x.dtype)
+        logits = x @ self.unembed(params).astype(x.dtype)
         return logits, new_cache
 
     # ------------------------------------------------------- split forward
@@ -585,7 +616,7 @@ class LM:
                                      _sub(poly, f"t{i}"), soft,
                                      positions, None, 0)
         x = layers.rmsnorm(params["final_norm"], x)
-        return x @ params["embed"].T.astype(x.dtype)
+        return x @ self.unembed(params).astype(x.dtype)
 
     def site_prefix_fractions(self, *, seq_len: int = 64) -> Dict[str, float]:
         """site -> fraction of forward FLOPs strictly before its segment.
@@ -615,22 +646,19 @@ class LM:
 
         def prefix_fn(site, masks, ctx):
             return self.forward_prefix(ctx["params"], masks,
-                                       ctx["batch"]["tokens"][:, :-1], site)
+                                       _inputs(ctx["batch"]), site)
 
         def prefix_ext_fn(from_site, site, masks, cached, ctx):
             return self.forward_prefix(ctx["params"], masks,
-                                       ctx["batch"]["tokens"][:, :-1], site,
+                                       _inputs(ctx["batch"]), site,
                                        from_site=from_site, cached=cached)
 
         def suffix_fn(site, masks, cached, ctx):
             logits = self.forward_suffix(ctx["params"], masks, cached, site)
-            pred = jnp.argmax(logits, -1)
-            return jnp.mean((pred == ctx["batch"]["tokens"][:, 1:])
-                            .astype(jnp.float32)) * 100.0
+            return _accuracy(logits, ctx["batch"])
 
         def pre_fn(ctx):
-            return self.forward_pre(ctx["params"],
-                                    ctx["batch"]["tokens"][:, :-1])
+            return self.forward_pre(ctx["params"], _inputs(ctx["batch"]))
 
         return engine.SplitEval(
             prefix=prefix_fn, suffix=suffix_fn,
@@ -650,18 +678,18 @@ class LM:
     # the candidate axis) and a host-callable wrapper for sequential use.
     # The metric is next-token accuracy [%] on a fixed token batch — masks
     # ride through the scanned stack as jit inputs, so candidate evaluation
-    # never recompiles.
+    # never recompiles.  With ``labels`` in the batch the forward reads all
+    # of ``tokens`` and scores against ``labels`` (same shape); without, it
+    # scores the next token: ``tokens[:, :-1]`` against ``tokens[:, 1:]``.
 
     def make_param_eval_fn(self, batch):
         """Traceable ``(mask_tree, params) -> accuracy[%]`` — params as an
         evaluator context (jit input), for finetuning-between-steps runs."""
-        tokens = jnp.asarray(batch["tokens"])
+        batch = {k: jnp.asarray(v) for k, v in batch.items()}
 
         def eval_fn(masks, params):
-            logits, _ = self.forward(params, masks, tokens[:, :-1])
-            pred = jnp.argmax(logits, -1)
-            return jnp.mean((pred == tokens[:, 1:])
-                            .astype(jnp.float32)) * 100.0
+            logits, _ = self.forward(params, masks, _inputs(batch))
+            return _accuracy(logits, batch)
         return eval_fn
 
     def make_eval_fn(self, params, batch):
@@ -676,14 +704,11 @@ class LM:
         ``"batch"`` while candidates shard over ``"cand"`` (joint layout for
         trial chunks smaller than the device count)."""
         def eval_fn(masks, ctx):
-            tokens = ctx["batch"]["tokens"]
             # "pre" (optional): the mask-independent embed fold, computed
             # once per context by the evaluator (SplitEval.pre)
-            logits, _ = self.forward(ctx["params"], masks, tokens[:, :-1],
-                                     pre=ctx.get("pre"))
-            pred = jnp.argmax(logits, -1)
-            return jnp.mean((pred == tokens[:, 1:])
-                            .astype(jnp.float32)) * 100.0
+            logits, _ = self.forward(ctx["params"], masks,
+                                     _inputs(ctx["batch"]), pre=ctx.get("pre"))
+            return _accuracy(logits, ctx["batch"])
         return eval_fn
 
     def make_eval_acc(self, params, batch):
@@ -713,6 +738,8 @@ class LM:
 
     def init_cache(self, B: int, max_len: int):
         cfg = self.cfg
+        if cfg.mla:
+            raise NotImplementedError(_MLA_NO_CACHE)
         R = cfg.n_repeats
         stack = {}
         for pos, blk in enumerate(cfg.pattern):
@@ -724,6 +751,56 @@ class LM:
                 "stack": stack,
                 "tail": [self._layer_cache(b, B, max_len)
                          for b in cfg.tail]}
+
+
+    # ------------------------------------------------------------ experts
+
+    def held_expert_rows(self, params, masks, tokens):
+        """Rows routed to each held expert in every expert layer of the
+        forward of ``tokens`` under ``masks``: (expert layers, held) int32,
+        in forward order (head blocks, stack repeats, tail)."""
+        cfg = self.cfg
+        mc = _moe_cfg(cfg)
+        x = jnp.take(params["embed"], tokens, axis=0)
+        B, S, _ = x.shape
+        positions = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+        blocks = [(blk, params["head"][i], _sub(masks, f"h{i}"))
+                  for i, blk in enumerate(cfg.head_blocks)]
+        for r in range(cfg.n_repeats):
+            for pos, blk in enumerate(cfg.pattern):
+                lp = params["stack"][str(pos)]
+                if not blk.shared:
+                    lp = jax.tree.map(lambda a: a[r], lp)
+                blocks.append((blk, lp, {k: v[r] for k, v in _sub(
+                    masks, f"s{pos}").items()}))
+        blocks += [(blk, params["tail"][i], _sub(masks, f"t{i}"))
+                   for i, blk in enumerate(cfg.tail)]
+        rows = []
+        for blk, lp, msk in blocks:
+            if blk.kind == "moe":
+                h, _ = self._attn_apply(blk, lp, x, positions, None, 0)
+                eidx, _ = moe_lib.route(lp["moe"]["router"], mc,
+                                        layers.rmsnorm(lp["ln2"], h))
+                rows.append(moe_lib.held_sizes(eidx, mc))
+            x, _ = self._layer_apply(blk, lp, x, msk, {}, False, positions,
+                                     None, 0)
+        return jnp.stack(rows)
+
+
+_MLA_NO_CACHE = ("latent attention (MLA) has no decode cache yet: serve "
+                 "and cached forwards do not take an MLA configuration")
+
+
+def _inputs(batch):
+    """The tokens the eval forward reads (see the eval closures)."""
+    return batch["tokens"] if "labels" in batch else batch["tokens"][:, :-1]
+
+
+def _accuracy(logits, batch):
+    """Top-1 agreement [%] with ``labels``, or with the next token."""
+    target = batch["labels"] if "labels" in batch else batch["tokens"][:, 1:]
+    pred = jnp.argmax(logits, -1)
+    return jnp.mean((pred == target).astype(jnp.float32)) * 100.0
 
 
 # =================================================================== specs

@@ -1,4 +1,15 @@
-"""Mixture-of-Experts FFN with sort-based (dropping) dispatch.
+"""Mixture-of-Experts FFN: sort-based dispatch, capacity-bounded or drop-free.
+
+``dispatch='held'`` is the drop-free layer of an expert-parallel
+deployment: the router scores all ``n_experts``, the layer holds experts
+``[expert_offset, expert_offset + n_held)`` and computes their part of the
+result for every (token, k) pair routed to them — sorted by expert, one
+grouped matmul (``lax.ragged_dot``) per projection, no capacity, no token
+dropped.  Under a vmap (the BCD engine's candidate axis) the layer folds
+the vmapped axes into the sorted rows (``custom_vmap``), since
+``ragged_dot`` has no batching rule but over its rows.
+
+The capacity-bounded modes below ('scatter', 'gather') hold every expert.
 
 Dispatch is done *per batch row* (vmap over B): top-k routing, argsort by
 expert id, capacity clip, gather → (E, C, d) → batched expert einsums →
@@ -14,9 +25,12 @@ fine-grained d_ff).
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax import custom_batching
 
 from repro.core import linearize
 from . import layers
@@ -36,14 +50,22 @@ class MoECfg:
     # 'gather':  d-wide ops are gathers only; scatters touch int32 index
     #            vectors (tiny).  GSPMD partitions gathers cleanly.
     dispatch: str = "scatter"
+    norm_topk: bool = True       # renormalize the top-k gates to sum to 1
+    n_held: int = 0              # 'held': experts held here (0 = all)
+    expert_offset: int = 0       # 'held': first expert held here
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.n_experts
 
 
 def moe_init(key, c: MoECfg, dtype=jnp.bfloat16):
     kr, kg, ku, kd, ks = jax.random.split(key, 5)
-    d, e, f = c.d_model, c.n_experts, c.d_ff_expert
+    d, e, f = c.d_model, c.held, c.d_ff_expert
     s = d ** -0.5
     p = {
-        "router": (jax.random.normal(kr, (d, e)) * s).astype(jnp.float32),
+        "router": (jax.random.normal(kr, (d, c.n_experts)) * s
+                   ).astype(jnp.float32),
         "w_gate": (jax.random.normal(kg, (e, d, f)) * s).astype(dtype),
         "w_up": (jax.random.normal(ku, (e, d, f)) * s).astype(dtype),
         "w_down": (jax.random.normal(kd, (e, f, d)) * f ** -0.5).astype(dtype),
@@ -73,7 +95,8 @@ def _dispatch_row(x, logits, c: MoECfg, C: int):
     S = x.shape[0]
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     gates, eidx = jax.lax.top_k(probs, c.top_k)          # (S, k)
-    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    if c.norm_topk:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
     flat_e = eidx.reshape(-1)                            # (S*k,)
     flat_t = jnp.repeat(jnp.arange(S), c.top_k)
     order = jnp.argsort(flat_e)
@@ -122,7 +145,8 @@ def _route(logits, c: MoECfg, C: int):
     S = logits.shape[0]
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     gates, eidx = jax.lax.top_k(probs, c.top_k)              # (S, k)
-    gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    if c.norm_topk:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
     flat_e = eidx.reshape(-1)                                # (S*k,)
     order = jnp.argsort(flat_e)
     se = flat_e[order]
@@ -142,6 +166,133 @@ def _route(logits, c: MoECfg, C: int):
     return gates, slot_src, slot_tk
 
 
+# ------------------------------------------------------- drop-free, held
+
+
+def route(router, c: MoECfg, x):
+    """Top-k expert ids and gates of every token over all ``n_experts``:
+    softmax scores in float32, greedy top-k."""
+    with jax.named_scope("moe.route"):
+        probs = jax.nn.softmax(x.astype(jnp.float32) @ router, axis=-1)
+        gates, eidx = jax.lax.top_k(probs, c.top_k)
+        if c.norm_topk:
+            gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+        return eidx, gates
+
+
+def held_sizes(eidx, c: MoECfg):
+    """Rows routed to each held expert: (n_held,) int32 counts of the
+    (token, k) pairs whose expert is held here."""
+    local = eidx.reshape(-1) - c.expert_offset
+    return jnp.sum(local[:, None] == jnp.arange(c.held)[None, :], axis=0,
+                   dtype=jnp.int32)
+
+
+def _held_core(x, eidx, gates, grp, mask, poly, wg, wu, wd, *, e0, kind,
+               replacement, soft):
+    """x: (T, d) tokens; eidx, gates: (T, k); mask: (G, n_held, F) masks
+    of ``G`` mask groups and ``grp``: (T,) each token's group (a folded
+    candidate axis; one group when nothing is folded); poly: (3, G,
+    n_held, F) or None.  Returns the held experts' gated sum (T, d)."""
+    T, d = x.shape
+    k = eidx.shape[1]
+    Eh, _, F = wg.shape
+    local = eidx.reshape(-1) - e0
+    held = (local >= 0) & (local < Eh)
+    key = jnp.where(held, local, Eh)              # pairs held elsewhere last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(Eh)[None, :], axis=0,
+                    dtype=jnp.int32)
+    tok = order // k
+    # sorted rows past the held groups belong to no group: the TPU's
+    # grouped matmul leaves them (and their gradients) unwritten, so every
+    # operand and result is zeroed there
+    live = (jnp.arange(T * k) < jnp.sum(sizes))[:, None]
+
+    def grouped(lhs, w):
+        return jnp.where(live, jax.lax.ragged_dot(lhs, w, sizes), 0)
+    with jax.named_scope("moe.experts"):
+        xs = jnp.where(live, x[tok], 0)
+        midx = grp[tok] * Eh + jnp.minimum(key[order], Eh - 1)
+        m = mask.reshape(-1, F)[midx]
+        pr = None if poly is None else poly.reshape(3, -1, F)[:, midx]
+        a = linearize.masked_act_plain(
+            grouped(xs, wg), m, linearize.MaskSite((F,), kind, replacement),
+            poly=pr, soft=soft)
+        ys = grouped(a * grouped(xs, wu), wd)     # (T*k, d), sorted
+    yk = ys[jnp.argsort(order)].reshape(T, k, d)
+    # gates weigh the rows elementwise (an einsum would round them to the
+    # matmul precision); rows held elsewhere are zeros
+    w = jnp.where(held.reshape(T, k), gates, 0).astype(yk.dtype)
+    return jnp.sum(yk * w[..., None], axis=1)
+
+
+def _bcast(axis_size, batched, v):
+    if v is None or batched:
+        return v
+    return jnp.broadcast_to(v[None], (axis_size,) + v.shape)
+
+
+@functools.lru_cache(maxsize=None)
+def _held_fn(e0: int, kind: str, replacement: str, soft: bool):
+    core = functools.partial(_held_core, e0=e0, kind=kind,
+                             replacement=replacement, soft=soft)
+
+    @custom_batching.custom_vmap
+    def f(x, eidx, gates, grp, mask, poly, wg, wu, wd):
+        return core(x, eidx, gates, grp, mask, poly, wg, wu, wd)
+
+    @f.def_vmap
+    def _rule(axis_size, in_batched, x, eidx, gates, grp, mask, poly, wg,
+              wu, wd):
+        if any(in_batched[6:]):
+            raise NotImplementedError(
+                "held experts under a vmap of their weights")
+        x, eidx, gates, grp, mask, poly = [
+            _bcast(axis_size, b, v) for b, v in
+            zip(in_batched, (x, eidx, gates, grp, mask, poly))]
+        C, T = x.shape[:2]
+        G = mask.shape[1]
+        grp = (grp + G * jnp.arange(C, dtype=grp.dtype)[:, None])
+        y = f(x.reshape(C * T, -1), eidx.reshape(C * T, -1),
+              gates.reshape(C * T, -1), grp.reshape(-1),
+              mask.reshape((C * G,) + mask.shape[2:]),
+              None if poly is None else jnp.moveaxis(poly, 0, 1).reshape(
+                  (3, C * G) + poly.shape[3:]),
+              wg, wu, wd)
+        return y.reshape(C, T, -1), True
+
+    @jax.custom_vjp
+    def g(x, eidx, gates, grp, mask, poly, wg, wu, wd):
+        return f(x, eidx, gates, grp, mask, poly, wg, wu, wd)
+
+    def g_fwd(*args):
+        return jax.vjp(core, *args)
+
+    def g_bwd(vjp, ct):
+        return vjp(ct)
+
+    g.defvjp(g_fwd, g_bwd)
+    return g
+
+
+def held_experts(p, c: MoECfg, x, eidx, gates, mask, site, *, poly=None,
+                 soft=False):
+    """The held experts' part of the layer for tokens ``x`` (..., d) routed
+    as ``eidx``/``gates`` (..., k); ``mask`` (n_held, F) per-expert channel
+    masks of the masked gate activation."""
+    if not (soft or site.replacement == "poly2"):
+        poly = None
+    lead = x.shape[:-1]
+    T = math.prod(lead)
+    fn = _held_fn(c.expert_offset, site.kind, site.replacement, soft)
+    y = fn(x.reshape(T, -1), eidx.reshape(T, -1), gates.reshape(T, -1),
+           jnp.zeros((T,), jnp.int32), mask[None],
+           None if poly is None else poly[:, None],
+           p["w_gate"], p["w_up"], p["w_down"])
+    return y.reshape(lead + (-1,))
+
+
 def moe_ffn(p, c: MoECfg, x, mask, site: linearize.MaskSite,
             shared_mask=None, shared_site=None, *, poly=None,
             shared_poly=None, soft=False, act_spec=None):
@@ -152,6 +303,12 @@ def moe_ffn(p, c: MoECfg, x, mask, site: linearize.MaskSite,
     (B,E,C,·) expert tensors (GSPMD drops batch sharding through the
     dispatch gathers otherwise — §Perf, mixtral)."""
     B, S, d = x.shape
+    if c.dispatch == "held":
+        eidx, gates = route(p["router"], c, x)
+        y = held_experts(p, c, x, eidx, gates, mask, site, poly=poly,
+                         soft=soft)
+        return (y + _shared(p, x, shared_mask, shared_site, shared_poly,
+                            soft)).astype(x.dtype)
     C = _capacity(c, S)
     bspec = act_spec[0] if act_spec is not None else None
 
@@ -207,7 +364,13 @@ def moe_ffn(p, c: MoECfg, x, mask, site: linearize.MaskSite,
         y = batched_gather(x, logits)
     else:
         y = jax.vmap(row_scatter)(x, logits)
-    if "shared" in p:
-        y = y + layers.ffn(p["shared"], x, shared_mask, shared_site,
-                           poly=shared_poly, soft=soft)
-    return y.astype(x.dtype)
+    return (y + _shared(p, x, shared_mask, shared_site, shared_poly, soft)
+            ).astype(x.dtype)
+
+
+def _shared(p, x, mask, site, poly, soft):
+    """The always-on shared experts (one FFN of their summed width)."""
+    if "shared" not in p:
+        return 0.0
+    with jax.named_scope("moe.shared"):
+        return layers.ffn(p["shared"], x, mask, site, poly=poly, soft=soft)

@@ -106,7 +106,7 @@ def make_train_step(model: lm_lib.LM, opt: opt_lib.Optimizer,
             hc = hidden.reshape(B, nch, cfg.loss_chunk, -1).swapaxes(0, 1)
             lc = batch["labels"].reshape(B, nch, cfg.loss_chunk).swapaxes(
                 0, 1)
-            embed_t = params["embed"].T
+            embed_t = model.unembed(params)
 
             def body(tot, xs):
                 h, lb = xs

@@ -8,6 +8,7 @@ at a time may load the TPU library, and every test worker imports this
 file), and every test of the file skips when it cannot be described.
 Nothing runs, so these tests say nothing about results or times.
 """
+import dataclasses
 import os
 
 import pytest
@@ -22,6 +23,10 @@ from repro.kernels import ops
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 D_FF, D_MODEL = 5632, 2048          # stablelm-1.6b FFN (configs/stablelm_1p6b)
+# DeepSeek-V2-Lite (configs/deepseek_v2_lite): dense FFN and shared experts,
+# float32 as its benchmark cell runs them; the cell's eval batch is 2 x 2048
+# tokens and its candidate chunk 8
+V2_DENSE, V2_SHARED, V2_TOKENS, V2_CHUNK = 10944, 2816, 4096, 8
 
 
 @pytest.fixture(scope="module")
@@ -81,6 +86,15 @@ CASES = {
                                                           kind="silu"),
         [((8, 64, D_FF), BF16), ((8, D_FF), F32), ((D_FF, D_MODEL), BF16),
          ((8, 64, D_FF), BF16)]),
+    **{f"masked_act_matmul_2d/deepseek_v2_lite_{k}": (
+        lambda x, m, w, u: K.masked_act_matmul_2d(x, m, w, u, kind="silu"),
+        [((512, f), F32), ((f,), F32), ((f, D_MODEL), F32), ((512, f), F32)])
+       for k, f in (("dense", V2_DENSE), ("shared", V2_SHARED))},
+    "masked_act_matmul_2d_batched/deepseek_v2_lite_shared": (
+        lambda x, m, w, u: K.masked_act_matmul_2d_batched(x, m, w, u,
+                                                          kind="silu"),
+        [((V2_CHUNK, V2_TOKENS, V2_SHARED), F32), ((V2_CHUNK, V2_SHARED), F32),
+         ((V2_SHARED, D_MODEL), F32), ((V2_CHUNK, V2_TOKENS, V2_SHARED), F32)]),
 }
 
 
@@ -99,3 +113,52 @@ def test_masked_act_train_step_compiles_for_v5e(one_chip, monkeypatch):
     text = _compiled_text(step, [((128, 32, 32, 64), F32),
                                  ((32, 32, 64), F32)], one_chip)
     assert "tpu_custom_call" in text
+
+
+def _v2_lite_share():
+    from repro.configs import get_config
+    return dataclasses.replace(get_config("deepseek_v2_lite"),
+                               dtype="float32").chip_share()
+
+
+def test_mla_compiles_for_v5e_at_the_cell_shapes(one_chip):
+    """Latent attention of a candidate chunk (8 x 2 x 2048 tokens) at
+    published widths, queries in chunks."""
+    from repro.models import layers, lm
+    cfg = _v2_lite_share()
+    c = lm._mla_cfg(cfg, cfg.pattern[0])
+    p = jax.eval_shape(lambda k: layers.mla_init(k, c, F32),
+                       jax.random.PRNGKey(0))
+    pos = jnp.broadcast_to(jnp.arange(2048)[None], (2, 2048))
+    fn = jax.vmap(lambda p, x: layers.mla_attention(p, c, x, pos),
+                  in_axes=(None, 0))
+    text = jax.jit(fn).lower(
+        jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                    sharding=one_chip), p),
+        jax.ShapeDtypeStruct((V2_CHUNK, 2, 2048, D_MODEL), F32,
+                             sharding=one_chip)).compile().as_text()
+    assert "while" in text       # the query chunks
+
+
+def test_held_experts_compile_for_v5e_at_the_cell_shapes(one_chip):
+    """The held-expert layer (router over 64, 8 experts held, drop-free
+    grouped matmuls, shared experts) for a candidate chunk whose masks
+    differ: the candidate axis folds into the sorted rows."""
+    from repro.core import linearize
+    from repro.models import lm, moe
+    cfg = _v2_lite_share()
+    mc = lm._moe_cfg(cfg)
+    p = jax.eval_shape(lambda k: moe.moe_init(k, mc, F32),
+                       jax.random.PRNGKey(0))
+    site = linearize.MaskSite((8, 1408), "silu")
+    shared = linearize.MaskSite((V2_SHARED,), "silu")
+    fn = jax.vmap(lambda p, x, m, ms: moe.moe_ffn(
+        p, mc, x, m, site, shared_mask=ms, shared_site=shared),
+        in_axes=(None, None, 0, 0))
+    sd = lambda shape, dt=F32: jax.ShapeDtypeStruct(shape, dt,
+                                                    sharding=one_chip)
+    text = jax.jit(fn).lower(
+        jax.tree.map(lambda a: sd(a.shape, a.dtype), p),
+        sd((2, 2048, D_MODEL)), sd((V2_CHUNK, 8, 1408)),
+        sd((V2_CHUNK, V2_SHARED))).compile().as_text()
+    assert "tpu_custom_call" in text      # the grouped matmuls
