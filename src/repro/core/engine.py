@@ -11,14 +11,17 @@ One BCD outer step evaluates up to RT candidate mask trees; the engine decides
     vmap compile time dominates.
 
 ``BatchedEvaluator``
-    Stacks the candidate axis through ``jax.vmap`` and evaluates a whole chunk
-    in a single jitted call.  Masks stay jit *inputs* (no recompile across
-    chunks); ragged final chunks are padded to the chunk size so the jit cache
-    holds exactly one entry per (chunk, shapes) signature.
+    Evaluates a whole chunk in a single jitted call.  On one device the
+    program loops over the chunk's real candidates (``lax.fori_loop``), one
+    plain forward each, and skips the padded slots; under a mesh it stacks
+    the candidate axis through ``jax.vmap``, which the mesh shards.  Masks
+    stay jit *inputs* (no recompile across chunks); ragged final chunks are
+    padded to the chunk size so the jit cache holds exactly one entry per
+    (chunk, shapes) signature.
 
 ``ShardedEvaluator``
-    BatchedEvaluator plus ``jax.sharding``: the candidate axis is laid out
-    across the mesh (``launch.mesh``), so RT trials cost RT / n_devices
+    BatchedEvaluator's vmap plus ``jax.sharding``: the candidate axis is laid
+    out across the mesh (``launch.mesh``), so RT trials cost RT / n_devices
     forward passes of wall-clock.  On a 2-D ``("cand", "batch")`` mesh
     (``launch.mesh.make_cand_batch_mesh``) it picks a ``PartitionSpec`` per
     call: chunks with at least one candidate per device shard jointly over
@@ -29,7 +32,7 @@ One BCD outer step evaluates up to RT candidate mask trees; the engine decides
 ``PipelinedEvaluator``
     Double-buffered staging on top of batched/sharded placement:
     :meth:`~BatchedEvaluator.stage` pads a chunk, starts its host→device
-    transfer, and dispatches the vmapped computation (jax dispatch is async),
+    transfer, and dispatches the chunk's computation (jax dispatch is async),
     so the trial loop (:func:`evaluate_prefetched`) materializes and stages
     chunk k+1 while the device still computes chunk k.
 
@@ -71,8 +74,8 @@ from . import tracing
 
 
 def _donate_mask_arg():
-    """``donate_argnums`` for the per-chunk mask stack (argument 0 of the
-    vmapped eval): donating lets XLA reuse the stack's buffers, so a staged
+    """``donate_argnums`` for the per-chunk mask stack (argument 0 of a
+    chunk program): donating lets XLA reuse the stack's buffers, so a staged
     pipeline stops holding two live copies of every padded chunk.  CPU
     backends don't implement donation and would warn per dispatch, so the
     hint is only emitted where it can be honored."""
@@ -252,6 +255,27 @@ def _with_stacked_route(eval_fn, *, fused: bool = False):
     return routed
 
 
+def _looped(eval_fn):
+    """A chunk program for one device: candidates ``0 .. n-1`` of the mask
+    stack, one after another, each a plain forward of ``eval_fn`` (no vmap,
+    so every mask site runs the per-candidate gate the step's evaluations
+    run).  ``n`` is traced, so one program serves every fill of a padded
+    chunk, and slots ``n ..`` are never computed (they read 0).
+
+    A vmap over the chunk would keep every candidate's activations live at
+    once, in a candidate-minor layout that costs relayout copies at every
+    site: about twice a single forward's memory traffic per candidate."""
+    def run(stacked, n, *ctx):
+        def body(i, accs):
+            masks = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+                stacked)
+            return accs.at[i].set(eval_fn(masks, *ctx))
+        slots = M.stacked_len(stacked)
+        return jax.lax.fori_loop(0, n, body, jnp.zeros((slots,), jnp.float32))
+    return run
+
+
 class SequentialEvaluator:
     """Reference backend: unstack and evaluate one candidate at a time."""
 
@@ -271,7 +295,12 @@ class SequentialEvaluator:
 
 
 class BatchedEvaluator:
-    """vmap-over-masks backend: one jitted call per chunk of candidates."""
+    """One jitted call per chunk of candidates: a loop over the chunk's real
+    candidates on one device (:func:`_looped`), a vmap over the chunk under
+    a mesh (:class:`ShardedEvaluator`), where the candidate axis is what
+    shards across devices.  Both count ``engine.fallback_forwards`` (the
+    forwards the program computes) and ``engine.fallback_slots`` (the slots
+    dispatched, padding included)."""
 
     name = "batched"
     preferred_chunk = None
@@ -293,16 +322,15 @@ class BatchedEvaluator:
         # tree makes every dispatch re-transfer them (and re-hash the host
         # arrays), which is pure per-chunk overhead on the hot path
         self.context = None if context is None else jax.device_put(context)
-        routed = _with_stacked_route(eval_fn)
         # the mask stack (arg 0) is donated: each staged chunk's stack is a
         # fresh buffer (_device_batch copies) read by exactly one dispatch,
         # so XLA may reuse it in place of a second live copy
-        if self._has_ctx:
-            self._vmapped = jax.jit(jax.vmap(routed, in_axes=(0, None)),
-                                    donate_argnums=_donate_mask_arg())
-        else:
-            self._vmapped = jax.jit(jax.vmap(routed),
-                                    donate_argnums=_donate_mask_arg())
+        self._looped = jax.jit(_looped(eval_fn),
+                               donate_argnums=_donate_mask_arg())
+        self._vmapped = jax.jit(
+            jax.vmap(_with_stacked_route(eval_fn),
+                     in_axes=(0, None) if self._has_ctx else 0),
+            donate_argnums=_donate_mask_arg())
         self._pad_to = pad_to
 
     def set_context(self, context) -> None:
@@ -312,7 +340,7 @@ class BatchedEvaluator:
         self.context = jax.device_put(context)
 
     def _device_batch(self, stacked: M.MaskTree):
-        # copy=True: the stack is donated into the vmapped eval, so leaves
+        # copy=True: the stack is donated into the chunk program, so leaves
         # must be buffers this evaluator owns — jnp.asarray would alias a
         # caller's already-on-device float32 array and donation would
         # delete it out from under them
@@ -331,9 +359,17 @@ class BatchedEvaluator:
         if self._pad_to is not None and n < self._pad_to:
             stacked = M.pad_stacked(stacked, self._pad_to)
         batch = self._device_batch(stacked)
-        with _mesh_scope(self._mesh):
-            accs = self._vmapped(batch, self.context) if self._has_ctx \
-                else self._vmapped(batch)
+        slots = M.stacked_len(batch)
+        ctx = (self.context,) if self._has_ctx else ()
+        if self._mesh is None:
+            forwards = n
+            accs = self._looped(batch, jax.device_put(np.int32(n)), *ctx)
+        else:
+            forwards = slots            # the vmap computes every slot
+            with _mesh_scope(self._mesh):
+                accs = self._vmapped(batch, *ctx)
+        tracing.count("engine.fallback_forwards", forwards)
+        tracing.count("engine.fallback_slots", slots)
         return StagedChunk(n, accs)
 
     def evaluate_staged(self, staged: StagedChunk) -> np.ndarray:

@@ -13,9 +13,9 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.core import bcd, engine, linearize, masks as M
+from repro.core import bcd, engine, linearize, masks as M, tracing
 from repro.data import ImageDatasetCfg, SyntheticImages
-from repro.launch import mesh as mesh_lib
+from repro.launch import mesh as mesh_lib, sweep
 from repro.models.resnet import CNN, CNNConfig
 from repro.training import optimizer as opt_lib, train as train_lib
 
@@ -134,6 +134,68 @@ def test_evaluator_accs_agree(setup):
                                atol=1e-4)
 
 
+def _pad_with_nan(stacked, n):
+    """``masks.pad_stacked`` with NaN masks in the padded slots: a padded
+    slot a program computed would read an accuracy other than 0."""
+    return {k: np.concatenate([v, np.full((n - len(v),) + v.shape[1:],
+                                          np.nan, np.float32)])
+            for k, v in stacked.items()}
+
+
+@pytest.mark.parametrize("backend", ["batched", "pipelined"])
+@pytest.mark.parametrize("fill", [1, 3, 4])
+def test_looped_chunk_matches_sequential_and_skips_padding(
+        setup, monkeypatch, backend, fill):
+    """On one device a chunk is one program that loops over its real
+    candidates: the same accuracies as the sequential reference at every
+    fill of a 4-slot chunk, whatever the padded slots hold, and the padded
+    slots are never computed (they read 0 and are not counted)."""
+    model, params, batch, masks0 = setup
+    pad_to = 4
+    stacked = M.sample_removal_blocks(np.random.default_rng(fill), masks0,
+                                      16, fill)
+    want = engine.SequentialEvaluator(
+        model.make_eval_acc(params, batch)).evaluate(stacked)
+    ev = engine.make_evaluator(backend, pad_to=pad_to, context=params,
+                               eval_fn=model.make_param_eval_fn(batch))
+    monkeypatch.setattr(M, "pad_stacked", _pad_with_nan)
+    with tracing.recording() as rec:
+        staged = ev.stage(stacked)
+        got = ev.evaluate_staged(staged)
+    np.testing.assert_allclose(got, want, atol=1e-4)
+    np.testing.assert_array_equal(np.asarray(staged.accs)[fill:], 0.0)
+    assert rec.counts["engine.fallback_forwards"] == fill
+    assert rec.counts["engine.fallback_slots"] == pad_to
+    assert (ev._looped._cache_size(), ev._vmapped._cache_size()) == (1, 0)
+
+
+def test_bcd_steps_with_looped_fallback_select_like_sequential(setup):
+    """Every mask live, so sampled blocks touch shallow sites and the suffix
+    engine sends chunks down its looped full-forward fallback: bcd_steps
+    still selects the sequential reference's blocks."""
+    model, params, batch, masks0 = setup
+    cfg = bcd.BCDConfig(b_target=0, drc=16, rt=8, adt=-1000.0, seed=5,
+                        chunk_size=4)
+    states = {}
+    for name in ("sequential", "suffix"):
+        ev, eval_acc, _ = sweep.make_bcd_evaluator(
+            name, model, batch, {"params": params}, chunk_size=4, rt=8)
+        states[name] = bcd.init_state(masks0, cfg)
+        gen = bcd.bcd_steps(states[name], cfg, eval_acc, evaluator=ev)
+        with tracing.recording() as rec:
+            for _ in range(3):
+                next(gen)
+        gen.close()
+        if name == "suffix":
+            assert rec.counts["engine.fallback_forwards"] > 0
+    seq, sfx = states["sequential"], states["suffix"]
+    for k in seq.masks:
+        np.testing.assert_array_equal(seq.masks[k], sfx.masks[k])
+    for a, b in zip(seq.history, sfx.history):
+        assert (a.trials, a.budget_after) == (b.trials, b.budget_after)
+        assert a.best_drop == pytest.approx(b.best_drop, abs=1e-4)
+
+
 def test_lm_eval_closures_batched_matches_sequential():
     """The LM path: masks ride the scanned stack as stacked xs; vmapping the
     candidate axis over the scan must agree with the sequential loop."""
@@ -218,6 +280,72 @@ def test_sharded_on_forced_multi_device_mesh():
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "SHARDED_OK" in out.stdout
+
+
+_MESH_PATH_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np, jax
+from repro.core import engine, linearize, masks as M, tracing
+from repro.data import ImageDatasetCfg, SyntheticImages
+from repro.launch import mesh as mesh_lib
+from repro.models.resnet import CNN, CNNConfig
+
+model = CNN(CNNConfig("tiny", 4, 8, ((4, 1, 1),), stem_channels=4))
+data = SyntheticImages(ImageDatasetCfg(n_classes=4, image_size=8,
+                                       n_train=64, n_test=32))
+params = model.init(jax.random.PRNGKey(0))
+batch = data.train_eval_set(16)
+masks0 = linearize.init_masks(model.mask_sites())
+stacked = M.sample_removal_blocks(np.random.default_rng(0), masks0, 8, 6)
+want = engine.SequentialEvaluator(
+    model.make_eval_acc(params, batch)).evaluate(stacked)
+mesh = mesh_lib.make_cand_batch_mesh(cand=2, batch=2)
+ctx = {"params": params, "batch": {k: np.asarray(v) for k, v in batch.items()}}
+specs = engine.context_batch_specs(ctx)
+sfx = engine.make_evaluator("suffix", split=model.make_suffix_eval_fns(),
+                            mesh=mesh, context=ctx, context_specs=specs,
+                            pad_to=4)
+# (evaluator, the chunk evaluator inside it, chunk, slots dispatched): the
+# 1-D mesh pads 6 candidates to its 4 devices; the joint mesh lays 6 over
+# "cand" alone (docs/bcd_engine.md, "Joint candidate×batch sharding")
+cases = (
+    (engine.ShardedEvaluator(model.make_eval_fn(params, batch),
+                             mesh_lib.make_candidate_mesh()), None,
+     stacked, 8),
+    (engine.PipelinedEvaluator(model.make_joint_eval_fn(), mesh=mesh,
+                               context=ctx, context_specs=specs, pad_to=4),
+     None, stacked, 6),
+    (sfx, sfx._inner, engine.SitedChunk(None, stacked), 6))
+for ev, inner, chunk, slots in cases:
+    inner = inner or ev
+    with tracing.recording() as rec:
+        np.testing.assert_allclose(ev.evaluate(chunk), want, atol=1e-4)
+    # the vmap computes every slot it is given, padding included
+    assert dict(rec.counts) == {"engine.fallback_forwards": slots,
+                                "engine.fallback_slots": slots}, rec.counts
+    assert inner._vmapped._cache_size() == 1, ev.name
+    assert inner._looped._cache_size() == 0, ev.name
+print("MESH_PATH_OK")
+"""
+
+
+def test_meshed_evaluators_keep_the_vmapped_path():
+    """Under a mesh the candidate axis is what shards across devices, so
+    the sharded, meshed pipelined and meshed suffix fallback evaluators keep
+    the vmapped chunk program (every slot computed), not the one-device
+    loop — and still match the sequential reference."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep + \
+        env.get("PYTHONPATH", "")
+    out = subprocess.run([sys.executable, "-c", _MESH_PATH_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert "MESH_PATH_OK" in out.stdout
 
 
 # ------------------------------------------------- chunked ADT semantics

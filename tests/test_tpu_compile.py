@@ -162,3 +162,33 @@ def test_held_experts_compile_for_v5e_at_the_cell_shapes(one_chip):
         sd((2, 2048, D_MODEL)), sd((V2_CHUNK, 8, 1408)),
         sd((V2_CHUNK, V2_SHARED))).compile().as_text()
     assert "tpu_custom_call" in text      # the grouped matmuls
+
+
+def test_looped_fallback_compiles_for_v5e_in_a_quarter_of_the_vmaps_temp(
+        one_chip, monkeypatch):
+    """The full-forward fallback's chunk program on one chip, ResNet18 at a
+    512-image eval batch and a chunk of 8, with the TPU's gate kernel: the
+    loop over candidates compiles, and holds one candidate's activations
+    where the candidate vmap holds eight in a candidate-minor layout, so
+    its temp memory is under a quarter of the vmapped program's."""
+    from repro.core import engine
+    from repro.models.resnet import CNN, CNNConfig
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    model = CNN(CNNConfig.resnet18(100, 32))
+    split = model.make_suffix_eval_fns()
+    sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    ctx = {"params": jax.eval_shape(model.init, jax.random.PRNGKey(0)),
+           "batch": {"images": jax.ShapeDtypeStruct((512, 32, 32, 3), F32),
+                     "labels": jax.ShapeDtypeStruct((512,), jnp.int32)}}
+    ctx = jax.tree.map(sd, {**ctx, "pre": jax.eval_shape(split.pre, ctx)})
+    masks = {k: sd(jax.ShapeDtypeStruct((8,) + s.shape, F32))
+             for k, s in model.mask_sites().items()}
+    # the context here only marks eval_fn as taking one; shapes come below
+    ev = engine.BatchedEvaluator(split.full, pad_to=8, context={})
+    looped = ev._looped.lower(masks, sd(jax.ShapeDtypeStruct((), jnp.int32)),
+                              ctx).compile()
+    vmapped = ev._vmapped.lower(masks, ctx).compile()
+    text = looped.as_text()
+    assert "while" in text and "tpu_custom_call" in text
+    assert looped.memory_analysis().temp_size_in_bytes < \
+        vmapped.memory_analysis().temp_size_in_bytes / 4
