@@ -1,8 +1,8 @@
 """Smoke run on the TPU: the quickest proof that the system starts on the chip.
 
     python3 chip_smoke.py              # one chip: kernels, bcd, serve
-    python3 chip_smoke.py --chips 4    # the sharded BCD engine over 4 chips,
-                                       # against the batched engine on 1 chip
+    python3 chip_smoke.py --chips 4    # the full-forward evaluator over a
+                                       # 4-chip mesh, against it on 1 chip
 
 One process holds the chip throughout and starts no children.  It fails at
 once unless JAX's first device is a TPU.  Phases run in order, each through
@@ -369,7 +369,7 @@ def phase_serve() -> None:
 # ------------------------------------------------------------- four chips
 
 
-def phase_sharded() -> None:
+def phase_mesh() -> None:
     import jax
     from repro.core import engine, linearize, masks as M
     from repro.data import ImageDatasetCfg, SyntheticImages
@@ -395,22 +395,20 @@ def phase_sharded() -> None:
           f"mesh {mesh} does not span 4 devices")
     ctx = {"params": params,
            "batch": {k: np.asarray(v) for k, v in eval_b.items()}}
-    sharded = engine.make_evaluator(
-        "sharded", eval_fn=model.make_joint_eval_fn(), mesh=mesh,
-        pad_to=chunk, context=ctx,
+    meshed = engine.PipelinedEvaluator(
+        model.make_joint_eval_fn(), mesh=mesh, pad_to=chunk, context=ctx,
         context_specs=engine.context_batch_specs(ctx))
-    placed = sharded._device_batch(chunks[0])
+    placed = meshed._device_batch(chunks[0])
     for k, leaf in placed.items():
         devs = {s.device.id for s in leaf.addressable_shards}
         slices = {str(s.index) for s in leaf.addressable_shards}
         check(len(devs) == 4 and len(slices) == 4,
               f"candidate chunk leaf {k} not split over 4 devices: "
               f"{len(devs)} devices, {len(slices)} distinct slices")
-    batched = engine.make_evaluator(
-        "batched", eval_fn=model.make_param_eval_fn(eval_b), pad_to=chunk,
-        context=params)
-    acc_s = np.concatenate([sharded.evaluate(c) for c in chunks])
-    acc_b = np.concatenate([batched.evaluate(c) for c in chunks])
+    one_chip = engine.PipelinedEvaluator(
+        model.make_param_eval_fn(eval_b), pad_to=chunk, context=params)
+    acc_s = np.concatenate([meshed.evaluate(c) for c in chunks])
+    acc_b = np.concatenate([one_chip.evaluate(c) for c in chunks])
     gap = float(np.max(np.abs(acc_s - acc_b)))
     base = float(model.make_eval_acc(params, eval_b)(masks))
 
@@ -420,11 +418,11 @@ def phase_sharded() -> None:
         return int(hits[0]) if hits.size else int(np.argmin(drops))
 
     same = select(acc_s) == select(acc_b)
-    print(f"  sharded (2x2 cand x batch mesh) vs batched (1 chip) on {rt} "
+    print(f"  meshed (2x2 cand x batch mesh) vs one chip on {rt} "
           f"ResNet18 candidates: max|dacc|={gap:.4f}pp (tol {ACC_TOL:.4f}pp)"
           f"; selected block same: {same} ({select(acc_s)} vs "
           f"{select(acc_b)})", flush=True)
-    check(gap <= ACC_TOL, f"sharded engine disagrees by {gap}pp")
+    check(gap <= ACC_TOL, f"meshed evaluator disagrees by {gap}pp")
 
 
 # --------------------------------------------------------------------- main
@@ -433,7 +431,7 @@ def phase_sharded() -> None:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4: run only the sharded-engine path over 4 chips")
+                    help="4: run only the meshed evaluator over 4 chips")
     args = ap.parse_args(argv)
 
     import jax
@@ -442,20 +440,21 @@ def main(argv=None) -> int:
         print(f"chip_smoke: JAX found no TPU (first device: {dev.platform}); "
               "nothing was run", file=sys.stderr)
         return 1
+    from repro.core import tracing
     from repro.launch import compile_cache
     print(f"compile cache: {compile_cache.enable()}", flush=True)
-    cache_hits = compile_cache.hit_counter()
 
     t0 = time.perf_counter()
-    phases = ([("sharded", phase_sharded)] if args.chips == 4 else
+    phases = ([("mesh", phase_mesh)] if args.chips == 4 else
               [("kernels", phase_kernels), ("bcd", phase_bcd),
                ("serve", phase_serve)])
-    for name, fn in phases:
-        with Phase(name):
-            fn()
+    with tracing.recording() as rec:
+        for name, fn in phases:
+            with Phase(name):
+                fn()
     print(f"[smoke timing] total_s={time.perf_counter() - t0:.3f}",
           flush=True)
-    print(cache_hits.log_line(), flush=True)
+    print(compile_cache.log_line(rec.counts), flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(jax.devices())}}))

@@ -25,18 +25,12 @@ data.  What's family-specific is all below the shared engine contract:
 
 After the sweep, the mid-scan suffix path is exercised explicitly: a
 block of candidates local to the DEEPEST per-repeat stack site is driven
-through the suffix evaluator (asserting carry-checkpointed sited chunks
-actually ran) and timed against the batched engine; the measured
-``speedup_suffix_vs_batched`` lands as one line in BENCH_history.jsonl
-(same row shape as benchmarks/bench_bcd_eval.py, so
-``SuffixCostModel.calibrated`` consumes it on later runs).
+through the suffix evaluator, asserting that carry-checkpointed sited
+chunks actually ran and that their accuracies match the sequential
+reference.
 """
 import argparse
-import datetime
-import json
 import os
-import subprocess
-import time
 
 import numpy as np
 import jax
@@ -60,11 +54,8 @@ def parse_args():
                          "(rwkv6_3b, zamba2_2p7b), MoE (deepseek_moe_16b, "
                          "mixtral_8x22b), or dense")
     ap.add_argument("--engine", default="suffix",
-                    choices=["sequential", "batched", "sharded",
-                             "pipelined", "suffix"])
+                    choices=["sequential", "suffix"])
     ap.add_argument("--chunk-size", type=int, default=4)
-    ap.add_argument("--prefetch", default="2",
-                    help="staged-ahead chunks (pipelined/suffix), or 'auto'")
     ap.add_argument("--moves", default="remove",
                     help="comma-separated move kinds (subset of "
                          f"{','.join(M.MOVE_KINDS)})")
@@ -81,23 +72,12 @@ def parse_args():
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=32)
     ap.add_argument("--eval-batch", type=int, default=4)
-    ap.add_argument("--bench-history", default=None,
-                    help="append the post-sweep mid-scan suffix-vs-batched "
-                         "timing here (default: <out-dir>/BENCH_history"
-                         ".jsonl; 'none' to skip)")
     args = ap.parse_args()
     args.moves = tuple(k.strip() for k in args.moves.split(","))
     for kind in args.moves:
         if kind not in M.MOVE_KINDS:
             ap.error(f"--moves: unknown kind {kind!r}")
-    if args.prefetch != "auto":
-        args.prefetch = int(args.prefetch)
-    elif args.engine not in ("pipelined", "suffix"):
-        ap.error("--prefetch auto requires --engine pipelined or suffix")
     args.sweep = [float(f) for f in args.sweep.split(",")]
-    if args.bench_history is None:
-        args.bench_history = os.path.join(args.out_dir,
-                                          "BENCH_history.jsonl")
     return args
 
 
@@ -124,112 +104,51 @@ def make_closures(model, mt, args):
     return batches, sloss, test_acc
 
 
-def _time_sited_sweep(ev, masks, indices, chunk):
-    """One full drive of ``indices`` through ``ev`` via the real trial-loop
-    path (site-major plan for site-aware backends); returns (seconds,
-    [sited chunk names])."""
-    flat, layout = M._flatten(masks)
-    sited_names = []
-    if getattr(ev, "site_aware", False):
-        ev.begin_step(masks)
-        order, chunks = engine.plan_sited_chunks(ev, indices, layout, chunk)
-        sited_names = [c[0] for c in chunks if c[0] is not None]
-        gen = engine.materialize_sited(flat, layout, indices, order, chunks)
-    else:
-        gen = M.materialize_chunks(flat, layout, indices, chunk)
-    t0 = time.perf_counter()
-    for _accs in engine.evaluate_prefetched(ev, gen):
-        pass
-    return time.perf_counter() - t0, sited_names
-
-
-def record_midscan_speedup(args, model, masks, params, eval_b):
-    """Exercise the carry-checkpointed suffix path at a mid-scan stack site
-    and record its measured speedup over the batched engine.
+def check_midscan_suffix(args, model, masks, params, eval_b):
+    """Exercise the carry-checkpointed suffix path at a mid-scan stack site.
 
     Candidates are site-local to the DEEPEST per-repeat virtual site
     (``s<pos>.<kind>@r``, r >= 1): the suffix engine's prefix runs the
     scan up to repeat r and checkpoints the carry; each candidate then
-    re-runs only repeats r.. and the tail.  Appends one
-    bench-history-compatible line (per_site_depth row keyed "midscan") and
-    returns the entry, or None when the family has no scanned stack."""
+    re-runs only repeats r.. and the tail.  Asserts that such a sited chunk
+    ran and that every accuracy matches the sequential reference; returns
+    the site, or None when the family has no scanned stack."""
     mid = [s for s in model.site_order()
            if "@" in s and int(s.rsplit("@", 1)[1]) >= 1]
     if not mid:
         print(f"[midscan] {model.cfg.name}: no per-repeat stack sites — "
-              "skipping the mid-scan timing")
+              "skipping the mid-scan check")
         return None
     site = mid[-1]
-    rt, reps = 16, 3
+    rt = 16
     chunk = min(args.chunk_size, rt)
     indices = M.sample_removal_indices_within(
         np.random.default_rng(7), masks, 8, rt, [site],
         repeat_sites=model.site_repeats())
-    holder = {"params": params}
-    suffix_ev, _, _ = sweep_lib.make_bcd_evaluator(
-        "suffix", model, eval_b, holder, chunk_size=chunk, rt=rt,
-        fused_kernels="share" not in args.moves)
-    batched_ev, _, _ = sweep_lib.make_bcd_evaluator(
-        "batched", model, eval_b, holder, chunk_size=chunk, rt=rt)
-
-    # warmup (compile + trie-populate), then check the plan really routed
-    # the chunk through a carry-checkpointed sited evaluation
-    _, sited = _time_sited_sweep(suffix_ev, masks, indices, chunk)
-    _time_sited_sweep(batched_ev, masks, indices, chunk)
-    ran_midscan = any("@" in s and int(s.rsplit("@", 1)[1]) >= 1
-                      for s in sited)
-    trie = suffix_ev.trie
-    assert ran_midscan and (trie.misses + trie.extensions) > 0, (
+    ev, eval_acc, _ = sweep_lib.make_bcd_evaluator(
+        "suffix", model, eval_b, {"params": params}, chunk_size=chunk,
+        rt=rt, fused_kernels="share" not in args.moves)
+    flat, layout = M._flatten(masks)
+    ev.begin_step(masks)
+    order, chunks = engine.plan_sited_chunks(ev, indices, layout, chunk)
+    accs = np.concatenate(list(engine.evaluate_prefetched(
+        ev, engine.materialize_sited(flat, layout, indices, order,
+                                     chunks))))
+    sited = [c[0] for c in chunks if c[0] is not None]
+    trie = ev.trie
+    assert any("@" in s and int(s.rsplit("@", 1)[1]) >= 1 for s in sited) \
+        and (trie.misses + trie.extensions) > 0, (
         f"mid-scan candidates at {site} fell back to the full forward "
         f"(sited={sited}) — the carry-checkpoint suffix path did not run")
-
-    # paired timing: alternate engines so host drift cancels in the ratio
-    ratios, b_cps, s_cps = [], [], []
-    for _ in range(reps):
-        dt_s, _ = _time_sited_sweep(suffix_ev, masks, indices, chunk)
-        dt_b, _ = _time_sited_sweep(batched_ev, masks, indices, chunk)
-        ratios.append(dt_b / dt_s)
-        s_cps.append(len(indices) / dt_s)
-        b_cps.append(len(indices) / dt_b)
-    ratio = round(float(np.median(ratios)), 2)
+    want = engine.SequentialEvaluator(eval_acc).evaluate(
+        M.materialize_candidates(masks, indices))
+    np.testing.assert_allclose(accs, want[order], atol=1e-4)
     frac = float(model.site_prefix_fractions()[site])
-    print(f"[midscan] {model.cfg.name} {site}: suffix vs batched "
-          f"{ratio:.2f}x (prefix_fraction={frac:.2f}, "
-          f"trie misses={trie.misses} extensions={trie.extensions})")
-
-    if args.bench_history == "none":
-        return None
-    try:
-        git = subprocess.run(["git", "rev-parse", "--short", "HEAD"],
-                             capture_output=True, text=True,
-                             timeout=10).stdout.strip() or None
-    except Exception:
-        git = None
-    entry = {
-        "utc": datetime.datetime.now(datetime.timezone.utc)
-        .strftime("%Y-%m-%dT%H:%M:%SZ"),
-        "git": git,
-        "config": {"model": model.cfg.name, "chunk_size": chunk,
-                   "eval_batch": args.eval_batch,
-                   "n_devices": jax.device_count(),
-                   "backend": jax.default_backend(),
-                   "source": "family_bcd_sweep"},
-        "per_site_depth": {"midscan": {
-            "site": site,
-            "prefix_fraction": round(frac, 4),
-            "mode": "suffix",
-            "batched_cands_per_s": round(float(np.median(b_cps)), 2),
-            "suffix_cands_per_s": round(float(np.median(s_cps)), 2),
-            "speedup_suffix_vs_batched": ratio,
-        }},
-        "speedup_suffix_vs_batched_midscan": ratio,
-    }
-    os.makedirs(os.path.dirname(args.bench_history) or ".", exist_ok=True)
-    with open(args.bench_history, "a") as f:
-        json.dump(entry, f, separators=(",", ":"))
-        f.write("\n")
-    print(f"[midscan] recorded -> {args.bench_history}")
-    return entry
+    print(f"[midscan] {model.cfg.name} {site}: {rt} candidates on the "
+          f"carry-checkpointed suffix path match the sequential reference "
+          f"(prefix_fraction={frac:.2f}, trie misses={trie.misses} "
+          f"extensions={trie.extensions})")
+    return site
 
 
 def main():
@@ -282,8 +201,7 @@ def run(args):
         mt.batch(args.eval_batch, args.seq, 10**6 + 1)["tokens"])}
     evaluator, eval_acc, set_ctx = sweep_lib.make_bcd_evaluator(
         args.engine, model, eval_b, holder, chunk_size=args.chunk_size,
-        rt=6, prefetch=args.prefetch,
-        fused_kernels="share" not in args.moves)
+        rt=6, fused_kernels="share" not in args.moves)
 
     def set_params(p):
         holder["params"] = p
@@ -310,7 +228,7 @@ def run(args):
         stage_finetune=stage_ft,
         stage_eval=test_acc,
         notes={"arch": args.arch, "engine": args.engine,
-               "prefetch": str(args.prefetch), "overlap": args.overlap,
+               "overlap": args.overlap,
                "moves": list(args.moves), "proposal": args.proposal},
         coordinator=coordinator)
 
@@ -322,8 +240,8 @@ def run(args):
               f"masks={s['mask_fingerprint'][:12]}")
 
     if coordinator.is_writer:
-        record_midscan_speedup(args, model, payload["final_masks"],
-                               holder["params"], eval_b)
+        check_midscan_suffix(args, model, payload["final_masks"],
+                             holder["params"], eval_b)
     return payload
 
 

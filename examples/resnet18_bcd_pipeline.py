@@ -3,23 +3,19 @@ vs SNL(B_target) head-to-head (Fig. 1 / Table 3 protocol, synthetic CIFAR).
 
     PYTHONPATH=src python examples/resnet18_bcd_pipeline.py \
         [--image-size 16] [--ref-frac 0.6] [--target-frac 0.4] [--full] \
-        [--engine batched] [--chunk-size 8] [--prefetch 2|auto] \
+        [--engine suffix|sequential] [--chunk-size 8] \
         [--moves remove,add_back,swap,stage_drop,share] \
         [--proposal uniform|sensitivity]
 
 --full uses the real ResNet18 geometry at 32x32 (slow on CPU); the default
 uses a reduced stage plan with the same code path.  --engine selects the BCD
-candidate-evaluation backend (core.engine): 'sequential' is the reference,
-'batched' vmaps candidate chunks into one jitted call, 'sharded' additionally
-lays the candidate axis out across all local devices, 'pipelined'
-double-buffers candidate staging — while the device evaluates chunk k, the
-host materializes and transfers chunk k+1 (--prefetch chunks stay in flight;
-``--prefetch auto`` measures producer vs consumer rates on the first chunks
-and picks the depth itself) — and 'suffix' adds prefix reuse: candidate
-chunks are grouped by the segment of their earliest mutated mask site, the
-shared forward prefix is computed once per site per step, and only the
-suffix is vmapped per candidate (docs/bcd_engine.md).  Selection is
-bit-identical across engines for a fixed seed.  --moves widens the
+candidate evaluation (core.engine): 'suffix' (the default) groups candidate
+chunks by the segment of their earliest mutated mask site, computes the
+shared forward prefix once per site per step and vmaps only the suffix per
+candidate; shallow cuts take the full-forward fallback, staged two chunks
+ahead of the one being read (docs/bcd_engine.md).  'sequential' is the
+reference, one forward per candidate.  Selection is bit-identical across
+engines for a fixed seed.  --moves widens the
 coordinate-descent move set beyond the paper's removals (docs/bcd_engine.md
 §Move vocabulary) and --proposal sensitivity weights kinds/sites by their
 running acceptance rates; per-kind accepted/proposed counters land in the
@@ -31,7 +27,7 @@ counts print at exit).
 Sweep mode (the paper's accuracy-vs-budget curve, Fig. 4 protocol):
 
     PYTHONPATH=src python examples/resnet18_bcd_pipeline.py \
-        --sweep 0.55,0.4 --out-dir runs/r18 [--engine pipelined]
+        --sweep 0.55,0.4 --out-dir runs/r18 [--engine suffix]
 
 descends the budget schedule with warm-starting + finetuning between stages,
 checkpointing after every accepted block (launch.sweep / core.runner).  The
@@ -73,9 +69,8 @@ def parse_args():
     ap.add_argument("--ref-frac", type=float, default=0.6)
     ap.add_argument("--target-frac", type=float, default=0.4)
     ap.add_argument("--full", action="store_true")
-    ap.add_argument("--engine", default="batched",
-                    choices=["sequential", "batched", "sharded",
-                             "pipelined", "suffix"])
+    ap.add_argument("--engine", default="suffix",
+                    choices=["sequential", "suffix"])
     ap.add_argument("--chunk-size", type=int, default=8)
     ap.add_argument("--moves", default="remove",
                     help="comma-separated move kinds the descent samples "
@@ -87,10 +82,6 @@ def parse_args():
                     help="candidate proposal distribution: 'uniform', or "
                          "'sensitivity' to weight kinds/sites by their "
                          "running acceptance rates")
-    ap.add_argument("--prefetch", default="2",
-                    help="chunks kept staged ahead (pipelined/suffix "
-                         "engines), or 'auto' to pick from measured rates "
-                         "(pipelined and suffix)")
     ap.add_argument("--sweep", default=None,
                     help="comma-separated descending budget fractions "
                          "(e.g. '0.55,0.4'): run the multi-budget sweep "
@@ -110,14 +101,6 @@ def parse_args():
         if kind not in M.MOVE_KINDS:
             ap.error(f"--moves: unknown kind {kind!r} (expected a subset "
                      f"of {','.join(M.MOVE_KINDS)})")
-    if args.prefetch != "auto":
-        try:
-            args.prefetch = int(args.prefetch)
-        except ValueError:
-            ap.error(f"--prefetch must be an integer or 'auto', got "
-                     f"{args.prefetch!r}")
-    elif args.engine not in ("pipelined", "suffix"):
-        ap.error("--prefetch auto requires --engine pipelined or suffix")
     if args.sweep is not None:
         if args.out_dir is None:
             ap.error("--sweep requires --out-dir")
@@ -177,7 +160,7 @@ def make_bcd_evaluator(args, model, eval_b, holder, chunk_size, rt):
     un-fused when the move set can produce ties."""
     return sweep_lib.make_bcd_evaluator(
         args.engine, model, eval_b, holder, chunk_size=chunk_size, rt=rt,
-        prefetch=args.prefetch, fused_kernels="share" not in args.moves)
+        fused_kernels="share" not in args.moves)
 
 
 def run_sweep_mode(args):
@@ -243,17 +226,9 @@ def run_sweep_mode(args):
         params_io=(lambda: holder["params"], set_params),
         stage_finetune=stage_ft,
         stage_eval=lambda m, p: test_acc(p, m),
-        notes={"engine": args.engine, "prefetch": str(args.prefetch),
-               "overlap": args.overlap, "moves": list(args.moves),
-               "proposal": args.proposal},
+        notes={"engine": args.engine, "overlap": args.overlap,
+               "moves": list(args.moves), "proposal": args.proposal},
         coordinator=coordinator)
-
-    report = getattr(evaluator, "auto_report", None)
-    if report is not None and coordinator.is_writer:
-        print(f"[auto-prefetch] depth={report['prefetch']} "
-              f"producer={report['producer_s']:.4f}s "
-              f"consumer={report['consumer_s']:.4f}s")
-        sweep_lib.update_notes(sweep_cfg, {"auto_prefetch": report})
 
     print(f"\n=== sweep curve ({payload['artifact']}) ===")
     for s in payload["stages"]:

@@ -98,15 +98,15 @@ def main():
     token_acc = jax.jit(token_acc_fn)
     masks_h = linearize.init_masks(model.mask_sites())
     total = M.count(masks_h)
-    # Candidate trials go through the batched engine: one vmapped jitted
-    # call per chunk of candidate mask trees (masks ride the scanned stack
-    # as jit inputs — no recompilation across candidates).
+    # Candidate trials go through the full-forward pipelined evaluator: one
+    # jitted call per chunk of candidate mask trees (masks ride the scanned
+    # stack as jit inputs — no recompilation across candidates).
     res = bcd.run_bcd(
         masks_h,
         bcd.BCDConfig(b_target=total // 2, drc=max(1, total // 10), rt=4,
                       adt=0.5, finetune_every_step=False, chunk_size=4),
         lambda m: float(token_acc(M.as_device(m))),
-        evaluator=engine.BatchedEvaluator(token_acc_fn, pad_to=4),
+        evaluator=engine.PipelinedEvaluator(token_acc_fn, pad_to=4),
         verbose=True)
     print(f"BCD: kept {M.count(res.masks)}/{total} FFN nonlinearities; "
           f"token acc {float(token_acc(M.as_device(res.masks))):.1f}%")

@@ -15,7 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 
 @dataclasses.dataclass(frozen=True)
@@ -364,141 +364,6 @@ def lm_segment_fwd_flops(cfg, *, seq_len: int) -> list:
             + [rep] * cfg.n_repeats
             + [f(b) for b in cfg.tail]
             + [2.0 * seq_len * cfg.d_model * cfg.vocab])
-
-
-def _iter_bench_history(path):
-    """Yield parsed BENCH_history.jsonl entries, skipping malformed lines
-    (the file is append-only across heterogeneous tool versions)."""
-    import json
-    import os
-    if not os.path.exists(path):
-        return
-    with open(path) as fh:
-        for line in fh:
-            line = line.strip()
-            if not line:
-                continue
-            try:
-                entry = json.loads(line)
-            except json.JSONDecodeError:
-                continue
-            if isinstance(entry, dict):
-                yield entry
-
-
-@dataclasses.dataclass(frozen=True)
-class SuffixCostModel:
-    """Per-site decision: suffix-mode (prefix once + vmapped suffix) vs the
-    full-forward backends, for a chunk of ``n`` candidates cutting at a
-    site with ``prefix_fraction`` f of forward FLOPs above it.
-
-    Per-chunk cost ratio:  suffix / full = ((f - c) + (1 - f)·n) / n, where
-    ``c`` is the prefix fraction already resident in the evaluator's trie
-    (``covered``) — always <1 for n > 1 even cold, so the analytic *model*
-    says "always suffix"; the thresholds price what it can't see: a shallow
-    cut's win (f·(n-1) forwards) is smaller than its fixed overheads (one
-    extra jit per segment, the cached-acts residency, per-chunk plan/slice
-    work), so those sites fall back to the full path (``use_suffix() ==
-    False`` -> the evaluator's inner batched/sharded/pipelined backend
-    evaluates the chunk).
-
-    ``measured`` switches the decision from the analytic threshold to
-    observed hardware behavior: a tuple of ``(prefix_fraction, speedup,
-    chunk)`` points calibrated from ``BENCH_history.jsonl``
-    (:meth:`calibrated` — EWMA per site over matching config fingerprints,
-    with the analytic ratio as the cold-start prior via an implicit
-    ``(0.0, 1.0)`` anchor).  Suffix mode then runs wherever the
-    interpolated measured speedup clears ``min_speedup``; the 5% margin
-    absorbs dispatch overheads the FLOPs ratio can't see.
-    """
-
-    min_prefix_fraction: float = 0.05   # below this the reuse is noise
-    min_chunk: int = 2                  # n=1 reuses nothing
-    min_speedup: float = 1.05           # measured-mode margin over full path
-    measured: Optional[Tuple[Tuple[float, float, int], ...]] = None
-
-    def speedup(self, prefix_fraction: float, n: int,
-                covered: float = 0.0) -> float:
-        """Predicted candidates/sec gain of suffix mode for one chunk;
-        ``covered`` discounts prefix work already cached in the trie."""
-        f = min(max(prefix_fraction, 0.0), 1.0)
-        c = min(max(covered, 0.0), f)
-        return n / max((f - c) + (1.0 - f) * n, 1e-9)
-
-    def predicted_speedup(self, prefix_fraction: float, n: int,
-                          covered: float = 0.0) -> float:
-        """Measured-mode estimate: linear interpolation over the calibrated
-        ``(frac, speedup)`` points — anchored at (0, 1): zero prefix means
-        zero reuse — rescaled by the analytic ratio to the requested chunk
-        size and trie coverage (measurements are cold-trie, per-config
-        chunk)."""
-        if not self.measured:
-            return self.speedup(prefix_fraction, n, covered)
-        f = min(max(prefix_fraction, 0.0), 1.0)
-        pts = sorted(((0.0, 1.0, n),) + tuple(self.measured))
-        hi = next((p for p in pts if p[0] >= f), None)
-        lo = next((p for p in reversed(pts) if p[0] <= f), pts[0])
-        if hi is None:
-            base = lo
-        elif hi[0] == lo[0]:
-            base = hi
-        else:
-            w = (f - lo[0]) / (hi[0] - lo[0])
-            base = (f, lo[1] + w * (hi[1] - lo[1]),
-                    int(round(lo[2] + w * (hi[2] - lo[2]))) or n)
-        n0 = max(int(base[2]), 1)
-        scale = self.speedup(f, n, covered) / max(self.speedup(f, n0), 1e-9)
-        return base[1] * scale
-
-    def use_suffix(self, prefix_fraction: float, n: int,
-                   covered: float = 0.0) -> bool:
-        if n < self.min_chunk:
-            return False
-        if self.measured:
-            return (self.predicted_speedup(prefix_fraction, n, covered)
-                    >= self.min_speedup)
-        return prefix_fraction >= self.min_prefix_fraction
-
-    @classmethod
-    def calibrated(cls, history_path, *, fingerprint: Optional[dict] = None,
-                   alpha: float = 0.5, **kwargs) -> "SuffixCostModel":
-        """Calibrate from ``BENCH_history.jsonl``'s per-depth measurements.
-
-        Walks the history oldest-first, EWMA-folding (weight ``alpha`` on
-        the newer sample) each site's measured suffix-vs-batched speedup —
-        only rows the evaluator actually ran in suffix mode (``mode ==
-        "suffix"``), and only entries whose config matches ``fingerprint``
-        on every key the entry carries (model / device / eval-batch changes
-        must not pollute each other's rates).  Legacy history lines without
-        ``per_site_depth`` are skipped, so an empty or pre-measurement file
-        degrades to the pure analytic model (``measured=None``)."""
-        ewma: dict = {}
-        for entry in _iter_bench_history(history_path):
-            cfg = entry.get("config") or {}
-            if fingerprint and any(k in cfg and cfg[k] != v
-                                   for k, v in fingerprint.items()):
-                continue
-            rows = entry.get("per_site_depth")
-            if not isinstance(rows, dict):
-                continue
-            chunk = int(cfg.get("chunk_size") or 0)
-            for row in rows.values():
-                if not isinstance(row, dict) or row.get("mode") != "suffix":
-                    continue
-                try:
-                    site = row["site"]
-                    frac = float(row["prefix_fraction"])
-                    sp = float(row["speedup_suffix_vs_batched"])
-                except (KeyError, TypeError, ValueError):
-                    continue
-                prev = ewma.get(site)
-                if prev is not None:
-                    sp = (1 - alpha) * prev[1] + alpha * sp
-                    chunk = chunk or prev[2]
-                ewma[site] = (frac, sp, chunk)
-        measured = tuple(sorted((f, s, max(c, 1)) for f, s, c in
-                                ewma.values())) or None
-        return cls(measured=measured, **kwargs)
 
 
 def analytic_cell(cfg, shape, mode: str, *, remat: bool = True):

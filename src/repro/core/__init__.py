@@ -3,6 +3,6 @@ from . import masks, linearize, bcd, engine, snl, autorep, pi_cost, analysis  # 
 
 from .bcd import BCDConfig, run_bcd            # noqa: F401
 from .engine import (CandidateEvaluator, SequentialEvaluator,  # noqa: F401
-                     BatchedEvaluator, ShardedEvaluator, make_evaluator)
+                     PipelinedEvaluator, SuffixEvaluator)
 from .snl import SNLConfig, run_snl, finetune  # noqa: F401
 from .autorep import AutoRepConfig, run_autorep  # noqa: F401

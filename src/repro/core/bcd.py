@@ -162,9 +162,9 @@ def _select_block(
     chunk mask trees lazily from the pre-sampled indices, and
     ``engine.evaluate_prefetched`` stages up to ``evaluator.prefetch_depth``
     chunks (host materialization + H2D transfer + compute dispatch) ahead of
-    the chunk whose results are being consumed — double-buffering for the
-    PipelinedEvaluator, a plain materialize → evaluate alternation for
-    everything else (prefetch_depth 0).
+    the chunk whose results are being consumed — staged ahead for the
+    pipelined and suffix evaluators, a plain materialize → evaluate
+    alternation for the sequential reference (prefetch_depth 0).
 
     Selection is backend-independent: candidates are scanned in sampling
     order; the *first* candidate with drop < adt wins (ADT early exit —

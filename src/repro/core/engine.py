@@ -1,57 +1,43 @@
 """Candidate-evaluation engine for BCD (Alg. 2's hot path).
 
 One BCD outer step evaluates up to RT candidate mask trees; the engine decides
-*how*.  All backends implement the :class:`CandidateEvaluator` protocol —
+*how*.  The evaluators implement the :class:`CandidateEvaluator` protocol —
 ``evaluate(stacked_tree) -> (n,) accuracies`` — and are interchangeable from
 ``run_bcd``'s point of view:
 
 ``SequentialEvaluator``
-    The reference: one jitted forward per candidate, host loop.  Exactly the
-    seed repo's behavior, kept for equivalence testing and tiny configs where
-    vmap compile time dominates.
-
-``BatchedEvaluator``
-    Evaluates a whole chunk in a single jitted call.  On one device the
-    program loops over the chunk's real candidates (``lax.fori_loop``), one
-    plain forward each, and skips the padded slots; under a mesh it stacks
-    the candidate axis through ``jax.vmap``, which the mesh shards.  Masks
-    stay jit *inputs* (no recompile across chunks); ragged final chunks are
-    padded to the chunk size so the jit cache holds exactly one entry per
-    (chunk, shapes) signature.
-
-``ShardedEvaluator``
-    BatchedEvaluator's vmap plus ``jax.sharding``: the candidate axis is laid
-    out across the mesh (``launch.mesh``), so RT trials cost RT / n_devices
-    forward passes of wall-clock.  On a 2-D ``("cand", "batch")`` mesh
-    (``launch.mesh.make_cand_batch_mesh``) it picks a ``PartitionSpec`` per
-    call: chunks with at least one candidate per device shard jointly over
-    both axes; smaller chunks shard candidates over ``"cand"`` only and let a
-    batch-sharded *context* split each forward over ``"batch"`` — no device
-    idles when RT < n_devices.
+    The reference: one jitted forward per candidate, host loop.  Kept for
+    equivalence testing.
 
 ``PipelinedEvaluator``
-    Double-buffered staging on top of batched/sharded placement:
-    :meth:`~BatchedEvaluator.stage` pads a chunk, starts its host→device
-    transfer, and dispatches the chunk's computation (jax dispatch is async),
-    so the trial loop (:func:`evaluate_prefetched`) materializes and stages
-    chunk k+1 while the device still computes chunk k.
+    The full-forward evaluator: one jitted call per chunk of candidates.  On
+    one device the program loops over the chunk's real candidates
+    (``lax.fori_loop``), one plain forward each, and skips the padded slots;
+    under a mesh it vmaps the candidate axis, which the mesh shards
+    (``launch.mesh``; on a 2-D ``("cand", "batch")`` mesh the layout is
+    chosen per call).  Masks stay jit *inputs* (no recompile across
+    chunks); ragged final chunks are padded to the chunk size so the jit
+    cache holds one entry per (chunk, shapes) signature.  :meth:`stage`
+    pads a chunk, starts its host→device transfer and dispatches its
+    program (jax dispatch is async), so the trial loop
+    (:func:`evaluate_prefetched`) materializes and stages later chunks while
+    the device still computes earlier ones.
 
 ``SuffixEvaluator``
     Prefix-reuse (split-forward) evaluation: candidates are local mask
     edits, so for a chunk whose candidates all first differ from the base
-    masks at/after one site, everything *before* that site is recomputed
-    identically per candidate by the backends above.  This backend computes
-    that shared prefix ONCE per (site, step) via the model's
-    ``forward_prefix`` (kept device-resident, batch-sharded on a 2-D mesh so
-    it never gathers) and vmaps only ``forward_suffix`` over the candidate
-    axis.  Site-aware: ``core.bcd._select_block`` feeds it site-grouped
-    chunks (:class:`SitedChunk`) in site-major order, and a cost model
-    (``analysis.roofline.SuffixCostModel``) falls shallow-cut chunks back to
-    the inner full-forward backend.
+    masks at/after one site, everything *before* that site is the same for
+    every candidate.  This evaluator computes that shared prefix ONCE per
+    (site, step) via the model's ``forward_prefix`` (kept device-resident,
+    batch-sharded on a 2-D mesh so it never gathers) and vmaps only
+    ``forward_suffix`` over the candidate axis.  Site-aware:
+    ``core.bcd._select_block`` feeds it site-grouped chunks
+    (:class:`SitedChunk`) in site-major order, and :class:`SuffixCostModel`
+    sends shallow-cut chunks down an inner :class:`PipelinedEvaluator`.
 
-Backends must rank candidates identically: ``run_bcd`` breaks ties by first
-occurrence, and all backends evaluate candidates in sampling order, so for a
-given seed/config every backend selects the same block (tested in
+Evaluators must rank candidates identically: ``run_bcd`` breaks ties by first
+occurrence, and all evaluators score candidates in sampling order, so for a
+given seed/config every one selects the same block (tested in
 ``tests/test_bcd_parallel.py``; the site-aware path reorders *evaluation*
 but replays selection in sampling order — ``tests/test_split_forward.py``).
 """
@@ -59,11 +45,10 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import dataclasses
 import functools
-import statistics
-import time
 from typing import (Any, Callable, Dict, Iterable, Iterator, NamedTuple,
-                    Optional, Protocol, Tuple, Union, runtime_checkable)
+                    Optional, Protocol, Tuple, runtime_checkable)
 
 import numpy as np
 import jax
@@ -90,9 +75,9 @@ class CandidateEvaluator(Protocol):
     """Evaluates a *stacked* candidate mask tree -> per-candidate accuracy."""
 
     name: str
-    # Chunk size the backend wants from run_bcd's trial loop; None defers to
-    # cfg.chunk_size.  Chunking never changes selection (rng burns RT draws
-    # per step regardless), so this is a pure performance hint.
+    # Chunk size the evaluator wants from run_bcd's trial loop; None defers
+    # to cfg.chunk_size.  Chunking never changes selection (rng burns RT
+    # draws per step regardless), so this is a pure performance hint.
     preferred_chunk: Optional[int]
     # How many chunks evaluate_prefetched may stage (transfer + dispatch)
     # ahead of the one being consumed.  0 = strict materialize -> evaluate.
@@ -109,62 +94,6 @@ class StagedChunk(NamedTuple):
     accs: jax.Array         # (n_padded,) device array, possibly not ready
 
 
-class PrefetchAutoTuner:
-    """Picks a prefetch depth from measured producer/consumer rates.
-
-    The pipeline overlaps the *producer* (host mask materialization + pad +
-    H2D transfer + dispatch) with the *consumer* (blocking on the device
-    result, i.e. the remaining compute).  :func:`evaluate_prefetched` runs
-    the first chunks of a run in strict alternation, timing both sides; the
-    first sample is discarded (it pays jit compile), and once ``n_probe``
-    clean samples exist the depth is fixed for the rest of the run:
-
-        depth = clamp(floor(consumer / producer), 1, max_depth)
-
-    — the number of chunks the producer can stage during one consumer
-    block.  Depth 1 already reaches steady-state overlap (per-chunk cost
-    max(p, c)); deeper staging only buys robustness to producer jitter when
-    the producer is much faster, and is capped because every staged chunk
-    is wasted work on an ADT early exit.
-    """
-
-    def __init__(self, n_probe: int = 2, max_depth: int = 4):
-        if n_probe < 1:
-            raise ValueError(f"n_probe must be >= 1, got {n_probe}")
-        if max_depth < 1:
-            raise ValueError(f"max_depth must be >= 1, got {max_depth}")
-        self.n_probe = n_probe
-        self.max_depth = max_depth
-        self._produce: list = []
-        self._consume: list = []
-        self._warmed = False      # first sample (compile) dropped
-        self.done = False
-
-    def add_sample(self, produce_s: float, consume_s: float) -> None:
-        if self.done:
-            return
-        if not self._warmed:
-            self._warmed = True
-            return
-        self._produce.append(produce_s)
-        self._consume.append(consume_s)
-        if len(self._produce) >= self.n_probe:
-            self.done = True
-
-    def depth(self) -> int:
-        p = max(statistics.median(self._produce), 1e-9)
-        c = statistics.median(self._consume)
-        return max(1, min(self.max_depth, int(c / p)))
-
-    def report(self) -> dict:
-        return {
-            "producer_s": statistics.median(self._produce),
-            "consumer_s": statistics.median(self._consume),
-            "prefetch": self.depth(),
-            "samples": len(self._produce),
-        }
-
-
 def evaluate_prefetched(evaluator, chunks: Iterable[M.MaskTree]
                         ) -> Iterator[np.ndarray]:
     """Producer/consumer driver for the trial loop.
@@ -174,40 +103,16 @@ def evaluate_prefetched(evaluator, chunks: Iterable[M.MaskTree]
     :class:`PipelinedEvaluator`), up to ``prefetch_depth`` chunks beyond the
     one being consumed are kept staged: their host materialization, device
     transfer, and compute dispatch all happen while earlier chunks still
-    compute.  Backends without staging — or with ``prefetch_depth == 0`` —
-    degrade to the strict materialize → evaluate alternation.
+    compute.  Evaluators without staging — or with ``prefetch_depth == 0``
+    — degrade to the strict materialize → evaluate alternation.
 
     The consumer may stop early (ADT exit): closing the generator drops any
     staged-but-unread chunks, and because ``chunks`` is itself pulled lazily,
     chunks beyond the staging horizon are never even materialized.  Chunk k's
     result is always yielded before chunk k+depth+1 is staged, so an early
     exit at chunk k commits at most ``depth`` chunks of wasted work.
-
-    When the evaluator carries a live :class:`PrefetchAutoTuner`
-    (``prefetch="auto"``), the first chunks run in strict alternation while
-    the tuner times the producer vs the consumer; once it converges the
-    evaluator's ``prefetch_depth`` is fixed for the rest of the run and the
-    loop switches to staged prefetching mid-stream.  The probe phase changes
-    timing only — chunk results and their order are identical.
     """
     it = iter(chunks)
-    tuner = getattr(evaluator, "auto_tuner", None)
-    if tuner is not None and not tuner.done and hasattr(evaluator, "stage"):
-        while not tuner.done:
-            t0 = time.perf_counter()
-            try:
-                chunk = next(it)
-            except StopIteration:
-                return
-            staged_one = evaluator.stage(chunk)
-            t1 = time.perf_counter()
-            accs = evaluator.evaluate_staged(staged_one)
-            t2 = time.perf_counter()
-            tuner.add_sample(t1 - t0, t2 - t1)
-            if tuner.done:
-                evaluator.prefetch_depth = tuner.depth()
-                evaluator.auto_report = tuner.report()
-            yield accs
     depth = int(getattr(evaluator, "prefetch_depth", 0) or 0)
     if depth <= 0 or not hasattr(evaluator, "stage"):
         for chunk in it:
@@ -294,20 +199,40 @@ class SequentialEvaluator:
                          for i in range(n)], dtype=np.float64)
 
 
-class BatchedEvaluator:
-    """One jitted call per chunk of candidates: a loop over the chunk's real
-    candidates on one device (:func:`_looped`), a vmap over the chunk under
-    a mesh (:class:`ShardedEvaluator`), where the candidate axis is what
-    shards across devices.  Both count ``engine.fallback_forwards`` (the
-    forwards the program computes) and ``engine.fallback_slots`` (the slots
-    dispatched, padding included)."""
+class PipelinedEvaluator:
+    """The full-forward evaluator: one jitted call per chunk of candidates,
+    staged ahead of the chunk being read.
 
-    name = "batched"
+    The chunk program follows the placement it is given.  Without a mesh it
+    loops over the chunk's real candidates (:func:`_looped`).  Under a mesh
+    it vmaps the chunk, since the candidate axis is what shards across
+    devices:
+
+    - 1-D mesh (``make_candidate_mesh``): every axis shards candidates;
+      counts pad up to the device count.
+    - 2-D ``("cand", "batch")`` mesh (``make_cand_batch_mesh``): the layout
+      is chosen *per call* by padded per-device work
+      (:meth:`_chunk_sharding`) — chunks big enough to give every device a
+      candidate shard jointly over both axes; smaller chunks shard over
+      ``"cand"`` only, and a ``context_specs``-sharded eval batch splits
+      each candidate's forward across ``"batch"``.
+
+    ``prefetch_depth`` chunks beyond the one being consumed stay staged:
+    padded, transferred, and *dispatched*, so jax's async dispatch overlaps
+    chunk k+1's host materialization and H2D transfer with chunk k's device
+    compute.  Selection is unchanged: chunks are consumed in sampling order
+    and the ADT early exit checks chunk k's results before chunk
+    k+1+prefetch_depth is committed.  Every chunk counts
+    ``engine.fallback_forwards`` (the forwards the program computes) and
+    ``engine.fallback_slots`` (the slots dispatched, padding included).
+    """
+
+    name = "pipelined"
     preferred_chunk = None
-    prefetch_depth = 0
+    prefetch_depth = 2
 
     def __init__(self, eval_fn: EvalFn, *, pad_to: Optional[int] = None,
-                 context=None):
+                 context=None, mesh=None, context_specs=None):
         """eval_fn: traceable single-tree accuracy (device arrays in/out).
         pad_to: pad ragged candidate axes up to this size (use the BCD
         chunk_size) so jit sees one leading-dim signature.
@@ -315,36 +240,77 @@ class BatchedEvaluator:
         second argument and mapped over with in_axes=None.  It is a jit
         *input*, not a closure constant — callers that finetune params
         between outer steps update it via :meth:`set_context` and the
-        compiled executable picks up the new values without retracing."""
+        compiled executable picks up the new values without retracing.
+        context_specs: a ``PartitionSpec`` tree for the context
+        (:func:`context_batch_specs`); needs a mesh."""
+        if context_specs is not None and mesh is None:
+            raise ValueError("context_specs requires a mesh")
+        if context_specs is not None and context is None:
+            raise ValueError("context_specs given without a context")
         self._has_ctx = context is not None
-        self._mesh = None
-        # commit the context to device once: leaving numpy leaves in the
-        # tree makes every dispatch re-transfer them (and re-hash the host
-        # arrays), which is pure per-chunk overhead on the hot path
-        self.context = None if context is None else jax.device_put(context)
+        self._pad_to = pad_to
+        self._mesh = mesh
+        self._ctx_shardings = None
+        if mesh is None:
+            program = _looped(eval_fn)
+        else:
+            from jax.sharding import NamedSharding, PartitionSpec as P
+            axes = tuple(mesh.axis_names)
+            self._n_dev = int(np.prod([mesh.shape[a] for a in axes]))
+            cand_axes = tuple(a for a in axes if a != "batch") or axes
+            self._cand = int(np.prod([mesh.shape[a] for a in cand_axes]))
+            self._joint_sharding = NamedSharding(mesh, P(axes))
+            self._cand_sharding = NamedSharding(mesh, P(cand_axes))
+            if context_specs is not None:
+                self._ctx_shardings = jax.tree.map(
+                    lambda s: NamedSharding(mesh, s), context_specs,
+                    is_leaf=lambda x: isinstance(x, P))
+            program = jax.vmap(_with_stacked_route(eval_fn),
+                               in_axes=(0, None) if self._has_ctx else 0)
         # the mask stack (arg 0) is donated: each staged chunk's stack is a
         # fresh buffer (_device_batch copies) read by exactly one dispatch,
         # so XLA may reuse it in place of a second live copy
-        self._looped = jax.jit(_looped(eval_fn),
-                               donate_argnums=_donate_mask_arg())
-        self._vmapped = jax.jit(
-            jax.vmap(_with_stacked_route(eval_fn),
-                     in_axes=(0, None) if self._has_ctx else 0),
-            donate_argnums=_donate_mask_arg())
-        self._pad_to = pad_to
+        self._program = jax.jit(program, donate_argnums=_donate_mask_arg())
+        # commit the context to device once: leaving numpy leaves in the
+        # tree makes every dispatch re-transfer them (and re-hash the host
+        # arrays), which is pure per-chunk overhead on the hot path
+        self.context = None
+        if self._has_ctx:
+            self.set_context(context)
 
     def set_context(self, context) -> None:
         """Swap the auxiliary context (same treedef/shapes: no recompile)."""
         if not self._has_ctx:
             raise ValueError("evaluator was built without a context")
-        self.context = jax.device_put(context)
+        self.context = jax.device_put(context, self._ctx_shardings)
+
+    def _chunk_sharding(self, n: int):
+        """Per-call layout under a mesh: (padded candidate count,
+        NamedSharding).
+
+        Minimize padded per-device work: the joint layout costs
+        ceil(n / n_dev) candidate-forwards per device; the cand-only layout
+        costs ceil(n / cand) forwards over 1/batch of the eval batch each.
+        Ties prefer joint (no cross-device reduction inside a forward)."""
+        batch_ax = self._n_dev // self._cand
+        joint_cost = -(-n // self._n_dev)
+        split_cost = -(-n // self._cand) / batch_ax
+        if joint_cost <= split_cost:
+            return n + (-n % self._n_dev), self._joint_sharding
+        return n + (-n % self._cand), self._cand_sharding
 
     def _device_batch(self, stacked: M.MaskTree):
-        # copy=True: the stack is donated into the chunk program, so leaves
-        # must be buffers this evaluator owns — jnp.asarray would alias a
-        # caller's already-on-device float32 array and donation would
-        # delete it out from under them
-        return {k: jnp.array(v, dtype=jnp.float32, copy=True)
+        # np.asarray first: a caller's device array comes to the host and
+        # device_put copies it, so the donated stack is always a buffer this
+        # evaluator owns.  device_put (not jnp.asarray) so the transfer is an
+        # async dispatch the pipeline can run ahead of.
+        sharding = None
+        if self._mesh is not None:
+            n = M.stacked_len(stacked)
+            n_pad, sharding = self._chunk_sharding(n)
+            if n_pad > n:
+                stacked = M.pad_stacked(stacked, n_pad)
+        return {k: jax.device_put(np.asarray(v, dtype=np.float32), sharding)
                 for k, v in stacked.items()}
 
     # -------------------------------------------------------------- staging
@@ -363,11 +329,11 @@ class BatchedEvaluator:
         ctx = (self.context,) if self._has_ctx else ()
         if self._mesh is None:
             forwards = n
-            accs = self._looped(batch, jax.device_put(np.int32(n)), *ctx)
+            accs = self._program(batch, jax.device_put(np.int32(n)), *ctx)
         else:
             forwards = slots            # the vmap computes every slot
             with _mesh_scope(self._mesh):
-                accs = self._vmapped(batch, *ctx)
+                accs = self._program(batch, *ctx)
         tracing.count("engine.fallback_forwards", forwards)
         tracing.count("engine.fallback_slots", slots)
         return StagedChunk(n, accs)
@@ -382,10 +348,9 @@ class BatchedEvaluator:
 
 
 def effective_chunk(evaluator, chunk_size: int) -> int:
-    """The chunk size the trial loop actually uses: backends may cap it via
-    ``preferred_chunk`` (SequentialEvaluator wants 1 so the ADT exit never
-    pays for unevaluated chunk-mates).  Shared by ``bcd._select_block`` and
-    the throughput benchmark so both drive the same loop."""
+    """The chunk size the trial loop actually uses: evaluators may cap it
+    via ``preferred_chunk`` (SequentialEvaluator wants 1 so the ADT exit
+    never pays for unevaluated chunk-mates)."""
     return min(chunk_size,
                getattr(evaluator, "preferred_chunk", None) or chunk_size)
 
@@ -396,135 +361,50 @@ def context_batch_specs(context: dict, *, batch_key: str = "batch",
     ``context[batch_key]`` shard their leading axis over mesh axis ``axis``
     (the axis size must divide their leading dim, e.g. batch 16 over a
     2-device axis); every other leaf replicates.  Feed the result to
-    ShardedEvaluator(context_specs=...)."""
+    ``PipelinedEvaluator(context_specs=...)`` or ``SuffixEvaluator``."""
     from jax.sharding import PartitionSpec as P
     return {k: jax.tree.map(lambda _: P(axis) if k == batch_key else P(), v)
             for k, v in context.items()}
 
 
-class ShardedEvaluator(BatchedEvaluator):
-    """Batched backend with the candidate axis sharded across a mesh.
-
-    1-D mesh (``make_candidate_mesh``): every axis contributes to the
-    candidate sharding (pure candidate-parallel); counts pad up to the device
-    count.  2-D ``("cand", "batch")`` mesh (``make_cand_batch_mesh``): the
-    spec is chosen *per call* by padded per-device work — chunks big enough
-    to give every device a candidate shard jointly over both axes; smaller
-    chunks shard over ``"cand"`` only, and a ``context_specs``-sharded eval
-    batch splits each candidate's forward across ``"batch"``.
-    """
-
-    name = "sharded"
-
-    def __init__(self, eval_fn: EvalFn, mesh, *, pad_to: Optional[int] = None,
-                 context=None, context_specs=None):
-        super().__init__(eval_fn, pad_to=pad_to, context=context)
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        self._mesh = mesh
-        axes = tuple(mesh.axis_names)
-        self._n_dev = int(np.prod([mesh.shape[a] for a in axes]))
-        cand_axes = tuple(a for a in axes if a != "batch") or axes
-        self._cand = int(np.prod([mesh.shape[a] for a in cand_axes]))
-        self._joint_sharding = NamedSharding(mesh, P(axes))
-        self._cand_sharding = NamedSharding(mesh, P(cand_axes))
-        self._ctx_shardings = None
-        if context_specs is not None:
-            if context is None:
-                raise ValueError("context_specs given without a context")
-            self._ctx_shardings = jax.tree.map(
-                lambda s: NamedSharding(mesh, s), context_specs,
-                is_leaf=lambda x: isinstance(x, P))
-            self.context = jax.device_put(context, self._ctx_shardings)
-
-    def set_context(self, context) -> None:
-        if self._ctx_shardings is not None:
-            context = jax.device_put(context, self._ctx_shardings)
-        super().set_context(context)
-
-    def _chunk_sharding(self, n: int):
-        """Per-call layout: (padded candidate count, NamedSharding).
-
-        Minimize padded per-device work: the joint layout costs
-        ceil(n / n_dev) candidate-forwards per device; the cand-only layout
-        costs ceil(n / cand) forwards over 1/batch of the eval batch each.
-        Ties prefer joint (no cross-device reduction inside a forward)."""
-        batch_ax = self._n_dev // self._cand
-        joint_cost = -(-n // self._n_dev)
-        split_cost = -(-n // self._cand) / batch_ax
-        if joint_cost <= split_cost:
-            return n + (-n % self._n_dev), self._joint_sharding
-        return n + (-n % self._cand), self._cand_sharding
-
-    def _device_batch(self, stacked: M.MaskTree):
-        n = M.stacked_len(stacked)
-        n_pad, sharding = self._chunk_sharding(n)
-        if n_pad > n:
-            stacked = M.pad_stacked(stacked, n_pad)
-        return {k: jax.device_put(np.asarray(v, dtype=np.float32), sharding)
-                for k, v in stacked.items()}
-
-
-class PipelinedEvaluator(ShardedEvaluator):
-    """Double-buffered candidate staging (batched or sharded placement).
-
-    ``prefetch`` chunks beyond the one being consumed stay staged: padded,
-    transferred, and *dispatched*.  jax's async dispatch then overlaps chunk
-    k+1's host materialization + H2D transfer with chunk k's device compute,
-    which is the wall-clock the chunk-serial BatchedEvaluator leaves on the
-    table.  ``mesh=None`` keeps single-device placement; passing a mesh
-    layers the prefetch pipeline over ShardedEvaluator's joint
-    candidate×batch layout.  Selection is unchanged versus every other
-    backend: chunks are consumed in sampling order and the ADT early exit
-    checks chunk k's results before chunk k+1+prefetch is committed.
-
-    ``prefetch="auto"`` defers the depth to a :class:`PrefetchAutoTuner`:
-    the run's first chunks execute in strict alternation while producer and
-    consumer rates are measured, then ``prefetch_depth`` locks in for the
-    rest of the run (``auto_report`` records the measurements) — the
-    ROADMAP's "pick prefetch from measured rates instead of a flag".
-    """
-
-    name = "pipelined"
-
-    def __init__(self, eval_fn: EvalFn, *, pad_to: Optional[int] = None,
-                 context=None, prefetch: Union[int, str] = 1, mesh=None,
-                 context_specs=None, auto_probe_chunks: int = 2,
-                 auto_max_prefetch: int = 4):
-        if prefetch == "auto":
-            self.auto_tuner = PrefetchAutoTuner(
-                n_probe=auto_probe_chunks, max_depth=auto_max_prefetch)
-            prefetch = 0          # strict alternation until the probe locks
-        elif isinstance(prefetch, str):
-            raise ValueError(
-                f"prefetch must be an int >= 0 or 'auto', got {prefetch!r}")
-        elif prefetch < 0:
-            raise ValueError(f"prefetch must be >= 0, got {prefetch}")
-        else:
-            self.auto_tuner = None
-        self.auto_report: Optional[dict] = None
-        if mesh is None:
-            if context_specs is not None:
-                raise ValueError("context_specs requires a mesh")
-            BatchedEvaluator.__init__(self, eval_fn, pad_to=pad_to,
-                                      context=context)
-            self._mesh = None
-            self._ctx_shardings = None
-        else:
-            ShardedEvaluator.__init__(self, eval_fn, mesh, pad_to=pad_to,
-                                      context=context,
-                                      context_specs=context_specs)
-        self.prefetch_depth = int(prefetch)
-
-    def _device_batch(self, stacked: M.MaskTree):
-        if self._mesh is None:
-            # device_put (not jnp.asarray) so the transfer is an async
-            # dispatch the pipeline can run ahead of.
-            return {k: jax.device_put(np.asarray(v, dtype=np.float32))
-                    for k, v in stacked.items()}
-        return ShardedEvaluator._device_batch(self, stacked)
-
-
 # ----------------------------------------------------- prefix-reuse backend
+
+
+@dataclasses.dataclass(frozen=True)
+class SuffixCostModel:
+    """Per-chunk decision: suffix mode (prefix once + vmapped suffix) vs the
+    full-forward :class:`PipelinedEvaluator`, for a chunk of ``n``
+    candidates cutting at a site with ``prefix_fraction`` f of forward
+    FLOPs above it.
+
+    Per-chunk cost ratio:  suffix / full = ((f - c) + (1 - f)·n) / n, where
+    ``c`` is the prefix fraction already resident in the evaluator's trie
+    (``covered``) — always <1 for n > 1 even cold, so the analytic *model*
+    says "always suffix"; the thresholds price what it can't see: a shallow
+    cut's win (f·(n-1) forwards) is smaller than its fixed overheads (one
+    extra jit per segment, the cached-acts residency, per-chunk plan/slice
+    work), so those sites fall back to the full forward (``use_suffix() ==
+    False``).
+    """
+
+    min_prefix_fraction: float = 0.05   # below this the reuse is noise
+    min_chunk: int = 2                  # n=1 reuses nothing
+
+    def speedup(self, prefix_fraction: float, n: int,
+                covered: float = 0.0) -> float:
+        """Predicted candidates/sec gain of suffix mode for one chunk;
+        ``covered`` discounts prefix work already cached in the trie."""
+        f = min(max(prefix_fraction, 0.0), 1.0)
+        c = min(max(covered, 0.0), f)
+        return n / max((f - c) + (1.0 - f) * n, 1e-9)
+
+    def use_suffix(self, prefix_fraction: float, n: int,
+                   covered: float = 0.0) -> bool:
+        """The thresholds decide; ``covered`` (what the trie already holds,
+        ``SuffixEvaluator.covered_fraction``) can only lower the cost, so a
+        cut that clears them cold clears them warm."""
+        return n >= self.min_chunk and \
+            prefix_fraction >= self.min_prefix_fraction
 
 
 class SplitEval(NamedTuple):
@@ -721,14 +601,11 @@ class SuffixEvaluator:
     deep-site chunks also transfer a fraction of the mask bytes.
 
     Plain (un-sited) chunks and cost-model fallbacks delegate to an inner
-    :class:`PipelinedEvaluator` sharing the same context/placement, so this
-    backend composes batched / sharded / pipelined behavior: ``prefetch``
-    staging works identically for sited chunks (stage = slice + pad +
-    transfer + dispatch suffix), and ``prefetch="auto"`` hands the depth to
-    the inner pipeline's :class:`PrefetchAutoTuner` (measured producer vs
-    consumer rates — locks 0 where overlap can't help, >0 where it does).
-    The fallback pipeline is built once and kept warm: consecutive fallback
-    chunks reuse its jit executable and its device-committed context — no
+    :class:`PipelinedEvaluator` sharing the same context/placement; sited
+    chunks stage the same way (stage = slice + pad + transfer + dispatch
+    suffix), at the inner evaluator's ``prefetch_depth``.  The fallback
+    evaluator is built once and kept warm: consecutive fallback chunks
+    reuse its jit executable and its device-committed context — no
     per-chunk re-staging cost.  When the model provides ``SplitEval.pre``
     (the mask-independent head fold — stem conv+bn / embed), it is computed
     once per context and shipped as ``ctx["pre"]``, so even fallback
@@ -744,10 +621,11 @@ class SuffixEvaluator:
     name = "suffix"
     site_aware = True
     preferred_chunk = None
+    prefetch_depth = PipelinedEvaluator.prefetch_depth
 
     def __init__(self, split: SplitEval, *, pad_to: Optional[int] = None,
                  context=None, mesh=None, context_specs=None,
-                 prefetch: int = 0, cost_model=None,
+                 cost_model: Optional[SuffixCostModel] = None,
                  trie_budget_bytes: Optional[int] = None,
                  fused_kernels: bool = True):
         if not isinstance(context, dict) or "params" not in context \
@@ -756,22 +634,15 @@ class SuffixEvaluator:
                 "SuffixEvaluator needs context={'params': …, 'batch': …} — "
                 "prefix and suffix consume the eval batch and params as jit "
                 "inputs (models' make_suffix_eval_fns contract)")
-        if cost_model is None:
-            from repro.analysis.roofline import SuffixCostModel
-            cost_model = SuffixCostModel()
         self._split = split
-        self.cost_model = cost_model
+        self.cost_model = cost_model or SuffixCostModel()
         self.fused_kernels = bool(fused_kernels)
         self._pad_to = pad_to
         self._mesh = mesh
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
-            axes = tuple(mesh.axis_names)
-            cand_axes = tuple(a for a in axes if a != "batch") or axes
-            self._cand = int(np.prod([mesh.shape[a] for a in cand_axes]))
-            self._cand_sharding = NamedSharding(mesh, P(cand_axes))
             self._cache_sharding = NamedSharding(
-                mesh, P("batch") if "batch" in axes else P())
+                mesh, P("batch") if "batch" in mesh.axis_names else P())
         # mask-independent head fold (SplitEval.pre): computed once per
         # context and shipped INSIDE the inner context, so every fallback
         # full-forward resumes from it instead of re-tracing the stem/embed
@@ -784,13 +655,9 @@ class SuffixEvaluator:
             context_specs = {**context_specs,
                              "pre": jax.tree.map(lambda _: spec,
                                                  context["pre"])}
-        # prefetch passes straight through (including "auto": the inner
-        # pipeline owns the PrefetchAutoTuner; this evaluator mirrors its
-        # prefetch_depth/auto_report so evaluate_prefetched's probe loop
-        # drives the tuner through the suffix staging protocol)
         self._inner = PipelinedEvaluator(
-            split.full, pad_to=pad_to, context=context,
-            prefetch=prefetch, mesh=mesh, context_specs=context_specs)
+            split.full, pad_to=pad_to, context=context, mesh=mesh,
+            context_specs=context_specs)
         # one representative site per segment: sites cutting at the same
         # segment share the prefix cache entry and the prefix/suffix jits
         self._segment_site: Dict[int, str] = {}
@@ -815,29 +682,6 @@ class SuffixEvaluator:
         if self._mesh is not None:
             pre = jax.device_put(pre, self._cache_sharding)
         return {**context, "pre": pre}
-
-    # the inner pipeline owns the staging depth and (for prefetch="auto")
-    # the tuner; mirroring them as properties lets evaluate_prefetched
-    # treat this evaluator exactly like a PipelinedEvaluator
-    @property
-    def prefetch_depth(self) -> int:
-        return self._inner.prefetch_depth
-
-    @prefetch_depth.setter
-    def prefetch_depth(self, depth) -> None:
-        self._inner.prefetch_depth = int(depth)
-
-    @property
-    def auto_tuner(self):
-        return self._inner.auto_tuner
-
-    @property
-    def auto_report(self):
-        return self._inner.auto_report
-
-    @auto_report.setter
-    def auto_report(self, report) -> None:
-        self._inner.auto_report = report
 
     # context lives on the inner evaluator (single source of truth; it owns
     # the device placement / context_specs resharding)
@@ -983,14 +827,15 @@ class SuffixEvaluator:
             # ship only the masks the suffix consumes (sites at/after the cut)
             sub = {k: stacked[k] for k in self._split.suffix_sites(site)}
             n_pad = max(n, self._pad_to or 0)
+            sharding = None
             if self._mesh is not None:
-                n_pad += -n_pad % self._cand
+                # candidates shard over "cand" as the fallback's do
+                n_pad += -n_pad % self._inner._cand
+                sharding = self._inner._cand_sharding
             if n_pad > n:
                 sub = M.pad_stacked(sub, n_pad)
-            put = (jax.device_put if self._mesh is None else
-                   functools.partial(jax.device_put,
-                                     device=self._cand_sharding))
-            batch = {k: put(np.asarray(v, dtype=np.float32))
+            batch = {k: jax.device_put(np.asarray(v, dtype=np.float32),
+                                       sharding)
                      for k, v in sub.items()}
             with _mesh_scope(self._mesh):
                 cached = self._prefix_for(site)
@@ -1097,73 +942,3 @@ def materialize_sited(flat: np.ndarray, layout: list, indices,
         else:
             stacked = M.materialize_from_flat(flat, layout, indices[sel])
         yield SitedChunk(site, stacked)
-
-
-def make_evaluator(
-    backend: str,
-    *,
-    eval_acc: Optional[Callable[[M.MaskTree], float]] = None,
-    eval_fn: Optional[EvalFn] = None,
-    mesh=None,
-    pad_to: Optional[int] = None,
-    context=None,
-    context_specs=None,
-    prefetch: Union[int, str] = 1,
-    split: Optional[SplitEval] = None,
-    cost_model=None,
-    trie_budget_bytes: Optional[int] = None,
-    fused_kernels: bool = True,
-) -> CandidateEvaluator:
-    """Factory: ``backend`` in {'sequential','batched','sharded',
-    'pipelined','suffix'}.
-
-    sequential needs ``eval_acc`` (host callable); batched/sharded/pipelined
-    need ``eval_fn`` (traceable); suffix needs ``split`` (the model's
-    ``make_suffix_eval_fns()`` bundle) plus a ``context`` carrying params
-    AND the eval batch.  sharded defaults to a mesh over all local devices
-    when ``mesh`` is None; pipelined/suffix keep single-device placement
-    unless a mesh is passed.  ``context_specs`` (see
-    :func:`context_batch_specs`) shards the context over the mesh — the
-    joint candidate×batch layout.  ``prefetch`` is a depth or ``"auto"``
-    (measured-rate tuning; pipelined and suffix).  ``cost_model`` overrides
-    the
-    suffix backend's per-site fallback policy; ``trie_budget_bytes`` bounds
-    its prefix-trie residency and ``fused_kernels`` gates the fused TPU
-    suffix megakernels (both suffix-only).
-    """
-    if backend not in ("pipelined", "suffix") and prefetch == "auto":
-        raise ValueError(
-            f"prefetch='auto' requires a staging pipeline (pipelined or "
-            f"suffix backend); the {backend!r} backend has none to tune "
-            "(integer prefetch values are ignored as a no-op hint)")
-    if backend == "sequential":
-        if eval_acc is None:
-            raise ValueError("sequential backend needs eval_acc")
-        return SequentialEvaluator(eval_acc)
-    if backend == "suffix":
-        if split is None:
-            raise ValueError("suffix backend needs split= — the model's "
-                             "make_suffix_eval_fns() bundle")
-        return SuffixEvaluator(split, pad_to=pad_to, context=context,
-                               mesh=mesh, context_specs=context_specs,
-                               prefetch=prefetch, cost_model=cost_model,
-                               trie_budget_bytes=trie_budget_bytes,
-                               fused_kernels=fused_kernels)
-    if backend in ("batched", "sharded", "pipelined"):
-        if eval_fn is None:
-            raise ValueError(f"{backend} backend needs a traceable eval_fn")
-    if backend == "batched":
-        return BatchedEvaluator(eval_fn, pad_to=pad_to, context=context)
-    if backend == "sharded":
-        if mesh is None:
-            from repro.launch import mesh as mesh_lib
-            mesh = mesh_lib.make_candidate_mesh()
-        return ShardedEvaluator(eval_fn, mesh, pad_to=pad_to,
-                                context=context, context_specs=context_specs)
-    if backend == "pipelined":
-        return PipelinedEvaluator(eval_fn, pad_to=pad_to, context=context,
-                                  prefetch=prefetch, mesh=mesh,
-                                  context_specs=context_specs)
-    raise ValueError(f"unknown evaluator backend {backend!r}; expected "
-                     "'sequential' | 'batched' | 'sharded' | 'pipelined' | "
-                     "'suffix'")

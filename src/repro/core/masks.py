@@ -112,9 +112,9 @@ def sample_removal_block(
 # ------------------------------------------------------------ stacked trees
 #
 # A *stacked* mask tree carries ``n`` candidate trees along a leading axis:
-# ``{site: (n, *site_shape)}``.  The batched/sharded evaluators (core.engine)
-# consume stacked trees whole — one jitted vmap call evaluates all n
-# candidates — so every helper here must index/slice consistently across
+# ``{site: (n, *site_shape)}``.  The evaluators of core.engine consume
+# stacked trees whole — one jitted call evaluates all n candidates — so
+# every helper here must index/slice consistently across
 # sites.  Sampling is split into *index* sampling (tiny: (n, drc) ints) and
 # *materialization* (per-chunk, so RT full-size candidate trees never live in
 # host memory at once).

@@ -10,8 +10,8 @@ same one:
 - otherwise ``<checkout>/.jax_cache`` (listed in ``.gitignore``).
 
 :func:`enable` applies that rule; ``chip_smoke.py``,
-``examples/resnet18_bcd_pipeline.py``, ``examples/family_bcd_sweep.py`` and
-``benchmarks/bench_bcd_eval.py`` call it before their first jit.  The
+``examples/resnet18_bcd_pipeline.py`` and ``examples/family_bcd_sweep.py``
+call it before their first jit.  The
 runners count cache hits and misses with ``core.tracing.recording()`` and
 print :func:`log_line` of its counts, so a restart shows how much it
 reused.  Cache keys include jax/XLA versions and compile options, so a

@@ -37,8 +37,8 @@ def make_candidate_mesh(n_devices: int | None = None):
     """1-D mesh over local devices for BCD candidate-parallel evaluation.
 
     The candidate axis of a stacked mask tree shards over ``"cand"``
-    (core.engine.ShardedEvaluator); params/data replicate.  Works on any
-    device count including 1 (degenerates to the batched evaluator).
+    (a meshed core.engine.PipelinedEvaluator); params/data replicate.
+    Works on any device count including 1.
     """
     import numpy as np
     from jax.sharding import Mesh
@@ -53,7 +53,7 @@ def make_cand_batch_mesh(cand: int | None = None, batch: int | None = None):
 
     A pure candidate layout idles ``n_devices - RT`` devices whenever a trial
     chunk has fewer candidates than the mesh has devices; this mesh lets
-    ``core.engine.ShardedEvaluator`` shard small chunks over ``"cand"`` while
+    ``core.engine.PipelinedEvaluator`` shard small chunks over ``"cand"`` while
     a batch-sharded evaluator context splits each candidate's forward over
     ``"batch"`` (big chunks still shard jointly over both axes — the spec is
     chosen per call).  Give either factor; the other defaults to using every

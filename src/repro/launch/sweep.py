@@ -57,46 +57,42 @@ from repro.core import runner as runner_lib
 
 
 def make_bcd_evaluator(engine_name: str, model, eval_b, holder, *,
-                       chunk_size: int, rt: int, prefetch=2,
+                       chunk_size: int, rt: int,
                        fused_kernels: bool = True):
     """Build the BCD candidate engine for any model family.
 
-    Model-agnostic: works for every model exposing the shared eval-closure
-    contract (``make_param_eval_fn`` / ``make_suffix_eval_fns`` — CNNs and
-    every ``models.lm`` family, including scanned-stack SSM/RWKV and MoE
-    configs whose suffix engine cuts mid-scan via carry checkpoints).
-    Params are evaluator *context* (a jit input) because finetuning
-    rewrites them between outer steps; ``holder`` is the live
-    ``{"params": ...}`` box the caller mutates.
+    ``engine_name`` is ``"suffix"`` (the engine) or ``"sequential"`` (the
+    reference it is tested against).  Model-agnostic: works for every model
+    exposing the shared eval-closure contract (``make_param_eval_fn`` /
+    ``make_suffix_eval_fns`` — CNNs and every ``models.lm`` family,
+    including scanned-stack SSM/RWKV and MoE configs whose suffix engine
+    cuts mid-scan via carry checkpoints).  Params are evaluator *context*
+    (a jit input) because finetuning rewrites them between outer steps;
+    ``holder`` is the live ``{"params": ...}`` box the caller mutates.
 
     Returns ``(evaluator, eval_acc, set_ctx)``: call ``set_ctx(params)``
-    after every finetune — engines differ in context shape (the suffix
-    engine carries the eval batch alongside params), so callers never
-    touch ``set_context`` directly.  ``fused_kernels=False`` keeps the
-    activation gate un-fused on the suffix backend (required when the move
-    set can produce share ties — see ``linearize._apply_share_ties``).
+    after every finetune (the suffix engine carries the eval batch
+    alongside params, so callers never touch ``set_context`` directly).
+    ``fused_kernels=False`` keeps the activation gate un-fused on the
+    suffix engine (required when the move set can produce share ties — see
+    ``linearize._apply_share_ties``).
     """
     eval_fn_p = model.make_param_eval_fn(eval_b)
     acc_jit = jax.jit(eval_fn_p)
     eval_acc = lambda m: float(acc_jit(M.as_device(m), holder["params"]))
     if engine_name == "sequential":
-        return engine.make_evaluator("sequential", eval_acc=eval_acc), \
-            eval_acc, lambda p: None
-    # don't let ragged-chunk padding exceed RT (sharded may still
-    # round up to the device count; extras are sliced off)
-    pad = min(chunk_size, rt)
-    if engine_name == "suffix":
-        batch_np = {k: np.asarray(v) for k, v in eval_b.items()}
-        evaluator = engine.make_evaluator(
-            "suffix", split=model.make_suffix_eval_fns(),
-            context={"params": holder["params"], "batch": batch_np},
-            pad_to=pad, prefetch=prefetch, fused_kernels=fused_kernels)
-        return evaluator, eval_acc, lambda p: evaluator.set_context(
-            {"params": p, "batch": batch_np})
-    evaluator = engine.make_evaluator(
-        engine_name, eval_fn=eval_fn_p, pad_to=pad,
-        context=holder["params"], prefetch=prefetch)
-    return evaluator, eval_acc, evaluator.set_context
+        return engine.SequentialEvaluator(eval_acc), eval_acc, lambda p: None
+    if engine_name != "suffix":
+        raise ValueError(f"unknown engine {engine_name!r}; expected "
+                         "'sequential' or 'suffix'")
+    batch_np = {k: np.asarray(v) for k, v in eval_b.items()}
+    # don't let ragged-chunk padding exceed RT
+    evaluator = engine.SuffixEvaluator(
+        model.make_suffix_eval_fns(),
+        context={"params": holder["params"], "batch": batch_np},
+        pad_to=min(chunk_size, rt), fused_kernels=fused_kernels)
+    return evaluator, eval_acc, lambda p: evaluator.set_context(
+        {"params": p, "batch": batch_np})
 
 
 @dataclasses.dataclass
@@ -156,16 +152,6 @@ def _atomic_write_json(path: str, obj) -> None:
     os.replace(tmp, path)
 
 
-def update_notes(cfg: SweepConfig, extra: dict) -> None:
-    """Atomically merge keys into the artifact's ``notes`` (e.g. the
-    auto-prefetch report, known only after the run)."""
-    path = artifact_path(cfg)
-    with open(path) as f:
-        payload = json.load(f)
-    payload.setdefault("notes", {}).update(extra)
-    _atomic_write_json(path, payload)
-
-
 def _log_jsonable(h: bcd_lib.BCDStepLog) -> dict:
     """A step log for the curve artifact, with ``wall_s`` split out: the
     remaining fields are the run's deterministic identity (what the
@@ -175,22 +161,6 @@ def _log_jsonable(h: bcd_lib.BCDStepLog) -> dict:
     return d
 
 
-def _merged_notes(cfg: SweepConfig, notes: Optional[dict]) -> dict:
-    """Caller notes merged over any already in the on-disk artifact — keys
-    added out-of-band (update_notes, e.g. the auto-prefetch report) must
-    survive rewrites and appear in every rank's returned payload."""
-    merged = {}
-    path = artifact_path(cfg)
-    if os.path.exists(path):
-        try:
-            with open(path) as f:
-                merged = json.load(f).get("notes", {}) or {}
-        except (json.JSONDecodeError, OSError):
-            merged = {}
-    merged.update(notes or {})
-    return merged
-
-
 def _payload(cfg: SweepConfig, stages: List[dict], complete: bool,
              notes: Optional[dict]) -> dict:
     return {
@@ -198,7 +168,7 @@ def _payload(cfg: SweepConfig, stages: List[dict], complete: bool,
         "schedule": list(cfg.budgets),
         "complete": complete,
         "stages": stages,
-        "notes": _merged_notes(cfg, notes),
+        "notes": dict(notes or {}),
     }
 
 

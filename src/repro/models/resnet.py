@@ -338,7 +338,7 @@ class CNN:
         """site -> fraction of full-forward FLOPs strictly before its
         segment.  0.0 for first-segment sites (suffix mode buys nothing),
         approaching 1.0 for the deepest sites — the suffix cost model
-        (analysis.roofline.SuffixCostModel) thresholds on this."""
+        (core.engine.SuffixCostModel) thresholds on this."""
         seg_flops = self._segment_flops()
         total = max(sum(seg_flops), 1.0)
         cum = 0.0
@@ -352,8 +352,8 @@ class CNN:
     #
     # BCD's candidate-evaluation engine (core.engine) needs two views of
     # "accuracy under a mask tree": a *traceable* single-tree closure that
-    # the batched/sharded backends can vmap over the candidate axis, and a
-    # plain host callable for the sequential reference / per-step base accs.
+    # the full-forward evaluator loops or vmaps over the candidate axis, and
+    # a plain host callable for the sequential reference / per-step base accs.
 
     def make_param_eval_fn(self, batch):
         """Traceable ``(mask_tree, params) -> accuracy[%]`` — for evaluator
@@ -379,8 +379,8 @@ class CNN:
     def make_joint_eval_fn(self):
         """Traceable ``(mask_tree, ctx) -> accuracy[%]`` with
         ``ctx = {"params": ..., "batch": ...}`` — params AND the eval batch
-        ride as evaluator context (jit inputs), so a ShardedEvaluator on a
-        ``("cand", "batch")`` mesh (``launch.mesh.make_cand_batch_mesh``)
+        ride as evaluator context (jit inputs), so a PipelinedEvaluator on
+        a ``("cand", "batch")`` mesh (``launch.mesh.make_cand_batch_mesh``)
         can lay the batch axis across the ``"batch"`` devices while the
         candidate axis shards over ``"cand"``: the joint layout that keeps
         every device busy when a trial chunk has fewer candidates than the

@@ -1,9 +1,10 @@
 """Candidate-evaluation engine: backend equivalence + chunk semantics.
 
 The contract under test (core.engine / core.bcd._select_block): for the same
-seed and config, every backend — sequential reference, vmapped batched,
-mesh-sharded — selects bit-identical blocks, because (a) candidate sampling
-burns exactly RT rng draws per outer step regardless of backend/chunking,
+seed and config, every evaluator — the sequential reference, the pipelined
+full-forward evaluator on one device or over a mesh — selects bit-identical
+blocks, because (a) candidate sampling burns exactly RT rng draws per outer
+step regardless of evaluator/chunking,
 (b) candidates are scanned in sampling order with first-occurrence argmin
 tie-breaking, and (c) the ADT early exit accepts the first candidate below
 tolerance and never looks past its chunk.
@@ -62,53 +63,26 @@ def _assert_same_result(a, b):
             (hb.budget_before, hb.budget_after)
 
 
-def test_batched_matches_sequential_bitwise(setup):
-    model, params, batch, masks0 = setup
-    seq = _run(model, params, batch, masks0,
-               engine.SequentialEvaluator(model.make_eval_acc(params, batch)))
-    bat = _run(model, params, batch, masks0,
-               engine.BatchedEvaluator(model.make_eval_fn(params, batch),
-                                       pad_to=4))
-    _assert_same_result(seq, bat)
-
-
-def test_sharded_matches_sequential_bitwise(setup):
-    model, params, batch, masks0 = setup
-    seq = _run(model, params, batch, masks0,
-               engine.SequentialEvaluator(model.make_eval_acc(params, batch)))
-    shd = _run(model, params, batch, masks0,
-               engine.ShardedEvaluator(model.make_eval_fn(params, batch),
-                                       mesh_lib.make_candidate_mesh(),
-                                       pad_to=4))
-    _assert_same_result(seq, shd)
-
-
-@pytest.mark.parametrize("prefetch", [0, 1, 2])
-def test_pipelined_matches_sequential_bitwise(setup, prefetch):
-    """Double-buffered staging is a pure latency optimization: for every
-    prefetch depth — including 0, the strict materialize→evaluate
-    degradation — the pipelined backend selects bit-identical blocks.
-    chunk_size=3 against rt=8 forces ragged final chunks (3, 3, 2)."""
+@pytest.mark.parametrize("meshed,depth", [
+    pytest.param(False, 0, id="0"), pytest.param(False, 1, id="1"),
+    pytest.param(False, 2, id="2"), pytest.param(True, 0, id="mesh-0"),
+    pytest.param(True, 1, id="mesh-1"), pytest.param(True, 2, id="mesh-2")])
+def test_pipelined_matches_sequential_bitwise(setup, meshed, depth):
+    """Staging is a pure latency optimization, and the placement only picks
+    the chunk program (the loop on one device, the vmap under a 1-D local
+    mesh): at every staging depth — including 0, the strict
+    materialize→evaluate degradation — the pipelined evaluator selects
+    bit-identical blocks.  chunk_size=3 against rt=8 forces ragged final
+    chunks (3, 3, 2)."""
     model, params, batch, masks0 = setup
     seq = _run(model, params, batch, masks0,
                engine.SequentialEvaluator(model.make_eval_acc(params, batch)),
                chunk_size=3)
-    pip = _run(model, params, batch, masks0,
-               engine.PipelinedEvaluator(model.make_eval_fn(params, batch),
-                                         pad_to=3, prefetch=prefetch),
-               chunk_size=3)
-    _assert_same_result(seq, pip)
-
-
-def test_pipelined_on_mesh_matches_sequential_bitwise(setup):
-    """Prefetch pipeline layered over sharded placement (1-D local mesh)."""
-    model, params, batch, masks0 = setup
-    seq = _run(model, params, batch, masks0,
-               engine.SequentialEvaluator(model.make_eval_acc(params, batch)))
-    pip = _run(model, params, batch, masks0,
-               engine.PipelinedEvaluator(model.make_eval_fn(params, batch),
-                                         pad_to=4, prefetch=2,
-                                         mesh=mesh_lib.make_candidate_mesh()))
+    ev = engine.PipelinedEvaluator(
+        model.make_eval_fn(params, batch), pad_to=3,
+        mesh=mesh_lib.make_candidate_mesh() if meshed else None)
+    ev.prefetch_depth = depth
+    pip = _run(model, params, batch, masks0, ev, chunk_size=3)
     _assert_same_result(seq, pip)
 
 
@@ -116,7 +90,7 @@ def test_chunk_size_does_not_change_selection(setup):
     """rng burns RT draws per step regardless of chunking, so chunk_size is
     a pure performance knob: selections are identical."""
     model, params, batch, masks0 = setup
-    ev = engine.BatchedEvaluator(model.make_eval_fn(params, batch))
+    ev = engine.PipelinedEvaluator(model.make_eval_fn(params, batch))
     a = _run(model, params, batch, masks0, ev, chunk_size=1)
     b = _run(model, params, batch, masks0, ev, chunk_size=8)
     for k in a.masks:
@@ -124,12 +98,12 @@ def test_chunk_size_does_not_change_selection(setup):
 
 
 def test_evaluator_accs_agree(setup):
-    """Raw per-candidate accuracies: vmapped batch == sequential loop."""
+    """Raw per-candidate accuracies: one chunk program == sequential loop."""
     model, params, batch, masks0 = setup
     stacked = M.sample_removal_blocks(
         np.random.default_rng(0), masks0, 16, 6)
     seq = engine.SequentialEvaluator(model.make_eval_acc(params, batch))
-    bat = engine.BatchedEvaluator(model.make_eval_fn(params, batch))
+    bat = engine.PipelinedEvaluator(model.make_eval_fn(params, batch))
     np.testing.assert_allclose(bat.evaluate(stacked), seq.evaluate(stacked),
                                atol=1e-4)
 
@@ -142,31 +116,33 @@ def _pad_with_nan(stacked, n):
             for k, v in stacked.items()}
 
 
-@pytest.mark.parametrize("backend", ["batched", "pipelined"])
+@pytest.mark.parametrize("depth", [0, 2])
 @pytest.mark.parametrize("fill", [1, 3, 4])
 def test_looped_chunk_matches_sequential_and_skips_padding(
-        setup, monkeypatch, backend, fill):
+        setup, monkeypatch, depth, fill):
     """On one device a chunk is one program that loops over its real
     candidates: the same accuracies as the sequential reference at every
-    fill of a 4-slot chunk, whatever the padded slots hold, and the padded
-    slots are never computed (they read 0 and are not counted)."""
+    fill of a 4-slot chunk, read strictly (depth 0) or staged ahead
+    (depth 2), whatever the padded slots hold, and the padded slots are
+    never computed (they read 0 and are not counted)."""
     model, params, batch, masks0 = setup
     pad_to = 4
     stacked = M.sample_removal_blocks(np.random.default_rng(fill), masks0,
                                       16, fill)
     want = engine.SequentialEvaluator(
         model.make_eval_acc(params, batch)).evaluate(stacked)
-    ev = engine.make_evaluator(backend, pad_to=pad_to, context=params,
-                               eval_fn=model.make_param_eval_fn(batch))
+    ev = engine.PipelinedEvaluator(model.make_param_eval_fn(batch),
+                                   pad_to=pad_to, context=params)
+    ev.prefetch_depth = depth
     monkeypatch.setattr(M, "pad_stacked", _pad_with_nan)
     with tracing.recording() as rec:
-        staged = ev.stage(stacked)
-        got = ev.evaluate_staged(staged)
+        got, = engine.evaluate_prefetched(ev, [stacked])
     np.testing.assert_allclose(got, want, atol=1e-4)
-    np.testing.assert_array_equal(np.asarray(staged.accs)[fill:], 0.0)
     assert rec.counts["engine.fallback_forwards"] == fill
     assert rec.counts["engine.fallback_slots"] == pad_to
-    assert (ev._looped._cache_size(), ev._vmapped._cache_size()) == (1, 0)
+    staged = ev.stage(stacked)
+    np.testing.assert_array_equal(np.asarray(staged.accs)[fill:], 0.0)
+    assert ev._program._cache_size() == 1
 
 
 def test_bcd_steps_with_looped_fallback_select_like_sequential(setup):
@@ -211,8 +187,8 @@ def test_lm_eval_closures_batched_matches_sequential():
     stacked = M.sample_removal_blocks(
         np.random.default_rng(1), masks0, 16, 5)
     seq = engine.SequentialEvaluator(model.make_eval_acc(params, batch))
-    bat = engine.BatchedEvaluator(model.make_eval_fn(params, batch),
-                                  pad_to=3)
+    bat = engine.PipelinedEvaluator(model.make_eval_fn(params, batch),
+                                    pad_to=3)
     np.testing.assert_allclose(bat.evaluate(stacked), seq.evaluate(stacked),
                                atol=1e-4)
 
@@ -222,7 +198,7 @@ def test_context_swap_is_visible_without_retrace():
     change results — a closure-captured param tree would silently go stale
     after finetuning."""
     eval_fn = lambda masks, scale: scale * jnp.sum(masks["s"])
-    ev = engine.BatchedEvaluator(eval_fn, context=jnp.asarray(1.0))
+    ev = engine.PipelinedEvaluator(eval_fn, context=jnp.asarray(1.0))
     stacked = M.sample_removal_blocks(
         np.random.default_rng(0), {"s": np.ones((8,), np.float32)}, 2, 3)
     before = ev.evaluate(stacked)
@@ -230,14 +206,13 @@ def test_context_swap_is_visible_without_retrace():
     ev.set_context(jnp.asarray(2.0))
     np.testing.assert_allclose(ev.evaluate(stacked), 2 * before)
     with pytest.raises(ValueError):
-        engine.BatchedEvaluator(lambda m: jnp.sum(m["s"])).set_context(1.0)
-    # the meshless pipelined backend must support the same swap (finetune
-    # between outer steps while chunks are staged)
-    pip = engine.PipelinedEvaluator(eval_fn, context=jnp.asarray(1.0),
-                                    prefetch=2)
-    np.testing.assert_allclose(pip.evaluate(stacked), before)
-    pip.set_context(jnp.asarray(3.0))
-    np.testing.assert_allclose(pip.evaluate(stacked), 3 * before)
+        engine.PipelinedEvaluator(lambda m: jnp.sum(m["s"])).set_context(1.0)
+    # a chunk staged before the swap keeps the context it was dispatched
+    # with (finetune between outer steps while chunks are staged)
+    staged = ev.stage(stacked)
+    ev.set_context(jnp.asarray(3.0))
+    np.testing.assert_allclose(ev.evaluate_staged(staged), 2 * before)
+    np.testing.assert_allclose(ev.evaluate(stacked), 3 * before)
 
 
 _SHARDED_SCRIPT = r"""
@@ -259,7 +234,7 @@ stacked = M.sample_removal_blocks(np.random.default_rng(0), masks0, 8, 6)
 mesh = mesh_lib.make_candidate_mesh()
 assert len(mesh.devices.reshape(-1)) == 4, mesh
 seq = engine.SequentialEvaluator(model.make_eval_acc(params, batch))
-shd = engine.ShardedEvaluator(model.make_eval_fn(params, batch), mesh)
+shd = engine.PipelinedEvaluator(model.make_eval_fn(params, batch), mesh=mesh)
 np.testing.assert_allclose(shd.evaluate(stacked), seq.evaluate(stacked),
                            atol=1e-4)
 print("SHARDED_OK")
@@ -303,15 +278,14 @@ want = engine.SequentialEvaluator(
 mesh = mesh_lib.make_cand_batch_mesh(cand=2, batch=2)
 ctx = {"params": params, "batch": {k: np.asarray(v) for k, v in batch.items()}}
 specs = engine.context_batch_specs(ctx)
-sfx = engine.make_evaluator("suffix", split=model.make_suffix_eval_fns(),
-                            mesh=mesh, context=ctx, context_specs=specs,
-                            pad_to=4)
+sfx = engine.SuffixEvaluator(model.make_suffix_eval_fns(), mesh=mesh,
+                             context=ctx, context_specs=specs, pad_to=4)
 # (evaluator, the chunk evaluator inside it, chunk, slots dispatched): the
 # 1-D mesh pads 6 candidates to its 4 devices; the joint mesh lays 6 over
 # "cand" alone (docs/bcd_engine.md, "Joint candidate×batch sharding")
 cases = (
-    (engine.ShardedEvaluator(model.make_eval_fn(params, batch),
-                             mesh_lib.make_candidate_mesh()), None,
+    (engine.PipelinedEvaluator(model.make_eval_fn(params, batch),
+                               mesh=mesh_lib.make_candidate_mesh()), None,
      stacked, 8),
     (engine.PipelinedEvaluator(model.make_joint_eval_fn(), mesh=mesh,
                                context=ctx, context_specs=specs, pad_to=4),
@@ -324,17 +298,17 @@ for ev, inner, chunk, slots in cases:
     # the vmap computes every slot it is given, padding included
     assert dict(rec.counts) == {"engine.fallback_forwards": slots,
                                 "engine.fallback_slots": slots}, rec.counts
-    assert inner._vmapped._cache_size() == 1, ev.name
-    assert inner._looped._cache_size() == 0, ev.name
+    assert inner._program._cache_size() == 1, ev.name
 print("MESH_PATH_OK")
 """
 
 
 def test_meshed_evaluators_keep_the_vmapped_path():
     """Under a mesh the candidate axis is what shards across devices, so
-    the sharded, meshed pipelined and meshed suffix fallback evaluators keep
-    the vmapped chunk program (every slot computed), not the one-device
-    loop — and still match the sequential reference."""
+    the pipelined evaluator on a 1-D and on a joint mesh, and the meshed
+    suffix evaluator's fallback, keep the vmapped chunk program (every slot
+    computed), not the one-device loop — and still match the sequential
+    reference."""
     import os
     import subprocess
     import sys
@@ -510,8 +484,9 @@ mesh = mesh_lib.make_cand_batch_mesh(cand=2, batch=2)
 assert tuple(mesh.axis_names) == ("cand", "batch"), mesh
 assert mesh.devices.size == 4, mesh
 ctx = {"params": params, "batch": {k: np.asarray(v) for k, v in batch.items()}}
-ev = engine.ShardedEvaluator(model.make_joint_eval_fn(), mesh, context=ctx,
-                             context_specs=engine.context_batch_specs(ctx))
+ev = engine.PipelinedEvaluator(model.make_joint_eval_fn(), mesh=mesh,
+                               context=ctx,
+                               context_specs=engine.context_batch_specs(ctx))
 seq = engine.SequentialEvaluator(model.make_eval_acc(params, batch))
 
 # per-call PartitionSpec selection: a 2-candidate chunk (< 4 devices) must
@@ -527,15 +502,12 @@ np.testing.assert_allclose(ev.evaluate(small), seq.evaluate(small), atol=1e-4)
 np.testing.assert_allclose(ev.evaluate(stacked), seq.evaluate(stacked),
                            atol=1e-4)
 
-# pipelined over the same joint mesh, with a context swap (re-sharded)
-pip = engine.PipelinedEvaluator(model.make_joint_eval_fn(), mesh=mesh,
-                                prefetch=2, context=ctx,
-                                context_specs=engine.context_batch_specs(ctx))
-np.testing.assert_allclose(pip.evaluate(small), seq.evaluate(small),
-                           atol=1e-4)
-pip.set_context(ctx)
-np.testing.assert_allclose(pip.evaluate(stacked), seq.evaluate(stacked),
-                           atol=1e-4)
+# both chunks staged ahead over the same joint mesh, after a context swap
+# (re-sharded)
+ev.set_context(ctx)
+got = list(engine.evaluate_prefetched(ev, [small, stacked]))
+np.testing.assert_allclose(got[0], seq.evaluate(small), atol=1e-4)
+np.testing.assert_allclose(got[1], seq.evaluate(stacked), atol=1e-4)
 print("JOINT_OK")
 """
 
@@ -597,15 +569,15 @@ ops._per_shard = counting
 mesh = mesh_lib.make_cand_batch_mesh(cand=2, batch=2)
 ctx = {"params": params, "batch": {k: np.asarray(v) for k, v in batch.items()}}
 specs = engine.context_batch_specs(ctx)
-ev = engine.ShardedEvaluator(model.make_joint_eval_fn(), mesh, context=ctx,
-                             context_specs=specs)
+ev = engine.PipelinedEvaluator(model.make_joint_eval_fn(), mesh=mesh,
+                               context=ctx, context_specs=specs)
 # a full chunk (joint layout) and a 2-candidate chunk (cand-only layout)
 np.testing.assert_allclose(ev.evaluate(stacked), want, atol=1e-4)
 np.testing.assert_allclose(ev.evaluate(M.slice_stacked(stacked, 0, 2)),
                            want[:2], atol=1e-4)
-sfx = engine.make_evaluator("suffix", split=model.make_suffix_eval_fns(),
-                            mesh=mesh, context=ctx, context_specs=specs,
-                            pad_to=4, fused_kernels=True)
+sfx = engine.SuffixEvaluator(model.make_suffix_eval_fns(), mesh=mesh,
+                             context=ctx, context_specs=specs, pad_to=4,
+                             fused_kernels=True)
 sfx.begin_step(masks0)
 np.testing.assert_allclose(sfx.evaluate(engine.SitedChunk(deep, sited)),
                            want_deep, atol=1e-4)
@@ -617,9 +589,9 @@ print("KERNEL_MESH_OK")
 def test_kernels_run_per_shard_on_forced_multi_device_mesh():
     """The TPU kernel dispatch under a 4-device ("cand", "batch") mesh:
     Mosaic kernels cannot be partitioned automatically, so every kernel
-    runs per device shard (``ops._per_shard``), in the sharded engine's
-    joint and cand-only layouts and in the suffix engine's fused suffix —
-    and still matches the sequential reference."""
+    runs per device shard (``ops._per_shard``), in the meshed pipelined
+    evaluator's joint and cand-only layouts and in the suffix engine's
+    fused suffix — and still matches the sequential reference."""
     import os
     import subprocess
     import sys
@@ -631,59 +603,6 @@ def test_kernels_run_per_shard_on_forced_multi_device_mesh():
                          env=env, capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "KERNEL_MESH_OK" in out.stdout
-
-
-# ------------------------------------------------- prefetch auto-tuning
-
-
-def test_auto_prefetch_picks_depth_and_matches_sequential(setup):
-    """prefetch='auto': the first chunks probe producer vs consumer rates
-    in strict alternation, then the depth locks in for the rest of the run
-    — and selection stays bit-identical to the sequential reference."""
-    model, params, batch, masks0 = setup
-    seq = _run(model, params, batch, masks0,
-               engine.SequentialEvaluator(model.make_eval_acc(params, batch)),
-               chunk_size=2)
-    ev = engine.PipelinedEvaluator(model.make_eval_fn(params, batch),
-                                   pad_to=2, prefetch="auto")
-    assert ev.prefetch_depth == 0 and not ev.auto_tuner.done
-    pip = _run(model, params, batch, masks0, ev, chunk_size=2)
-    _assert_same_result(seq, pip)
-    assert ev.auto_tuner.done
-    assert 1 <= ev.prefetch_depth <= ev.auto_tuner.max_depth
-    assert set(ev.auto_report) == {"producer_s", "consumer_s", "prefetch",
-                                   "samples"}
-    assert ev.auto_report["prefetch"] == ev.prefetch_depth
-
-
-def test_auto_tuner_depth_formula():
-    t = engine.PrefetchAutoTuner(n_probe=2, max_depth=4)
-    t.add_sample(1.0, 1.0)              # warm-up (compile) — discarded
-    t.add_sample(0.001, 0.0095)
-    assert not t.done
-    t.add_sample(0.001, 0.0105)
-    assert t.done
-    assert t.depth() == 4               # floor(10) capped at max_depth
-    slow_prod = engine.PrefetchAutoTuner(n_probe=1, max_depth=4)
-    slow_prod.add_sample(1.0, 1.0)
-    slow_prod.add_sample(0.05, 0.001)   # producer-bound: still overlap once
-    assert slow_prod.done and slow_prod.depth() == 1
-
-
-def test_make_evaluator_accepts_auto_prefetch():
-    ev = engine.make_evaluator("pipelined",
-                               eval_fn=lambda m: jnp.sum(m["s"]),
-                               prefetch="auto")
-    assert ev.auto_tuner is not None and ev.prefetch_depth == 0
-    with pytest.raises(ValueError):
-        engine.make_evaluator("pipelined", eval_fn=lambda m: 0.0,
-                              prefetch="bogus")
-    # backends without a staging pipeline must reject 'auto' loudly rather
-    # than silently running untuned
-    for backend in ("sequential", "batched", "sharded"):
-        with pytest.raises(ValueError, match="pipelined"):
-            engine.make_evaluator(backend, eval_acc=lambda m: 0.0,
-                                  eval_fn=lambda m: 0.0, prefetch="auto")
 
 
 # ------------------------------------------------------------- hardening
@@ -707,26 +626,29 @@ def test_target_at_or_above_start_is_noop():
     assert res.history == [] and M.count(res.masks) == M.count(masks)
 
 
-def test_make_evaluator_factory_validates():
-    with pytest.raises(ValueError):
-        engine.make_evaluator("sequential")
-    with pytest.raises(ValueError):
-        engine.make_evaluator("batched")
-    with pytest.raises(ValueError):
-        engine.make_evaluator("pipelined")
-    with pytest.raises(ValueError):
-        engine.make_evaluator("nope", eval_acc=lambda m: 0.0)
-    with pytest.raises(ValueError):        # negative prefetch
-        engine.make_evaluator("pipelined", eval_fn=lambda m: 0.0,
-                              prefetch=-1)
-    with pytest.raises(ValueError):        # context_specs needs a mesh
+def test_make_evaluator_factory_validates(setup):
+    """``sweep.make_bcd_evaluator`` builds the engine and its reference and
+    refuses any other name; the pipelined evaluator refuses a context
+    placement it cannot honour."""
+    model, params, batch, _ = setup
+    build = lambda name: sweep.make_bcd_evaluator(
+        name, model, batch, {"params": params}, chunk_size=4, rt=8)
+    for name in ("batched", "sharded", "pipelined", "nope"):
+        with pytest.raises(ValueError, match="'sequential' or 'suffix'"):
+            build(name)
+    with pytest.raises(ValueError, match="requires a mesh"):
         engine.PipelinedEvaluator(lambda m: 0.0, context={"batch": {}},
                                   context_specs={"batch": {}})
-    ev = engine.make_evaluator("pipelined",
-                               eval_fn=lambda m: jnp.sum(m["s"]),
-                               prefetch=2)
-    assert ev.prefetch_depth == 2 and ev.name == "pipelined"
-    ev = engine.make_evaluator("sequential", eval_acc=lambda m: 42.0)
-    accs = ev.evaluate(M.sample_removal_blocks(
-        np.random.default_rng(0), _tiny_masks(), 2, 3))
+    with pytest.raises(ValueError, match="without a context"):
+        engine.PipelinedEvaluator(lambda m: 0.0,
+                                  mesh=mesh_lib.make_candidate_mesh(),
+                                  context_specs={})
+    ev, _, _ = build("suffix")
+    assert (ev.name, ev.prefetch_depth) == ("suffix", 2)
+    assert (ev._inner.name, ev._inner.prefetch_depth) == ("pipelined", 2)
+    ev, _, _ = build("sequential")
+    assert (ev.name, ev.prefetch_depth) == ("sequential", 0)
+    accs = engine.SequentialEvaluator(lambda m: 42.0).evaluate(
+        M.sample_removal_blocks(np.random.default_rng(0), _tiny_masks(),
+                                2, 3))
     np.testing.assert_array_equal(accs, [42.0, 42.0, 42.0])

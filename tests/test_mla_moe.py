@@ -267,8 +267,8 @@ def test_suffix_engine_selects_the_sequential_engines_blocks():
         return bcd.run_bcd(masks0, c, model.make_eval_acc(params, batch),
                            evaluator=evaluator)
     seq = run(engine.SequentialEvaluator(model.make_eval_acc(params, batch)))
-    suf = run(engine.make_evaluator(
-        "suffix", split=model.make_suffix_eval_fns(),
+    suf = run(engine.SuffixEvaluator(
+        model.make_suffix_eval_fns(),
         context={"params": params, "batch": batch}, pad_to=4))
     for k in seq.masks:
         np.testing.assert_array_equal(seq.masks[k], suf.masks[k])

@@ -31,7 +31,9 @@ try:
 except ImportError:
     HAS_HYPOTHESIS = False
 
-BACKENDS = ("sequential", "batched", "sharded", "pipelined", "suffix")
+# the pipelined evaluator read strictly (depth 0), over a 1-D local mesh
+# (the vmapped chunk program), and staged ahead at its own depth
+BACKENDS = ("sequential", "depth0", "mesh", "pipelined", "suffix")
 MIXED = M.MOVE_KINDS                 # all five kinds in one config
 
 
@@ -50,27 +52,26 @@ def setup():
     return model, params, batch, masks0
 
 
-def _make_ev(backend, model, params, batch, prefetch=1):
+def _make_ev(backend, model, params, batch, prefetch=None):
     if backend == "sequential":
         return engine.SequentialEvaluator(model.make_eval_acc(params, batch))
-    if backend == "batched":
-        return engine.BatchedEvaluator(model.make_eval_fn(params, batch),
-                                       pad_to=3)
-    if backend == "sharded":
-        return engine.ShardedEvaluator(model.make_eval_fn(params, batch),
-                                       mesh_lib.make_candidate_mesh(),
-                                       pad_to=3)
-    if backend == "pipelined":
-        return engine.PipelinedEvaluator(model.make_eval_fn(params, batch),
-                                         pad_to=3, prefetch=prefetch)
     if backend == "suffix":
         ctx = {"params": params,
                "batch": {k: np.asarray(v) for k, v in batch.items()}}
-        return engine.make_evaluator("suffix",
-                                     split=model.make_suffix_eval_fns(),
-                                     context=ctx, pad_to=3,
-                                     prefetch=prefetch)
-    raise AssertionError(backend)
+        ev = engine.SuffixEvaluator(model.make_suffix_eval_fns(),
+                                    context=ctx, pad_to=3)
+    elif backend in ("depth0", "mesh", "pipelined"):
+        ev = engine.PipelinedEvaluator(
+            model.make_eval_fn(params, batch), pad_to=3,
+            mesh=mesh_lib.make_candidate_mesh() if backend == "mesh"
+            else None)
+        if backend != "pipelined":
+            ev.prefetch_depth = 0
+    else:
+        raise AssertionError(backend)
+    if prefetch is not None:
+        ev.prefetch_depth = prefetch
+    return ev
 
 
 def _run(model, params, batch, masks0, evaluator, moves,
@@ -119,7 +120,7 @@ def seq_ref(setup):
 @pytest.mark.parametrize("backend", BACKENDS[1:])
 @pytest.mark.parametrize("kind", M.MOVE_KINDS)
 def test_backend_matches_sequential_per_kind(setup, seq_ref, backend, kind):
-    """{batched, sharded, pipelined, suffix} × {remove, add_back, swap,
+    """{depth0, mesh, pipelined, suffix} × {remove, add_back, swap,
     stage_drop, share}: bit-identical masks, trial counts, early-exit flags
     and acceptance stats vs the sequential reference."""
     model, params, batch, masks0 = setup
@@ -200,7 +201,7 @@ def family_seq_ref(family_setup):
 def test_family_backend_matches_sequential_per_kind(family_setup,
                                                     family_seq_ref, arch,
                                                     backend, kind):
-    """{rwkv6, deepseek-moe} × {batched, sharded, pipelined, suffix} ×
+    """{rwkv6, deepseek-moe} × {depth0, mesh, pipelined, suffix} ×
     {remove, swap, stage_drop}: bit-identical masks, trial counts and
     early-exit flags vs the per-family sequential reference."""
     model, params, batch, masks0 = family_setup[arch]
@@ -243,8 +244,9 @@ for arch in ("rwkv6_3b", "deepseek_moe_16b"):
     mesh = mesh_lib.make_candidate_mesh()
     assert len(mesh.devices.reshape(-1)) == 4, mesh
     shd = bcd.run_bcd(masks0, cfg, eval_acc,
-                      evaluator=engine.ShardedEvaluator(
-                          model.make_eval_fn(params, batch), mesh, pad_to=3))
+                      evaluator=engine.PipelinedEvaluator(
+                          model.make_eval_fn(params, batch), mesh=mesh,
+                          pad_to=3))
     for k in seq.masks:
         np.testing.assert_array_equal(seq.masks[k], shd.masks[k])
     assert [(h.trials, h.found_early, h.move_kind) for h in seq.history] \
@@ -296,8 +298,9 @@ seq = bcd.run_bcd(masks0, cfg, eval_acc,
 mesh = mesh_lib.make_candidate_mesh()
 assert len(mesh.devices.reshape(-1)) == 4, mesh
 shd = bcd.run_bcd(masks0, cfg, eval_acc,
-                  evaluator=engine.ShardedEvaluator(
-                      model.make_eval_fn(params, batch), mesh, pad_to=3))
+                  evaluator=engine.PipelinedEvaluator(
+                      model.make_eval_fn(params, batch), mesh=mesh,
+                      pad_to=3))
 for k in seq.masks:
     np.testing.assert_array_equal(seq.masks[k], shd.masks[k])
 assert [h.move_kind for h in seq.history] == \
@@ -499,9 +502,8 @@ if HAS_HYPOTHESIS:
 def _suffix_ev(model, params, batch, **kw):
     ctx = {"params": params,
            "batch": {k: np.asarray(v) for k, v in batch.items()}}
-    return engine.make_evaluator("suffix",
-                                 split=model.make_suffix_eval_fns(),
-                                 context=ctx, **kw)
+    return engine.SuffixEvaluator(model.make_suffix_eval_fns(), context=ctx,
+                                  **kw)
 
 
 def test_two_segment_move_never_straddles_sited_chunks(setup):
@@ -530,10 +532,9 @@ def test_two_segment_move_never_straddles_sited_chunks(setup):
     assert ranks[1] == split.site_segment[shallow]
     # force suffix mode for every sited chunk — the fallback path would
     # make the straddling check vacuous
-    from repro.analysis.roofline import SuffixCostModel
     ev = _suffix_ev(model, params, batch, pad_to=3,
-                    cost_model=SuffixCostModel(min_prefix_fraction=0.0,
-                                               min_chunk=1))
+                    cost_model=engine.SuffixCostModel(
+                        min_prefix_fraction=0.0, min_chunk=1))
     ev.begin_step(masks0)
     order, chunks = engine.plan_sited_chunks(ev, moves, layout,
                                              chunk_size=3)

@@ -29,7 +29,6 @@ try:
 except ImportError:
     HAS_HYPOTHESIS = False
 
-from repro.analysis.roofline import SuffixCostModel
 from repro.configs.base import ArchConfig, Block
 from repro.core import bcd, engine, linearize, masks as M
 from repro.core.engine import PrefixTrie, tree_nbytes
@@ -236,9 +235,8 @@ def setup():
 def _suffix_ev(model, params, batch, **kw):
     ctx = {"params": params,
            "batch": {k: np.asarray(v) for k, v in batch.items()}}
-    return engine.make_evaluator("suffix",
-                                 split=model.make_suffix_eval_fns(),
-                                 context=ctx, **kw)
+    return engine.SuffixEvaluator(model.make_suffix_eval_fns(), context=ctx,
+                                  **kw)
 
 
 def test_engine_extends_ancestor_instead_of_recomputing(setup):
@@ -297,7 +295,7 @@ def test_trie_budget_thrash_does_not_change_selection(setup):
                       evaluator=engine.SequentialEvaluator(eval_acc))
     tight = bcd.run_bcd(masks0, cfg, eval_acc,
                         evaluator=_suffix_ev(model, params, batch,
-                                             pad_to=4, prefetch=1,
+                                             pad_to=4,
                                              trie_budget_bytes=0))
     for k in ref.masks:
         np.testing.assert_array_equal(ref.masks[k], tight.masks[k])
@@ -306,9 +304,10 @@ def test_trie_budget_thrash_does_not_change_selection(setup):
 
 
 def test_engine_multi_step_trie_reuse_matches_sequential(setup):
-    """Full run_bcd with a warm trie carried across outer steps (plus the
-    calibrated-capable cost model path) stays bit-identical to the
-    sequential reference."""
+    """Full run_bcd with a warm trie carried across outer steps, under the
+    analytic cost model at thresholds that send every multi-candidate
+    chunk down the suffix path, stays bit-identical to the sequential
+    reference."""
     model, params, batch, masks0 = setup
     total = M.count(masks0)
     cfg = bcd.BCDConfig(b_target=total - 4 * 12, drc=12, rt=8, adt=0.5,
@@ -316,11 +315,10 @@ def test_engine_multi_step_trie_reuse_matches_sequential(setup):
     eval_acc = model.make_eval_acc(params, batch)
     ref = bcd.run_bcd(masks0, cfg, eval_acc,
                       evaluator=engine.SequentialEvaluator(eval_acc))
-    cm = SuffixCostModel(measured=((0.3, 2.0, 8), (0.75, 4.0, 8)))
+    cm = engine.SuffixCostModel(min_prefix_fraction=0.0)
     suf = bcd.run_bcd(masks0, cfg, eval_acc,
                       evaluator=_suffix_ev(model, params, batch,
-                                           pad_to=3, prefetch=1,
-                                           cost_model=cm))
+                                           pad_to=3, cost_model=cm))
     for k in ref.masks:
         np.testing.assert_array_equal(ref.masks[k], suf.masks[k])
     for ha, hb in zip(ref.history, suf.history):

@@ -140,79 +140,3 @@ def test_peaks_table_is_keyed_by_device_kind():
                     model_flops_global=1.0)
     with pytest.raises(ValueError, match="no published peaks"):
         r.row()
-
-
-# --------------------------------------------- measured suffix cost model
-
-
-def _hist_entry(chunk=8, site="deep.site", frac=0.75, sp=4.0,
-                mode="suffix", **cfg):
-    return {"config": {"chunk_size": chunk, **cfg},
-            "per_site_depth": {"deep": {
-                "site": site, "prefix_fraction": frac,
-                "speedup_suffix_vs_batched": sp, "mode": mode}}}
-
-
-def _write_hist(path, entries, *, junk=True):
-    import json
-    with open(path, "w") as fh:
-        if junk:
-            fh.write("not json at all\n\n[1, 2, 3]\n")
-            # legacy PR-5-era line: summary keys only, no per_site_depth
-            fh.write(json.dumps({"config": {"chunk_size": 8},
-                                 "speedup_suffix_vs_batched": 4.0}) + "\n")
-        for e in entries:
-            fh.write(json.dumps(e) + "\n")
-
-
-def test_cost_model_calibrated_missing_history_is_analytic(tmp_path):
-    cm = rl.SuffixCostModel.calibrated(str(tmp_path / "nope.jsonl"))
-    assert cm.measured is None
-    assert cm.use_suffix(0.5, 8) and not cm.use_suffix(0.01, 8)
-
-
-def test_cost_model_calibrated_ewma_and_fingerprint(tmp_path):
-    p = str(tmp_path / "h.jsonl")
-    _write_hist(p, [
-        _hist_entry(sp=4.0, model="r18-mini"),
-        _hist_entry(sp=2.0, model="r18-mini"),          # EWMA -> 3.0
-        _hist_entry(sp=100.0, model="other"),           # filtered out
-        _hist_entry(sp=100.0, mode="fallback"),         # not a measurement
-    ])
-    cm = rl.SuffixCostModel.calibrated(p, fingerprint={"model": "r18-mini"})
-    assert cm.measured == ((0.75, 3.0, 8),)
-    # fingerprint keys absent from an entry's config don't exclude it
-    cm2 = rl.SuffixCostModel.calibrated(
-        p, fingerprint={"model": "r18-mini", "n_devices": 1})
-    assert cm2.measured == ((0.75, 3.0, 8),)
-    # no fingerprint: the alien entry joins the EWMA
-    cm3 = rl.SuffixCostModel.calibrated(p, fingerprint=None)
-    assert cm3.measured is not None and cm3.measured[0][1] > 3.0
-
-
-def test_cost_model_predicted_speedup_interpolates(tmp_path):
-    cm = rl.SuffixCostModel(measured=((0.4, 2.0, 8), (0.8, 4.0, 8)))
-    # exact measured point at its own chunk size
-    assert cm.predicted_speedup(0.4, 8) == pytest.approx(2.0)
-    assert cm.predicted_speedup(0.8, 8) == pytest.approx(4.0)
-    # midpoint interpolates
-    assert cm.predicted_speedup(0.6, 8) == pytest.approx(3.0, rel=0.2)
-    # below the shallowest point: anchored at (0, 1)
-    assert cm.predicted_speedup(0.0, 8) == pytest.approx(1.0)
-    assert 1.0 < cm.predicted_speedup(0.2, 8) < 2.0
-    # trie coverage only ever helps
-    assert cm.predicted_speedup(0.8, 8, covered=0.8) > \
-        cm.predicted_speedup(0.8, 8)
-    # larger chunks amortize the prefix: analytic rescaling is monotone
-    assert cm.predicted_speedup(0.8, 32) > cm.predicted_speedup(0.8, 8)
-
-
-def test_cost_model_measured_decision_respects_margin():
-    cm = rl.SuffixCostModel(measured=((0.75, 4.0, 8),), min_speedup=1.05)
-    assert cm.use_suffix(0.75, 8)
-    assert not cm.use_suffix(0.001, 8)     # interpolates to ~1.0 < margin
-    assert not cm.use_suffix(0.75, 1)      # min_chunk still applies
-    # a measured slowdown at depth turns suffix off where analytic says on
-    slow = rl.SuffixCostModel(measured=((0.75, 0.9, 8),))
-    assert not slow.use_suffix(0.75, 8)
-    assert rl.SuffixCostModel().use_suffix(0.75, 8)   # analytic prior: on

@@ -98,14 +98,15 @@ def test_rng_restore_rejects_foreign_bit_generator():
 def _toy_evaluator(backend):
     if backend == "sequential":
         return engine.SequentialEvaluator(_toy_eval_acc)
-    if backend == "batched":
-        return engine.BatchedEvaluator(_toy_eval_fn, pad_to=2)
-    if backend == "pipelined":
-        return engine.PipelinedEvaluator(_toy_eval_fn, pad_to=2, prefetch=2)
-    raise AssertionError(backend)
+    ev = engine.PipelinedEvaluator(_toy_eval_fn, pad_to=2)
+    if backend == "depth0":
+        ev.prefetch_depth = 0
+    elif backend != "pipelined":
+        raise AssertionError(backend)
+    return ev
 
 
-@pytest.mark.parametrize("backend", ["sequential", "batched", "pipelined"])
+@pytest.mark.parametrize("backend", ["sequential", "depth0", "pipelined"])
 def test_resume_matches_uninterrupted_across_backends(backend, tmp_path):
     masks = _toy_masks()
     cfg = _toy_cfg(masks, steps=5)
@@ -375,16 +376,12 @@ def test_sweep_descends_warm_started_and_resumes(tmp_path):
     assert all("wall_s" not in h for s in art["stages"]
                for h in s["history"])
 
-    # notes merged out-of-band (e.g. the auto-prefetch report) must survive
-    # a later artifact rewrite by a resumed sweep
-    sweep_lib.update_notes(cfg, {"auto_prefetch": {"prefetch": 2}})
-
-    # re-run: both stages skip, artifact identical, notes preserved
+    # re-run: both stages skip, artifact identical
     res2 = sweep_lib.run_sweep(cfg, mk, _toy_eval_acc, init=init,
                                params_io=pio, eval_test=_toy_eval_acc)
     assert [s["mask_fingerprint"] for s in res2["stages"]] == \
         [s["mask_fingerprint"] for s in res["stages"]]
-    assert res2["notes"]["auto_prefetch"] == {"prefetch": 2}
+    assert res2["notes"] == res["notes"]
 
 
 def test_sweep_interrupted_mid_stage_matches_uninterrupted(tmp_path):

@@ -33,9 +33,9 @@ try:
 except ImportError:
     HAS_HYPOTHESIS = False
 
-from repro.analysis.roofline import SuffixCostModel
 from repro.configs.base import ArchConfig, Block, get_config
 from repro.core import bcd, engine, linearize, masks as M
+from repro.core.engine import SuffixCostModel
 from repro.data import ImageDatasetCfg, SyntheticImages
 from repro.models.lm import LM
 from repro.models.resnet import CNN, CNNConfig
@@ -201,8 +201,8 @@ def test_suffix_trie_extends_along_repeats_and_row_diff_invalidation():
     tokens = np.asarray(np.random.default_rng(2).integers(
         0, model.cfg.vocab, (2, 17), dtype=np.int32))
     ctx = {"params": params, "batch": {"tokens": tokens}}
-    ev = engine.make_evaluator("suffix", split=model.make_suffix_eval_fns(),
-                               context=ctx, pad_to=4)
+    ev = engine.SuffixEvaluator(model.make_suffix_eval_fns(), context=ctx,
+                                pad_to=4)
     seq = engine.SequentialEvaluator(
         model.make_eval_acc(params, {"tokens": tokens}))
     segs, reps = model.site_segments(), model.site_repeats()
@@ -566,12 +566,14 @@ def _assert_same_result(a, b):
             (hb.budget_before, hb.budget_after)
 
 
-def _suffix_ev(model, params, batch, **kw):
+def _suffix_ev(model, params, batch, prefetch=None, **kw):
     ctx = {"params": params,
            "batch": {k: np.asarray(v) for k, v in batch.items()}}
-    return engine.make_evaluator("suffix",
-                                 split=model.make_suffix_eval_fns(),
-                                 context=ctx, **kw)
+    ev = engine.SuffixEvaluator(model.make_suffix_eval_fns(), context=ctx,
+                                **kw)
+    if prefetch is not None:
+        ev.prefetch_depth = prefetch
+    return ev
 
 
 @pytest.mark.parametrize("prefetch", [0, 1, 2])
@@ -592,11 +594,12 @@ def test_suffix_matches_sequential_bitwise(setup, prefetch):
 
 def test_suffix_matches_batched_without_early_exit(setup):
     """adt=-1 disables the ADT exit: the full RT argmin path, where every
-    candidate is evaluated — suffix vs batched must agree exactly."""
+    candidate is evaluated — suffix vs the full-forward pipelined evaluator
+    must agree exactly."""
     model, params, batch, masks0 = setup
     bat = _run(model, params, batch, masks0,
-               engine.BatchedEvaluator(model.make_eval_fn(params, batch),
-                                       pad_to=4), adt=-1.0)
+               engine.PipelinedEvaluator(model.make_eval_fn(params, batch),
+                                         pad_to=4), adt=-1.0)
     suf = _run(model, params, batch, masks0,
                _suffix_ev(model, params, batch, pad_to=4, prefetch=1),
                adt=-1.0)
@@ -683,10 +686,9 @@ def test_suffix_evaluator_validates_inputs(setup):
         engine.SuffixEvaluator(split, context={"params": params})
     ctx = {"params": params,
            "batch": {k: np.asarray(v) for k, v in batch.items()}}
-    with pytest.raises(ValueError, match="prefetch"):
-        engine.SuffixEvaluator(split, context=ctx, prefetch="turbo")
-    with pytest.raises(ValueError, match="split"):
-        engine.make_evaluator("suffix", context=ctx)
+    with pytest.raises(ValueError, match="requires a mesh"):
+        engine.SuffixEvaluator(split, context=ctx,
+                               context_specs=engine.context_batch_specs(ctx))
     ev = engine.SuffixEvaluator(split, context=ctx)
     with pytest.raises(RuntimeError, match="begin_step"):
         ev.evaluate(engine.SitedChunk(
@@ -695,17 +697,14 @@ def test_suffix_evaluator_validates_inputs(setup):
                                     4, 2)))
 
 
-def test_suffix_auto_prefetch_tunes_and_matches_sequential(setup):
-    """prefetch="auto" on the suffix backend: the inner pipeline's tuner
-    probes the first chunks, locks a depth, and results stay bit-identical
-    to the sequential reference throughout (the probe changes timing
-    only)."""
+def test_suffix_staged_plan_matches_sequential(setup):
+    """A whole site-major plan, sited and fallback chunks, staged ahead at
+    the suffix evaluator's depth (its inner evaluator's): the results, put
+    back in sampling order, are bit-identical to the sequential
+    reference."""
     model, params, batch, masks0 = setup
-    ctx = {"params": params,
-           "batch": {k: np.asarray(v) for k, v in batch.items()}}
-    ev = engine.SuffixEvaluator(model.make_suffix_eval_fns(), context=ctx,
-                                pad_to=2, prefetch="auto")
-    assert ev.auto_tuner is not None and not ev.auto_tuner.done
+    ev = _suffix_ev(model, params, batch, pad_to=2)
+    assert ev.prefetch_depth == ev._inner.prefetch_depth == 2
     seq = engine.SequentialEvaluator(model.make_eval_acc(params, batch))
     idx = M.sample_removal_indices(np.random.default_rng(3), masks0, 6, 12)
     flat, layout = M._flatten(masks0)
@@ -718,9 +717,7 @@ def test_suffix_auto_prefetch_tunes_and_matches_sequential(setup):
     accs_s[order] = accs
     ref = seq.evaluate(M.materialize_candidates(masks0, idx))
     np.testing.assert_array_equal(accs_s, ref)
-    # enough chunks to finish the probe: the tuner locked a depth
-    assert ev.auto_tuner.done
-    assert ev.prefetch_depth >= 0 and ev.auto_report is not None
+    assert {site is None for site, _, _ in chunks} == {True, False}
 
 
 # ----------------------------------------- forced multi-device sharding
@@ -747,7 +744,7 @@ ctx = {"params": params,
        "batch": {k: np.asarray(v) for k, v in batch.items()}}
 ev = engine.SuffixEvaluator(model.make_suffix_eval_fns(), context=ctx,
                             context_specs=engine.context_batch_specs(ctx),
-                            mesh=mesh, pad_to=4, prefetch=1)
+                            mesh=mesh, pad_to=4)
 seq = engine.SequentialEvaluator(model.make_eval_acc(params, batch))
 
 order, segs = model.site_order(), model.site_segments()

@@ -11,6 +11,7 @@ Nothing runs, so these tests say nothing about results or times.
 import dataclasses
 import os
 
+import numpy as np
 import pytest
 
 import jax
@@ -183,11 +184,15 @@ def test_looped_fallback_compiles_for_v5e_in_a_quarter_of_the_vmaps_temp(
     ctx = jax.tree.map(sd, {**ctx, "pre": jax.eval_shape(split.pre, ctx)})
     masks = {k: sd(jax.ShapeDtypeStruct((8,) + s.shape, F32))
              for k, s in model.mask_sites().items()}
-    # the context here only marks eval_fn as taking one; shapes come below
-    ev = engine.BatchedEvaluator(split.full, pad_to=8, context={})
-    looped = ev._looped.lower(masks, sd(jax.ShapeDtypeStruct((), jnp.int32)),
-                              ctx).compile()
-    vmapped = ev._vmapped.lower(masks, ctx).compile()
+    # the context here only marks eval_fn as taking one; shapes come below.
+    # A mesh of the one described chip gives the evaluator's vmapped program.
+    mesh = jax.sharding.Mesh(np.array(list(one_chip.device_set)), ("cand",))
+    one, meshed = (engine.PipelinedEvaluator(split.full, pad_to=8,
+                                             context={}, mesh=m)
+                   for m in (None, mesh))
+    looped = one._program.lower(
+        masks, sd(jax.ShapeDtypeStruct((), jnp.int32)), ctx).compile()
+    vmapped = meshed._program.lower(masks, ctx).compile()
     text = looped.as_text()
     assert "while" in text and "tpu_custom_call" in text
     assert looped.memory_analysis().temp_size_in_bytes < \
