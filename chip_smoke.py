@@ -10,7 +10,8 @@ the entry points a user calls, and each checks its results:
 
 * kernels — every Pallas kernel on a model path at the widths the models
   run, against its jnp oracle, with ``tpu_custom_call`` in its compiled
-  text; the masked activation's custom VJP against the oracle's gradient.
+  text; the hard-mask gate the models differentiate (XLA's, no kernel)
+  against the oracle's gradient.
 * bcd — ResNet18 on synthetic CIFAR-10 at real shapes, built as
   ``examples/resnet18_bcd_pipeline.py --full`` builds it: train steps, a
   short SNL to B_ref, then BCD outer steps through the ``suffix`` engine
@@ -104,7 +105,8 @@ def _rel_err(got, want) -> float:
 def phase_kernels() -> None:
     import jax
     import jax.numpy as jnp
-    from repro.kernels import masked_act as K, ops, ref
+    from repro.core import linearize
+    from repro.kernels import masked_act as K, ref
 
     bf16, f32 = jnp.bfloat16, jnp.float32
     d_ff, d_model = 5632, 2048            # stablelm-1.6b FFN
@@ -183,24 +185,26 @@ def phase_kernels() -> None:
               f"[smoke timing] warm_call_s={warm:.6f}", flush=True)
         check(err <= tol, f"{name}: error {err:.3e} above {tol:.0e}")
 
-    # custom VJP at the ResNet18 stem site: the kernel stays in the forward
-    # and the gradient is the oracle's
+    # the unrouted hard-mask gate at the ResNet18 stem site, as training,
+    # finetuning and the plain forwards run it: XLA's, with no kernel, and
+    # its gradient is the oracle's
     x = jax.random.normal(jax.random.fold_in(key, 100),
                           (EVAL_BATCH, 32, 32, 64), f32)
     m = jax.random.bernoulli(jax.random.fold_in(key, 101), 0.5,
                              (32, 32, 64)).astype(f32)
     cot = jax.random.normal(jax.random.fold_in(key, 102), x.shape, f32)
+    site = linearize.MaskSite((32, 32, 64), "relu")
     step = jax.jit(jax.value_and_grad(
-        lambda x: jnp.sum(ops.masked_act_sited(x, m) * cot)))
-    check("tpu_custom_call" in step.lower(x).compile().as_text(),
-          "masked_act VJP: kernel missing from the differentiated forward")
+        lambda x: jnp.sum(linearize.apply_masked_act(x, m, site) * cot)))
+    check("tpu_custom_call" not in step.lower(x).compile().as_text(),
+          "hard-mask gate: a kernel in the differentiated forward")
     _, g = step(x)
     g_ref = jax.jit(jax.grad(
         lambda x: jnp.sum(ref.masked_act_ref(x, m) * cot)))(x)
     err = _rel_err(g, g_ref)
-    print(f"  masked_act custom_vjp grad: max|err|/max|ref|={err:.3e} "
+    print(f"  hard-mask gate grad: max|err|/max|ref|={err:.3e} "
           "(tol 1e-6)", flush=True)
-    check(err <= 1e-6, f"masked_act VJP: gradient error {err:.3e}")
+    check(err <= 1e-6, f"hard-mask gate: gradient error {err:.3e}")
 
 
 # ---------------------------------------------------------------------- bcd

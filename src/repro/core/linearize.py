@@ -24,12 +24,13 @@ _ROUTE_STATE = threading.local()
 def stacked_kernel_route(on: bool = True):
     """Trace-time hint (thread-local): inside this context, the hard-mask
     TPU dispatch in :func:`apply_masked_act` emits the custom-vmap routed op
-    (``ops.masked_act_sited_routed``), so a candidate-axis vmap — the
-    batched/sharded/pipelined engines in ``core.engine`` — lowers every mask
-    site to the stacked Pallas kernel (``masked_act_2d_batched``) instead of
-    vmapping the per-candidate kernel's grid.  Off by default: custom_vmap
-    does not support differentiation, and training forwards must keep the
-    plain kernel."""
+    (``ops.masked_act_sited_routed``), so a candidate-axis vmap — the meshed
+    ``core.engine.PipelinedEvaluator`` and the suffix engine — lowers every
+    mask site to the stacked Pallas kernel (``masked_act_2d_batched``).
+    Off by default: custom_vmap does not support differentiation, and
+    everywhere else the gate is the plain broadcast expression
+    (:func:`masked_act_plain`), which XLA fuses into the op that produces
+    ``x``."""
     prev = getattr(_ROUTE_STATE, "on", False)
     _ROUTE_STATE.on = on
     try:
@@ -170,26 +171,24 @@ def apply_masked_act(x, mask, site: MaskSite, poly=None, soft: bool = False):
     """Apply the (possibly soft, for SNL) masked activation at a site.
 
     x: (batch..., *site.shape) — site shape must be the trailing dims.
-    soft=True keeps real-valued masks differentiable (SNL's relaxation);
-    hard masks route through the fused kernel wrapper.  Hard masks may
-    carry share-tied coordinates (``masks.TIE``), overridden by
+    soft=True keeps real-valued masks differentiable (SNL's relaxation).
+    The gate is :func:`masked_act_plain` on every backend, except for hard
+    masks on the TPU under :func:`stacked_kernel_route`, which take the
+    routed Pallas op.  The plain expression is elementwise, so XLA fuses it
+    into the batch norm or residual add that produces ``x``; a standalone
+    kernel would make ``x`` and the gate's output round-trip HBM.  Hard
+    masks may carry share-tied coordinates (``masks.TIE``), overridden by
     :func:`_apply_share_ties`; soft mode treats every real value as an SNL
     relaxation weight and never ties.
     """
     p = None
     if poly is not None and (soft or site.replacement == "poly2"):
         p = poly
-    if soft:
-        mask = jnp.clip(mask, 0.0, 1.0)
-    if soft or not ops._use_pallas():
+    if soft or not (stacked_route_active() and ops._use_pallas()):
         # Direct broadcast application — NO reshape.  Flattening the site
         # dims (e.g. an MoE (E, F) mask) merges a model-sharded axis into a
         # mixed one and forces GSPMD to fully rematerialize the activation
-        # (EXPERIMENTS.md §Perf, mixtral hillclimb).  Pallas needs the 2D
-        # layout, but it only runs on TPU where the kernel owns the tiling.
+        # (EXPERIMENTS.md §Perf, mixtral hillclimb).
         return masked_act_plain(x, mask, site, poly=p, soft=soft)
-    if stacked_route_active():
-        out = ops.masked_act_sited_routed(x, mask, kind=site.kind, poly=p)
-    else:
-        out = ops.masked_act_sited(x, mask, kind=site.kind, poly=p)
+    out = ops.masked_act_sited_routed(x, mask, kind=site.kind, poly=p)
     return _apply_share_ties(x, mask, out)

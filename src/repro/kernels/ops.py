@@ -4,6 +4,13 @@ Dispatch policy: on TPU backends the Pallas kernels run compiled; elsewhere
 (the CPU) callers get the pure-jnp oracle unless they explicitly ask for
 ``force_pallas=True`` / ``interpret=True`` (kernel-correctness tests do).
 Model code calls these wrappers only — it never touches pallas directly.
+
+Models reach the masked-activation kernels only through the routed
+(custom-vmap) entries: the stacked gate under
+``core.linearize.stacked_kernel_route`` and the fused gate + conv/matmul
+suffix kernels under ``core.linearize.fused_suffix_route``.  Every other
+hard-mask gate is ``core.linearize.masked_act_plain``, which XLA fuses into
+the op that produces its input; none of these kernels is differentiable.
 """
 from __future__ import annotations
 
@@ -63,42 +70,19 @@ def _per_shard(kernel, args, roles):
                          out_specs=spec[roles[0]], check_vma=False)(*args)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _masked_act_2d_vjp(x2, mask, poly, kind, interpret):
-    """The Pallas kernel forward, differentiated through the jnp oracle:
-    ``pallas_call`` has no reverse-mode rule, and training, SNL/BCD
-    finetuning and the LM train step differentiate hard-mask forwards."""
-    return _per_shard(
-        lambda *a: masked_act_2d(*a, kind=kind, interpret=interpret),
-        (x2, mask, poly), ("r", None, None))
-
-
-def _masked_act_2d_fwd(x2, mask, poly, kind, interpret):
-    return (_masked_act_2d_vjp(x2, mask, poly, kind, interpret),
-            (x2, mask, poly))
-
-
-def _masked_act_2d_bwd(kind, interpret, res, g):
-    _, vjp = jax.vjp(
-        lambda x, m, p: ref.masked_act_ref(x, m, kind=kind, poly=p), *res)
-    return vjp(g)
-
-
-_masked_act_2d_vjp.defvjp(_masked_act_2d_fwd, _masked_act_2d_bwd)
-
-
 def masked_act(x, mask, *, kind: str = "relu", poly=None,
                force_pallas: bool = False, interpret: bool = False):
     """y = mask·act(x) + (1−mask)·g(x) over (..., C) with per-channel mask.
 
     Accepts any leading shape; flattens to (rows, C) for the kernel.
-    Differentiable: the kernel path's VJP is the oracle's.
     """
     if not (force_pallas or _use_pallas()):
         return ref.masked_act_ref(x, mask, kind=kind, poly=poly)
     shape = x.shape
-    out = _masked_act_2d_vjp(x.reshape(-1, shape[-1]), mask, poly, kind,
-                             interpret or not _use_pallas())
+    interpret = interpret or not _use_pallas()
+    out = _per_shard(
+        lambda *a: masked_act_2d(*a, kind=kind, interpret=interpret),
+        (x.reshape(-1, shape[-1]), mask, poly), ("r", None, None))
     return out.reshape(shape)
 
 
@@ -162,8 +146,8 @@ def masked_act_sited_batched(x, masks, *, kind: str = "relu", poly=None,
 # site to the stacked kernel (masked_act_2d_batched) — one pallas_call owning
 # the (N, rows, cols) tiling, with the mask row broadcast per candidate
 # inside VMEM.  custom_vmap does not support differentiation, so this entry
-# is opt-in (core.linearize.stacked_kernel_route): training forwards keep the
-# plain kernel.
+# is opt-in (core.linearize.stacked_kernel_route); unbatched, it runs the
+# per-candidate kernel.
 #
 # Suffix entry (the prefix-reuse engine, core.engine.SuffixEvaluator): the
 # vmapped *suffix* forward receives the cached prefix activation with
@@ -220,8 +204,8 @@ def masked_act_sited_routed(x, mask, *, kind: str = "relu", poly=None,
     the stacked Pallas kernel instead of a vmapped per-candidate grid.
 
     TPU-path only (callers dispatch; the kernel always runs, with
-    ``interpret=True`` for off-TPU tests).  Not differentiable — route
-    training forwards through :func:`masked_act_sited`.
+    ``interpret=True`` for off-TPU tests).  Not differentiable: training
+    forwards take ``core.linearize.masked_act_plain``.
     """
     f = _routed_sited(kind, bool(interpret), poly is not None)
     return f(x, mask) if poly is None else f(x, mask, poly)

@@ -49,31 +49,105 @@ def test_masked_act_poly_matches_oracle(kind):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
+@pytest.fixture
+def tpu_dispatch(monkeypatch):
+    """The TPU's kernel dispatch on the CPU: ``ops._use_pallas`` forced
+    true and every ``pallas_call`` in interpret mode."""
+    from jax.experimental import pallas as pl
+    call = pl.pallas_call
+    monkeypatch.setattr(pl, "pallas_call",
+                        lambda *a, **k: call(*a, **{**k, "interpret": True}))
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+
+
+def _gate_inputs(seed, site_shape, batch=2):
+    """Activations, a hard mask holding off, on and share-tied
+    (``masks.TIE``) coordinates, and a cotangent."""
+    from repro.core import masks as M
+    rng = np.random.default_rng(seed)
+    x = jnp.asarray(rng.normal(size=(batch,) + site_shape)
+                    .astype(np.float32))
+    m = jnp.asarray(rng.choice(np.float32([0.0, 1.0, M.TIE]),
+                               size=site_shape))
+    cot = jnp.asarray(rng.normal(size=x.shape).astype(np.float32))
+    return x, m, cot
+
+
+def _oracle_gate(x, m, kind, poly=None):
+    """The hard-mask gate by its definition: the kernels' oracle, then the
+    share-ties override (``linearize._apply_share_ties``)."""
+    from repro.core import linearize
+    return linearize._apply_share_ties(
+        x, m, ref.masked_act_ref(x, m, kind=kind, poly=poly))
+
+
 @pytest.mark.parametrize("kind", KINDS)
 @pytest.mark.parametrize("with_poly", [False, True])
-def test_masked_act_kernel_grad_matches_oracle_grad(kind, with_poly):
-    """The hard-mask kernel entry differentiates (training, SNL/BCD
-    finetune and the LM train step run it on the TPU): its gradients with
-    respect to x, mask and poly are the oracle's."""
-    rng = np.random.default_rng(2)
-    x = jnp.asarray(rng.normal(size=(2, 9, 130)).astype(np.float32))
-    m = jnp.asarray((rng.random(130) > 0.5).astype(np.float32))
-    poly = (jnp.asarray(rng.normal(size=(3, 130)).astype(np.float32) * 0.1)
-            if with_poly else None)
-    cot = jnp.asarray(rng.normal(size=x.shape).astype(np.float32))
+def test_masked_act_kernel_grad_matches_oracle_grad(kind, with_poly,
+                                                    tpu_dispatch):
+    """The TPU's hard-mask gate differentiates (training, SNL/BCD finetune
+    and the LM train step run it): its gradients with respect to x, mask
+    and poly are the oracle's."""
+    from repro.core import linearize
+    x, m, cot = _gate_inputs(2, (9, 130))
+    rng = np.random.default_rng(3)
+    poly = (jnp.asarray(rng.normal(size=(3, 9, 130)).astype(np.float32)
+                        * 0.1) if with_poly else None)
+    site = linearize.MaskSite((9, 130), kind,
+                              "poly2" if with_poly else "identity")
 
     def loss(f):
         return lambda x, m, p: jnp.sum(f(x, m, p) * cot)
 
-    kernel = loss(lambda x, m, p: ops.masked_act(
-        x, m, kind=kind, poly=p, force_pallas=True, interpret=True))
-    oracle = loss(lambda x, m, p: ref.masked_act_ref(x, m, kind=kind,
-                                                     poly=p))
+    gate = loss(lambda x, m, p: linearize.apply_masked_act(x, m, site,
+                                                           poly=p))
+    oracle = loss(lambda x, m, p: _oracle_gate(x, m, kind, poly=p))
     argnums = (0, 1, 2) if with_poly else (0, 1)
-    got = jax.jit(jax.grad(kernel, argnums))(x, m, poly)
+    got = jax.jit(jax.grad(gate, argnums))(x, m, poly)
     want = jax.jit(jax.grad(oracle, argnums))(x, m, poly)
     for g, w in zip(got, want):
         np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_unrouted_hard_gate_is_xlas_on_the_tpu_dispatch(kind, tpu_dispatch):
+    """Outside any route, the TPU's hard-mask gate is the plain broadcast
+    expression: its jaxpr holds no ``pallas_call`` (XLA fuses the gate into
+    the op that produces x), and it computes the oracle's gate with the
+    share-ties override."""
+    from repro.core import linearize
+    x, m, _ = _gate_inputs(12, (4, 4, 8))
+    site = linearize.MaskSite((4, 4, 8), kind)
+    gate = lambda x, m: linearize.apply_masked_act(x, m, site)
+    assert "pallas_call" not in str(jax.make_jaxpr(gate)(x, m))
+    np.testing.assert_allclose(gate(x, m), _oracle_gate(x, m, kind),
+                               rtol=1e-6, atol=1e-6)
+
+
+def test_stacked_route_still_lowers_to_the_stacked_kernel(tpu_dispatch,
+                                                          monkeypatch):
+    """Under ``stacked_kernel_route`` the TPU's hard gate keeps its Pallas
+    kernels: a candidate vmap traces the stacked kernel
+    (``masked_act_2d_batched``), an unbatched call the per-candidate one,
+    and both agree with the oracle's gate per candidate."""
+    from repro.core import linearize
+    stacked = []
+    batched = ops.masked_act_2d_batched
+    monkeypatch.setattr(ops, "masked_act_2d_batched",
+                        lambda *a, **k: stacked.append(1) or batched(*a, **k))
+    x, _, _ = _gate_inputs(13, (4, 4, 8))
+    masks = jnp.stack([_gate_inputs(14 + i, (4, 4, 8))[1] for i in range(3)])
+    site = linearize.MaskSite((4, 4, 8), "relu")
+    gate = lambda m: linearize.apply_masked_act(x, m, site)
+    with linearize.stacked_kernel_route():
+        jaxpr = str(jax.make_jaxpr(jax.vmap(gate))(masks))
+        got = jax.vmap(gate)(masks)
+        one = str(jax.make_jaxpr(gate)(masks[0]))
+    assert stacked and "pallas_call" in jaxpr and "pallas_call" in one
+    for i in range(3):
+        np.testing.assert_allclose(got[i], _oracle_gate(x, masks[i],
+                                                            "relu"),
+                                   rtol=1e-6, atol=1e-6)
 
 
 if HAS_HYPOTHESIS:

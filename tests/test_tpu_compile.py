@@ -106,14 +106,17 @@ def test_kernel_compiles_for_v5e(name, one_chip):
 
 
 def test_masked_act_train_step_compiles_for_v5e(one_chip, monkeypatch):
-    """value_and_grad through the hard-mask entry at a ResNet18 site: the
-    kernel stays in the forward (custom VJP) and the whole step compiles."""
+    """value_and_grad through the TPU's hard-mask gate at a ResNet18 site:
+    the whole step compiles, and the gate is XLA's (no Pallas call), so
+    autodiff of the plain expression is the step's backward."""
+    from repro.core import linearize
     monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    site = linearize.MaskSite((32, 32, 64), "relu")
     step = jax.value_and_grad(
-        lambda x, m: jnp.sum(ops.masked_act_sited(x, m) ** 2))
+        lambda x, m: jnp.sum(linearize.apply_masked_act(x, m, site) ** 2))
     text = _compiled_text(step, [((128, 32, 32, 64), F32),
                                  ((32, 32, 64), F32)], one_chip)
-    assert "tpu_custom_call" in text
+    assert "tpu_custom_call" not in text
 
 
 def _v2_lite_share():
@@ -165,16 +168,12 @@ def test_held_experts_compile_for_v5e_at_the_cell_shapes(one_chip):
     assert "tpu_custom_call" in text      # the grouped matmuls
 
 
-def test_looped_fallback_compiles_for_v5e_in_a_quarter_of_the_vmaps_temp(
-        one_chip, monkeypatch):
-    """The full-forward fallback's chunk program on one chip, ResNet18 at a
-    512-image eval batch and a chunk of 8, with the TPU's gate kernel: the
-    loop over candidates compiles, and holds one candidate's activations
-    where the candidate vmap holds eight in a candidate-minor layout, so
-    its temp memory is under a quarter of the vmapped program's."""
-    from repro.core import engine
+def _resnet18_fallback(one_chip, chunk=None):
+    """ResNet18 (100 classes) at a 512-image eval batch, as the uniform
+    cell runs its full-forward fallback: the split forward, its context
+    (params, batch and the cached stem ``pre``) and mask shapes, with a
+    leading ``chunk`` axis when given."""
     from repro.models.resnet import CNN, CNNConfig
-    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
     model = CNN(CNNConfig.resnet18(100, 32))
     split = model.make_suffix_eval_fns()
     sd = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
@@ -182,8 +181,30 @@ def test_looped_fallback_compiles_for_v5e_in_a_quarter_of_the_vmaps_temp(
            "batch": {"images": jax.ShapeDtypeStruct((512, 32, 32, 3), F32),
                      "labels": jax.ShapeDtypeStruct((512,), jnp.int32)}}
     ctx = jax.tree.map(sd, {**ctx, "pre": jax.eval_shape(split.pre, ctx)})
-    masks = {k: sd(jax.ShapeDtypeStruct((8,) + s.shape, F32))
+    lead = () if chunk is None else (chunk,)
+    masks = {k: sd(jax.ShapeDtypeStruct(lead + s.shape, F32))
              for k, s in model.mask_sites().items()}
+    return split, ctx, masks, sd
+
+
+def _bytes_accessed(compiled):
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    return cost["bytes accessed"]
+
+
+def test_looped_fallback_compiles_for_v5e_in_a_quarter_of_the_vmaps_temp(
+        one_chip, monkeypatch):
+    """The full-forward fallback's chunk program on one chip, ResNet18 at a
+    512-image eval batch and a chunk of 8, with the TPU's dispatch: the
+    loop over candidates compiles with XLA's gate at every site (no Pallas
+    call), while the meshed vmap still lowers each site to the stacked
+    kernel; and the loop holds one candidate's activations where the vmap
+    holds eight in a candidate-minor layout, so its temp memory is under a
+    quarter of the vmapped program's."""
+    from repro.core import engine
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    split, ctx, masks, sd = _resnet18_fallback(one_chip, chunk=8)
     # the context here only marks eval_fn as taking one; shapes come below.
     # A mesh of the one described chip gives the evaluator's vmapped program.
     mesh = jax.sharding.Mesh(np.array(list(one_chip.device_set)), ("cand",))
@@ -194,6 +215,28 @@ def test_looped_fallback_compiles_for_v5e_in_a_quarter_of_the_vmaps_temp(
         masks, sd(jax.ShapeDtypeStruct((), jnp.int32)), ctx).compile()
     vmapped = meshed._program.lower(masks, ctx).compile()
     text = looped.as_text()
-    assert "while" in text and "tpu_custom_call" in text
+    assert "while" in text and "tpu_custom_call" not in text
+    assert "tpu_custom_call" in vmapped.as_text()
     assert looped.memory_analysis().temp_size_in_bytes < \
         vmapped.memory_analysis().temp_size_in_bytes / 4
+
+
+def test_fallback_forward_moves_fewer_bytes_with_xlas_gate_for_v5e(
+        one_chip, monkeypatch):
+    """One fallback forward (ResNet18, 512 images, from the cached stem
+    ``pre``) compiled for a v5e both ways: plain, every gate XLA's and
+    fused into the batch norm or residual add that produces its input; and
+    under ``stacked_kernel_route`` (the engine's wrapper, a function of its
+    own, so the two traces are not one cached trace), whose unbatched call
+    runs the Pallas gate.  XLA's count of the plain program's bytes is below
+    the routed one's, though the routed count leaves out its Pallas calls'
+    own traffic."""
+    from repro.core import engine
+    monkeypatch.setattr(ops, "_use_pallas", lambda: True)
+    split, ctx, masks, _ = _resnet18_fallback(one_chip)
+    plain = jax.jit(split.full).lower(masks, ctx).compile()
+    routed = jax.jit(engine._with_stacked_route(split.full)).lower(
+        masks, ctx).compile()
+    assert "tpu_custom_call" not in plain.as_text()
+    assert "tpu_custom_call" in routed.as_text()
+    assert _bytes_accessed(plain) < _bytes_accessed(routed)
